@@ -18,11 +18,9 @@ ring behind, which is exactly the throttle the submission plane wants.
 Lane kernels are jitted once per lane shape (the shape set is bounded by
 the pow-2 bucketing in `width_bucket`, so the jit cache cannot churn
 under mixed object sizes — the MTPU recompilation audit in
-tests/test_dataplane.py counts traces). On non-CPU backends the staged
-batch array is donated to the launch (SNIPPETS.md `donate_argnums`
-notes): XLA reuses the H2D buffer for outputs instead of allocating per
-launch. CPU ignores donation, so it is gated off there to keep the
-"donated buffer not usable" warnings out of serving logs.
+tests/test_dataplane.py counts traces). Nothing is donated: no output
+has the staged batch's shape, so there is nothing XLA could alias
+(`_jit_lane`).
 """
 
 from __future__ import annotations
@@ -133,15 +131,6 @@ class RingPool:
 
 
 @functools.lru_cache(maxsize=1)
-def _donate() -> bool:
-    """Donate the staged batch to the launch on real accelerators; CPU
-    has no usable donation and would warn per compile."""
-    import jax
-
-    return jax.default_backend() != "cpu"
-
-
-@functools.lru_cache(maxsize=1)
 def _row_sharding():
     """Batch-dim NamedSharding over every local device, or None on a
     single-device host. A coalesced lane launch is embarrassingly
@@ -176,8 +165,6 @@ def lane_kernel(key: LaneKey):
     codec.begin_reconstruct's fused digests, so a heal batch never
     pays a second queued launch for its bitrot frames)
     """
-    import jax
-
     from minio_tpu.ops import fused, rs_xla
 
     k, m = key.k, key.aux
@@ -209,13 +196,28 @@ def lane_kernel(key: LaneKey):
         def launch(data, weights):
             return rs_xla.gf2_matmul_multi(data, weights, t)
 
-    donate = (0,) if _donate() else ()
-    shard = _row_sharding()
-    if shard is not None and key.rows % len(jax.devices()) == 0:
-        return jax.jit(launch, donate_argnums=donate,
-                       in_shardings=(shard,) * nargs,
-                       out_shardings=shard)
-    return jax.jit(launch, donate_argnums=donate)
+    return _jit_lane(launch, nargs, key.rows, _row_sharding())
+
+
+def _jit_lane(launch, nargs: int, rows: int, shard):
+    """jit a lane launch, dp-sharded over `shard`'s devices when the row
+    count divides over them. The split is an explicit shard_map, not
+    in_shardings on a plain jit: the SPMD partitioner cannot split a
+    Pallas (Mosaic) kernel — the TPU compiler refuses the program with
+    "Mosaic kernels cannot be automatically partitioned" — and a lane
+    launch has no cross-row op, so each device simply runs the launch on
+    its own rows.
+
+    The staged batch is not donated: no output of a lane launch has the
+    input's shape, so XLA can alias nothing to it, and on the chip every
+    lane compile only warned "Some donated buffers were not usable"."""
+    import jax
+
+    if shard is not None and rows % shard.mesh.size == 0:
+        launch = jax.shard_map(
+            launch, mesh=shard.mesh, in_specs=(shard.spec,) * nargs,
+            out_specs=shard.spec, check_vma=False)
+    return jax.jit(launch)
 
 
 def trace_count() -> int:

@@ -429,12 +429,30 @@ class HealingMixin:
                             for b in batch_ids
                         ]
                         rows = []
+                        # Survivor records of a device-checksummed object
+                        # verify in ONE device launch per batch, as the
+                        # GET path's do; read_at would hash every chunk
+                        # on the host (numpy), a host lane inside heal.
+                        records = []
                         for j, b in enumerate(batch_ids):
                             chunk_len = -(-block_lens[j] // k)
                             row: list[bytes | None] = [None] * n
                             for pos in chosen:
-                                row[pos] = readers[pos].read_at(b * shard_size, chunk_len)
+                                if use_fused and chunk_len:
+                                    want, row[pos] = readers[pos].read_record(b)
+                                    records.append((b, want, row[pos]))
+                                else:
+                                    row[pos] = readers[pos].read_at(b * shard_size, chunk_len)
                             rows.append(row)
+                        if records:
+                            from minio_tpu.ops import fused
+
+                            got = fused.digest_chunks_host(
+                                [c for _b, _w, c in records], shard_size)
+                            for (b, want, _c), g in zip(records, got):
+                                if g != want:
+                                    raise se.FileCorrupt(
+                                        f"bitrot digest mismatch at chunk {b}")
                         pending.append(begin_rebuild(rows, block_lens))
                         if len(pending) >= 2:
                             drain_one()

@@ -211,12 +211,9 @@ def main(argv=None) -> None:
     ap.add_argument("--versioned", action="store_true")
     args = ap.parse_args(argv)
 
-    plat = os.environ.get("MTPU_JAX_PLATFORM", "")
-    if plat:
-        import jax
+    from minio_tpu.utils import compile_cache, sysres
 
-        jax.config.update("jax_platforms", plat)
-    from minio_tpu.utils import sysres
+    compile_cache.enable()
 
     sysres.maximize_nofile()
 

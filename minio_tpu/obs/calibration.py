@@ -57,16 +57,12 @@ _BUILD = gauge(
 
 
 def _accel() -> tuple[str, int]:
-    """(platform, local device count) — guarded: a host without a
-    working jax install still fingerprints as plain CPU."""
-    try:
-        import jax
+    """(platform, local device count). A backend that fails to
+    initialise raises: a fingerprint of a host that does not exist
+    would make `minio_tpu_build_info` lie about where the codec runs."""
+    import jax
 
-        return jax.default_backend(), len(jax.devices())
-    # mtpu: allow(MTPU003) - no accelerator stack is a valid host
-    # class, not an error.
-    except Exception:  # noqa: BLE001
-        return "none", 0
+    return jax.default_backend(), len(jax.devices())
 
 
 def _probe_fsync(root: str) -> tuple[str, float]:

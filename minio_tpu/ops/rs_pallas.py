@@ -39,10 +39,9 @@ def use_pallas() -> bool:
         return False
     if env in ("1", "on"):
         return True
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # noqa: BLE001
-        return False
+    # A backend error propagates: picking the XLA route on it would hide
+    # a device that failed to initialise.
+    return jax.default_backend() != "cpu"
 
 
 def _gf2_kernel(kin: int, tout: int, ts: int, wt_ref, x_ref, o_ref):

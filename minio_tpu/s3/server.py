@@ -3132,15 +3132,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
     import sys as _sys
 
-    # Pin the JAX backend before first device use (the env var alone can
-    # be overridden by site hooks that force-register accelerator
-    # plugins). Cluster harness tests run many server processes on CPU;
-    # an accelerator is single-tenant and must not be grabbed by each.
-    plat = os.environ.get("MTPU_JAX_PLATFORM", "")
-    if plat:
-        import jax
+    # Compiled codec programs persist across restarts (placement rule:
+    # utils/compile_cache.py). The backend is whatever JAX_PLATFORMS
+    # names; an accelerator belongs to ONE process at a time.
+    from minio_tpu.utils import compile_cache
 
-        jax.config.update("jax_platforms", plat)
+    compile_cache.enable()
 
     # Raise the fd soft limit to the hard limit (reference pkg/sys
     # setMaxResources) — a drive fleet + RPC fan-out outgrows the default

@@ -1,1 +1,1 @@
-"""Shared utilities: error taxonomy, quorum reducers, hashing helpers."""
+"""Shared utilities: error classes, quorum reducers, hashing helpers."""

@@ -1,4 +1,4 @@
-"""Error taxonomy for the storage stack.
+"""Error classes of the storage stack.
 
 Mirrors the reference's typed storage errors (cmd/typed-errors.go,
 cmd/storage-errors.go) as an exception hierarchy. Quorum logic reduces lists

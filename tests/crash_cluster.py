@@ -87,7 +87,6 @@ class Cluster:
         env.update({
             "MTPU_ROOT_USER": ACCESS,
             "MTPU_ROOT_PASSWORD": SECRET,
-            "MTPU_JAX_PLATFORM": "cpu",
             "JAX_PLATFORMS": "cpu",
             # Composed chaos plane: fault surfaces armed (inert until
             # programmed over the guarded admin endpoint), MRF requeue

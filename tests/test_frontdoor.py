@@ -325,7 +325,7 @@ def fd(tmp_path_factory):
         drives, f"127.0.0.1:{port}", workers=2, parity=1,
         shared_lanes=True, log_dir=str(root),
         env={"MTPU_ROOT_USER": S3_ACCESS, "MTPU_ROOT_PASSWORD": S3_SECRET,
-             "MTPU_JAX_PLATFORM": "cpu", "JAX_PLATFORMS": "cpu",
+             "JAX_PLATFORMS": "cpu",
              "MTPU_METAPLANE": "1", "MTPU_BATCHED_DATAPLANE": "1",
              # Keep PUT encodes on the device-codec plane (the native
              # C++ lane would serve them host-side) so non-zero workers
@@ -541,7 +541,7 @@ def test_respawn_and_graceful_drain(fd):
         [os.path.join(root, f"d{i}") for i in range(4)],
         f"127.0.0.1:{port}", workers=1, parity=1,
         env={"MTPU_ROOT_USER": S3_ACCESS, "MTPU_ROOT_PASSWORD": S3_SECRET,
-             "MTPU_JAX_PLATFORM": "cpu", "JAX_PLATFORMS": "cpu",
+             "JAX_PLATFORMS": "cpu",
              "MTPU_METAPLANE": "1"})
     sup.start()
     try:

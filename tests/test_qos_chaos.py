@@ -50,7 +50,7 @@ def _mk_sup(root, port, extra_env):
     from minio_tpu.frontdoor.supervisor import Supervisor
 
     env = {"MTPU_ROOT_USER": S3_ACCESS, "MTPU_ROOT_PASSWORD": S3_SECRET,
-           "MTPU_JAX_PLATFORM": "cpu", "JAX_PLATFORMS": "cpu",
+           "JAX_PLATFORMS": "cpu",
            "MTPU_METAPLANE": "1", "MTPU_BATCHED_DATAPLANE": "1"}
     env.update(extra_env)
     drives = [str(root / f"d{i}") for i in range(4)]

@@ -427,7 +427,6 @@ class _ReplNode:
         env.update({
             "MTPU_ROOT_USER": ACCESS,
             "MTPU_ROOT_PASSWORD": SECRET,
-            "MTPU_JAX_PLATFORM": "cpu",
             "JAX_PLATFORMS": "cpu",
             "MTPU_FAULT_INJECTION": "1",
         })

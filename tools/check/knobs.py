@@ -219,11 +219,6 @@ KNOB_DOCS: dict[str, tuple[str, str]] = {
         "Admit-time verification that the RESIDENT copy re-hashes to "
         "the host staging baseline (default on); `0` trusts the "
         "admit transfer."),
-    "MTPU_JAX_PLATFORM": (
-        "",
-        "Force the JAX platform (`cpu`, `tpu`, …) before first device "
-        "use — cluster harness processes pin `cpu` so a single-tenant "
-        "accelerator is not grabbed by each."),
     "MTPU_KERNEL_SYNC": (
         "METRICS.md",
         "`1` makes kernel observability block until device-complete "
