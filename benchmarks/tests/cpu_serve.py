@@ -1,0 +1,102 @@
+"""A launcher for the tests only: the benchmark's own `serve.py`, after
+making the program store `mxsum256` on the CPU backend too (what it picks by
+itself on a TPU), and after planting the fault `BENCH_TEST_FAULT` names
+underneath the program, where the answer is produced (or, for `outage`, the
+condition in which the program answers late)."""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))
+sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
+
+import serve  # noqa: E402
+
+
+def plant(fault: str) -> None:
+    from minio_tpu.ops import bitrot
+
+    bitrot._DEVICE_DEFAULT = "mxsum256"
+    if fault == "parity":
+        # One parity byte altered as the encode launch hands it back.
+        from minio_tpu.erasure import codec
+
+        wait = codec.PendingEncode.wait
+
+        def bad_wait(self):
+            chunks, digs = wait(self)
+            last = bytearray(chunks[0][-1])
+            last[0] ^= 1
+            chunks[0][-1] = memoryview(bytes(last))
+            return chunks, digs
+
+        codec.PendingEncode.wait = bad_wait
+    elif fault == "lost-drives":
+        # Acknowledged, but the commit reached fewer drives than quorum.
+        from minio_tpu.erasure import objects
+
+        quorum = objects.reduce_write_quorum
+        objects.reduce_write_quorum = (
+            lambda outcomes, q, *names: quorum(outcomes, 1, *names))
+        from minio_tpu.storage import local
+
+        rename = local.LocalDrive.rename_data
+
+        def bad_rename(self, *a, **kw):
+            if self.root.rstrip("/").endswith(("d0", "d1")):
+                raise OSError("planted: this drive takes no commit")
+            return rename(self, *a, **kw)
+
+        local.LocalDrive.rename_data = bad_rename
+    elif fault == "get-body":
+        # One byte of every GET body altered where the handler sends it.
+        from aiohttp import web
+
+        write = web.StreamResponse.write
+
+        async def bad_write(self, data):
+            if len(data) > 4096:
+                data = bytes([data[0] ^ 1]) + bytes(data[1:])
+            return await write(self, data)
+
+        web.StreamResponse.write = bad_write
+    elif fault == "outage":
+        # No fault of an answer: every drive is offline, as the program's
+        # health check has it after the host stood still. Twice, for two
+        # seconds each: from the first health probe (the read back's
+        # first question), and from the first read of a shard file (the
+        # first read back itself, its headers already sent).
+        import time
+
+        from minio_tpu.storage import healthcheck
+
+        offline = {}          # what began it -> until when
+        lookup = healthcheck.HealthChecker.__getattr__
+        begin = healthcheck.HealthChecker._begin
+        disk_info = healthcheck.HealthChecker.disk_info
+
+        def lookup_then_outage(self, name):
+            if name.startswith("read_file"):
+                offline.setdefault("read", time.monotonic() + 2.0)
+            return lookup(self, name)
+
+        def probe_then_outage(self):
+            offline.setdefault("probe", time.monotonic() + 2.0)
+            return disk_info(self)
+
+        def begin_or_offline(self, cls):
+            if any(time.monotonic() < t for t in offline.values()):
+                raise healthcheck.se.DiskNotFound("planted: drive offline")
+            return begin(self, cls)
+
+        healthcheck.HealthChecker.__getattr__ = lookup_then_outage
+        healthcheck.HealthChecker.disk_info = probe_then_outage
+        healthcheck.HealthChecker._begin = begin_or_offline
+    elif fault:
+        raise SystemExit(f"unknown fault {fault!r}")
+
+
+if __name__ == "__main__":
+    plant(os.environ.get("BENCH_TEST_FAULT", ""))
+    sys.exit(serve.main(sys.argv))
