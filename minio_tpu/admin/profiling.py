@@ -18,6 +18,8 @@ import tempfile
 import threading
 import zipfile
 
+from minio_tpu.obs import flight
+
 
 class Profiler:
     """One node's profiling session (at most one active at a time).
@@ -29,7 +31,14 @@ class Profiler:
     a marker file explaining WHY when the host has no usable device
     profiler (CPU-only containers must not fail the cluster-wide
     profiling round, and an empty archive must not read as "captured
-    nothing interesting")."""
+    nothing interesting").
+
+    The device trace is taken WITHOUT the Python tracer
+    (`python_tracer_level=0`): hooking every Python call stalls the
+    serving threads for seconds at start and slows the host all through
+    the slice. What the host did is in the trace all the same, as the
+    `mtpu/<stage>` annotations of `obs.flight.span`, which the session
+    arms for its length (docs/TRACING.md)."""
 
     def __init__(self):
         self._mu = threading.Lock()
@@ -58,7 +67,11 @@ class Profiler:
                     import jax
 
                     backend = jax.default_backend()
-                    jax.profiler.start_trace(d)
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0
+                    opts.host_tracer_level = 2
+                    jax.profiler.start_trace(d, profiler_options=opts)
+                    flight.set_profiling(True)
                     self._jax_dir = d
                     self._jax_name = ("tpu_trace.zip"
                                       if device_kind == "tpu"
@@ -98,6 +111,7 @@ class Profiler:
                 os.unlink(tmp)
                 self._cpu = None
             if self._jax_dir is not None:
+                flight.set_profiling(False)
                 try:
                     import jax
 

@@ -647,15 +647,20 @@ class BatchPlane:
             kern = ring.lane_kernel(
                 ring.LaneKey(op, k, aux, width, rb, digests))
             t0 = time.perf_counter()
-            if op == ring.OP_RECONSTRUCT and digests:
-                # Heal lane: rebuilt chunks + their mxsum digests in
-                # ONE launch (lens drive the cap-invariant digest).
-                outs = kern(slot.data[:rb], slot.weights[:rb],
-                            slot.lens[:rb])
-            elif op == ring.OP_RECONSTRUCT:
-                outs = kern(slot.data[:rb], slot.weights[:rb])
-            else:
-                outs = kern(slot.data[:rb], slot.lens[:rb])
+            # The dispatcher's own span around the shared launch: bus
+            # record and device-profile annotation; the members' timeline
+            # entries are the stamps below.
+            with flight.span("dp_launch", "dataplane", timeline=False,
+                             op=op, rows=rb):
+                if op == ring.OP_RECONSTRUCT and digests:
+                    # Heal lane: rebuilt chunks + their mxsum digests in
+                    # ONE launch (lens drive the cap-invariant digest).
+                    outs = kern(slot.data[:rb], slot.weights[:rb],
+                                slot.lens[:rb])
+                elif op == ring.OP_RECONSTRUCT:
+                    outs = kern(slot.data[:rb], slot.weights[:rb])
+                else:
+                    outs = kern(slot.data[:rb], slot.lens[:rb])
             obs_kernel.observe(
                 f"dp_{op}", _backend(), t0, blocks=rb,
                 nbytes=int(slot.data[:rb].size),
@@ -670,8 +675,8 @@ class BatchPlane:
                     # wait + staging memcpy); launch = the device
                     # dispatch for the whole batch.
                     r.tl.stamp("dp_queue_wait", t0 - r.t_submit,
-                               "dataplane")
-                    r.tl.stamp("dp_launch", now - t0, "dataplane")
+                               "dataplane", end=t0)
+                    r.tl.stamp("dp_launch", now - t0, "dataplane", end=now)
             if obs.has_subscribers():
                 obs.publish({
                     "type": "batch", "plane": "dataplane", "op": op,
@@ -708,19 +713,23 @@ class BatchPlane:
         resolve its requests' futures, recycle the slot."""
         try:
             t0 = time.perf_counter()
-            if slot_key.op == ring.OP_ENCODE:
-                parity, digs = outs
-                mat = (np.asarray(parity),
-                       np.asarray(digs) if digs is not None else None)
-            elif slot_key.op == ring.OP_RECONSTRUCT and slot_key.digests:
-                rebuilt, digs = outs
-                mat = (np.asarray(rebuilt), np.asarray(digs))
-            else:
-                mat = np.asarray(outs)
-            dt_mat = time.perf_counter() - t0
+            with flight.span("dp_materialize", "dataplane", timeline=False,
+                             op=slot_key.op, rows=slot_key.rows):
+                if slot_key.op == ring.OP_ENCODE:
+                    parity, digs = outs
+                    mat = (np.asarray(parity),
+                           np.asarray(digs) if digs is not None else None)
+                elif (slot_key.op == ring.OP_RECONSTRUCT
+                      and slot_key.digests):
+                    rebuilt, digs = outs
+                    mat = (np.asarray(rebuilt), np.asarray(digs))
+                else:
+                    mat = np.asarray(outs)
+            t1 = time.perf_counter()
             for req in reqs:
                 if req.tl is not None:
-                    req.tl.stamp("dp_materialize", dt_mat, "dataplane")
+                    req.tl.stamp("dp_materialize", t1 - t0, "dataplane",
+                                 end=t1)
             row0 = 0
             for req in reqs:
                 try:

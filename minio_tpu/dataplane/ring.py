@@ -196,10 +196,22 @@ def lane_kernel(key: LaneKey):
         def launch(data, weights):
             return rs_xla.gf2_matmul_multi(data, weights, t)
 
-    return _jit_lane(launch, nargs, key.rows, _row_sharding())
+    return _jit_lane(launch, nargs, key.rows, _row_sharding(),
+                     name=lane_name(key))
 
 
-def _jit_lane(launch, nargs: int, rows: int, shard):
+def lane_name(key: LaneKey) -> str:
+    """The lane program's name in a device trace and in the compile
+    counters: `lane_encode_k8m4_w16384_r4_d` (jit prefixes `jit_`). `m`
+    reads `t` on reconstruct lanes (the padded target count), a trailing
+    `_d` says the launch fuses the digests."""
+    aux = {OP_ENCODE: f"m{key.aux}", OP_RECONSTRUCT: f"t{key.aux}"}.get(
+        key.op, "")
+    return (f"lane_{key.op}_k{key.k}{aux}_w{key.width}_r{key.rows}"
+            + ("_d" if key.digests else ""))
+
+
+def _jit_lane(launch, nargs: int, rows: int, shard, name: str = ""):
     """jit a lane launch, dp-sharded over `shard`'s devices when the row
     count divides over them. The split is an explicit shard_map, not
     in_shardings on a plain jit: the SPMD partitioner cannot split a
@@ -217,6 +229,10 @@ def _jit_lane(launch, nargs: int, rows: int, shard):
         launch = jax.shard_map(
             launch, mesh=shard.mesh, in_specs=(shard.spec,) * nargs,
             out_specs=shard.spec, check_vma=False)
+    if name:
+        # jit names the program after the function: `jit_launch` for every
+        # lane says nothing in a trace.
+        launch.__name__ = launch.__qualname__ = name
     return jax.jit(launch)
 
 

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from minio_tpu.obs import flight
 from minio_tpu.obs import kernel as obs_kernel
 from minio_tpu.ops import rs_xla
 from minio_tpu.utils.shardmath import ceil_div as _ceil_div
@@ -89,8 +90,12 @@ class PendingEncode:
         """-> (per-block list of n shard chunks, per-block list of n chunk
         digests or None when digests were not requested)."""
         k, m = self._codec.k, self._codec.m
-        parity = np.asarray(self._parity_dev) if self._parity_dev is not None else None
-        digs = np.asarray(self._digs_dev) if self._digs_dev is not None else None
+        # Device done + the one D2H per tensor.
+        with flight.span("enc_wait", "dataplane"):
+            parity = (np.asarray(self._parity_dev)
+                      if self._parity_dev is not None else None)
+            digs = (np.asarray(self._digs_dev)
+                    if self._digs_dev is not None else None)
         out_chunks: list[list[memoryview]] = []
         out_digs: list[list[bytes]] | None = [] if digs is not None else None
         for bi, block in enumerate(self._blocks):
@@ -163,8 +168,20 @@ class ErasureCodec:
         (parity, and with_digests=True the mxsum256 bitrot digest of every
         shard chunk in the same launch — ops/fused.py). Returns immediately;
         results come from PendingEncode.wait()."""
-        import jax.numpy as jnp
+        with flight.span("enc_stage", "dataplane"):
+            batch, chunk_lens, padded = self._stage_blocks(blocks)
+        parity_dev = digs_dev = None
+        if self.m or with_digests:
+            # H2D and the jitted call, until it returns (async dispatch).
+            with flight.span("enc_dispatch", "dataplane"):
+                parity_dev, digs_dev = self._dispatch_encode(
+                    batch, chunk_lens, len(blocks), with_digests)
+        return PendingEncode(self, blocks, chunk_lens, padded,
+                             parity_dev, digs_dev)
 
+    def _stage_blocks(self, blocks: list[bytes]):
+        """Host staging of one batch -> (batch [rows, k, s_stage] u8,
+        chunk_lens per block, padded copies of the short blocks)."""
         from minio_tpu.ops import fused
 
         s_full = self.shard_size()
@@ -200,58 +217,67 @@ class ErasureCodec:
                 batch[bi, :, s:] = 0
         if rows != len(blocks):
             batch[len(blocks):] = 0
-        staged_lens = chunk_lens + [0] * (rows - len(blocks))
+        return batch, chunk_lens, padded
+
+    def _dispatch_encode(self, batch, chunk_lens: list[int], b: int,
+                         with_digests: bool):
+        """The device call for one staged batch of `b` real blocks ->
+        (parity_dev | None, digs_dev | None), not yet materialized."""
+        import jax.numpy as jnp
+
+        from minio_tpu.ops import fused
+
+        rows, _k, s_stage = batch.shape
+        s_full = self.shard_size()
+        staged_lens = chunk_lens + [0] * (rows - b)
         parity_dev = digs_dev = None
-        if self.m or with_digests:
-            mesh = serving_mesh()
-            b = len(blocks)
-            # rows (not b) is the staged batch dim: pow-2 row padding
-            # keeps non-pow-2 tail batches mesh-eligible — pad rows are
-            # zeros, their parity/digests are computed and ignored
-            # (wait() iterates real blocks only).
-            dims_ok = (mesh is not None
-                       and rows % mesh.shape["dp"] == 0
-                       and self.k % mesh.shape["tp"] == 0
-                       and s_full % mesh.shape["sp"] == 0)
-            if (dims_ok and self.m and with_digests
-                    and all(s == s_full for s in chunk_lens)):
-                # Multi-device host, full blocks: the mesh-sharded fused
-                # launch (psum GF contraction over ICI, sp-sharded mxsum)
-                # — the host numpy batch stays uncommitted so jit shards
-                # it straight onto the mesh. Ragged tails fall through to
-                # the single-device launch, which handles per-block
-                # lengths.
-                from minio_tpu.parallel import sharded_encode_with_mxsum
+        mesh = serving_mesh()
+        # rows (not b) is the staged batch dim: pow-2 row padding
+        # keeps non-pow-2 tail batches mesh-eligible — pad rows are
+        # zeros, their parity/digests are computed and ignored
+        # (wait() iterates real blocks only).
+        dims_ok = (mesh is not None
+                   and rows % mesh.shape["dp"] == 0
+                   and self.k % mesh.shape["tp"] == 0
+                   and s_full % mesh.shape["sp"] == 0)
+        if (dims_ok and self.m and with_digests
+                and all(s == s_full for s in chunk_lens)):
+            # Multi-device host, full blocks: the mesh-sharded fused
+            # launch (psum GF contraction over ICI, sp-sharded mxsum)
+            # — the host numpy batch stays uncommitted so jit shards
+            # it straight onto the mesh. Ragged tails fall through to
+            # the single-device launch, which handles per-block
+            # lengths.
+            from minio_tpu.parallel import sharded_encode_with_mxsum
 
-                t0 = time.perf_counter()
-                parity_dev, digs_dev = sharded_encode_with_mxsum(
-                    mesh, batch, self.k, self.m)
-                obs_kernel.observe("encode_digests", "mesh", t0,
-                                   blocks=b, nbytes=batch.size,
-                                   out=parity_dev)
-            elif dims_ok and self.m and not with_digests:
-                from minio_tpu.parallel import sharded_encode
+            t0 = time.perf_counter()
+            parity_dev, digs_dev = sharded_encode_with_mxsum(
+                mesh, batch, self.k, self.m)
+            obs_kernel.observe("encode_digests", "mesh", t0,
+                               blocks=b, nbytes=batch.size,
+                               out=parity_dev)
+        elif dims_ok and self.m and not with_digests:
+            from minio_tpu.parallel import sharded_encode
 
-                t0 = time.perf_counter()
-                parity_dev = sharded_encode(mesh, batch, self.k, self.m)
-                obs_kernel.observe("encode", "mesh", t0,
-                                   blocks=b, nbytes=batch.size,
-                                   out=parity_dev)
-            else:
-                data_dev = jnp.asarray(batch)
-                lens_dev = jnp.asarray(staged_lens, dtype=jnp.int32)
-                if self.m and with_digests:
-                    parity_dev, digs_dev = fused.encode_with_digests(
-                        data_dev, self.k, self.m, lens_dev)
-                elif self.m:
-                    parity_dev = fused.encode_only(data_dev, self.k, self.m)
-                else:  # digests for a parity-less geometry (k shards only)
-                    digs_dev = fused.verify_digests(
-                        data_dev.reshape(rows * self.k, s_stage),
-                        jnp.repeat(lens_dev, self.k),
-                    ).reshape(rows, self.k, -1)
-        return PendingEncode(self, blocks, chunk_lens, padded,
-                             parity_dev, digs_dev)
+            t0 = time.perf_counter()
+            parity_dev = sharded_encode(mesh, batch, self.k, self.m)
+            obs_kernel.observe("encode", "mesh", t0,
+                               blocks=b, nbytes=batch.size,
+                               out=parity_dev)
+        else:
+            data_dev = jnp.asarray(batch)
+            lens_dev = jnp.asarray(staged_lens, dtype=jnp.int32)
+            if self.m and with_digests:
+                parity_dev, digs_dev = fused.encode_with_digests(
+                    data_dev, self.k, self.m, lens_dev)
+            elif self.m:
+                parity_dev = fused.encode_only(data_dev, self.k, self.m)
+            else:  # digests for a parity-less geometry (k shards only)
+                digs_dev = fused.verify_digests(
+                    data_dev.reshape(rows * self.k, s_stage),
+                    jnp.repeat(lens_dev, self.k),
+                ).reshape(rows, self.k, -1)
+        return parity_dev, digs_dev
 
     def encode_blocks(self, blocks: list[bytes]) -> list[list[bytes]]:
         """Synchronous encode: per block, the n = k+m shard chunks (data

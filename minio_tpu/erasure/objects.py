@@ -68,12 +68,6 @@ _WRITE_SENTINEL = None
 # getting shard files (reference inlines small objects in xl.meta v2).
 INLINE_DATA_LIMIT = 16 << 10
 
-# Rolling erasure-encode throughput, EWMA over per-fan-out bytes/wall —
-# the live counterpart of PERF.md's hand-run encode benchmarks.
-_ENCODE_GIBPS = obs.gauge(
-    "minio_tpu_encode_gibps",
-    "Rolling erasure encode+fan-out throughput in GiB/s (EWMA)")
-
 # Tail-latency hedging on shard reads (first-k-wins): launched spares and
 # how many of them beat the straggler they covered for.
 _HEDGED_READS = obs.counter(
@@ -184,7 +178,6 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         # below the shared-pool dispatch cost. Wide sets and any remote
         # drive keep the parallel fan-out (RPC/disk latency dominates there).
         self._serial_meta_reads = self.n <= 8 and self._drives_all_local()
-        self._encode_gibps: float | None = None
         # Hedged shard reads: rolling EWMA of one shard's batch-read
         # latency feeds the hedge delay; hedge_delay pins it explicitly
         # (tests / operator override). None delay + no history = no hedge
@@ -468,8 +461,8 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
             serial_writes = self.fast_local_reads and self._drives_all_online()
             with self.nslock.lock(bucket, obj) as lease:
                 self._check_put_precondition(bucket, obj, opts)
-                with obs.span("commit", bucket=bucket, object=obj,
-                              inline=True):
+                with flight.span("commit", "metaplane", timeline=False,
+                                 bucket=bucket, object=obj, inline=True):
                     outcomes = None
                     if self._setcache is not None:
                         # Metaplane armed: two-phase group commit —
@@ -552,7 +545,11 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 deadline=self._meta_deadline())
 
         try:
-            with obs.span("encode", bucket=bucket, object=obj) as sp:
+            # The Timeline entry of encode/commit/quorum-read is the
+            # sequential mark's; the span adds the bus record and the
+            # device-profile annotation, no second entry.
+            with flight.span("encode", "dataplane", timeline=False,
+                             bucket=bucket, object=obj) as sp:
                 total, md5_hex, errs = self._fan_out_encode(
                     shuffled, sys_vol, f"{tmp_rel}/part.1", data, size, codec,
                     write_quorum, bucket, obj, initial=first_block,
@@ -591,7 +588,8 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
             except se.ObjectError:
                 cleanup_tmp()
                 raise
-            with obs.span("commit", bucket=bucket, object=obj):
+            with flight.span("commit", "metaplane", timeline=False,
+                             bucket=bucket, object=obj):
                 outcomes = parallel_map(
                     [lambda i=i, d=d: commit(i, d)
                      for i, d in enumerate(shuffled)],
@@ -873,15 +871,9 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                             break
                         except se.StorageError:
                             continue
-                    decoded = self._decode_rows(codec, rows, lens)
-                    for j, b in enumerate(ids):
-                        blk_start = b * fi.erasure.block_size
-                        lo = max(offset, blk_start) - blk_start
-                        hi = min(offset + length,
-                                 blk_start + lens[j]) - blk_start
-                        if hi > lo:
-                            yield from _yield_block_range(
-                                decoded[j], lo, hi)
+                    yield from self._decoded_ranges(
+                        codec, rows, ids, lens, fi.erasure.block_size,
+                        offset, length)
             finally:
                 for r in readers:
                     if r is not None:
@@ -964,19 +956,14 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         prod.start()
         try:
             while True:
-                tag, a, b_, c = out_q.get()
+                with flight.span("readahead_wait", "erasure"):
+                    tag, a, b_, c = out_q.get()
                 if tag == "done":
                     break
                 if tag == "err":
                     raise a
-                batch_ids, block_lens, rows = a, b_, c
-                decoded = self._decode_rows(codec, rows, block_lens)
-                for j, b in enumerate(batch_ids):
-                    blk_start = b * fi.erasure.block_size
-                    lo = max(offset, blk_start) - blk_start
-                    hi = min(offset + length, blk_start + block_lens[j]) - blk_start
-                    if hi > lo:
-                        yield from _yield_block_range(decoded[j], lo, hi)
+                yield from self._decoded_ranges(
+                    codec, c, a, b_, fi.erasure.block_size, offset, length)
         finally:
             # Runs on normal completion AND early close (GeneratorExit) —
             # callers that read exactly length bytes leave the generator
@@ -1001,6 +988,22 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
             if dead and self.mrf is not None:
                 self.mrf.add_partial(bucket, obj, fi.version_id,
                                      deep=bool(corrupt))
+
+    def _decoded_ranges(self, codec: ErasureCodec, rows, batch_ids,
+                        block_lens, block_size: int, offset: int,
+                        length: int):
+        """One read batch -> the memoryview slices of [offset,
+        offset+length) it holds, in order, sliced as the caller pulls.
+        The `decode` span closes before the first slice is handed out: it
+        must not stay open while the caller sends."""
+        with flight.span("decode", "erasure"):
+            decoded = self._decode_rows(codec, rows, block_lens)
+        for j, b in enumerate(batch_ids):
+            blk_start = b * block_size
+            lo = max(offset, blk_start) - blk_start
+            hi = min(offset + length, blk_start + block_lens[j]) - blk_start
+            if hi > lo:
+                yield from _yield_block_range(decoded[j], lo, hi)
 
     def _native_stream(self, bucket: str, obj: str, fi: FileInfo, part,
                        algo: str, shuffled: list[StorageAPI], rel: str,
@@ -1260,8 +1263,35 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
     def _read_chunk_rows(self, readers, chosen, batch_ids, block_lens, codec,
                          n, dead, algo=None, pool=None, corrupt=None,
                          open_reader=None, benched=None):
-        """Read one batch of chunk rows from the chosen shards; marks dead
-        drives and raises StorageError to trigger re-selection.
+        """Read one batch of chunk rows from the chosen shards and verify
+        them; marks dead drives and raises StorageError to trigger
+        re-selection."""
+        batched_verify = algo == "mxsum256"
+        with flight.span("shard_read", "erasure"):
+            results = self._read_shards(
+                readers, chosen, batch_ids, block_lens, codec, n, dead,
+                batched_verify, pool, corrupt, open_reader, benched)
+        rows: list[list[bytes | None]] = []
+        records: list[tuple[int, bytes, bytes]] = []  # (drive, want, chunk)
+        for j, _b in enumerate(batch_ids):
+            row: list[bytes | None] = [None] * n
+            for i in sorted(results):
+                want, chunk = results[i][j]
+                row[i] = chunk
+                if batched_verify:
+                    records.append((i, want, chunk))
+            rows.append(row)
+        if records:
+            # Staging, lane submit or launch, and the D2H of the digests.
+            with flight.span("verify_wait", "dataplane"):
+                self._verify_records(records, codec, readers, dead, corrupt)
+        return rows
+
+    def _read_shards(self, readers, chosen, batch_ids, block_lens, codec,
+                     n, dead, batched_verify, pool, corrupt, open_reader,
+                     benched) -> dict[int, list]:
+        """shard index -> its [(stored digest | None, chunk)] per block of
+        the batch, for the first k shards that answer.
 
         Shards read in PARALLEL (one worker per shard, each reading its
         batch sequentially — per-drive sequential I/O, cross-drive
@@ -1278,7 +1308,6 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         drive degrades GET latency by one hedge delay, not one deadline.
         Stragglers still pending when k arrive (or at the hard data
         deadline) are abandoned, never awaited."""
-        batched_verify = algo == "mxsum256"
         shard_size = codec.shard_size()
         chunk_lens = [-(-bl // codec.k) for bl in block_lens]
 
@@ -1445,20 +1474,7 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
             if first_err is not None:
                 i, e = first_err
                 raise se.FileCorrupt(f"shard {i}: {e}") from e
-
-        rows: list[list[bytes | None]] = []
-        records: list[tuple[int, bytes, bytes]] = []  # (drive, want, chunk)
-        for j, _b in enumerate(batch_ids):
-            row: list[bytes | None] = [None] * n
-            for i in sorted(results):
-                want, chunk = results[i][j]
-                row[i] = chunk
-                if batched_verify:
-                    records.append((i, want, chunk))
-            rows.append(row)
-        if records:
-            self._verify_records(records, codec, readers, dead, corrupt)
-        return rows
+        return results
 
     def _decode_rows(self, codec: ErasureCodec, rows, lens):
         """GET-path reconstruction: through the batched plane when
@@ -1862,11 +1878,9 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         The all-local sip256 configuration takes the native C++ lane
         instead (_native_fan_out); this Python/device path serves
         accelerator-fused digests and remote-drive topologies."""
-        t_enc = time.perf_counter()
         native = self._native_fan_out(shuffled, vol, rel, data, size, codec,
                                       write_quorum, bucket, obj, initial)
         if native is not None:
-            self._note_encode_rate(native[0], time.perf_counter() - t_enc)
             return native
         qs: list[queue.Queue] = [queue.Queue(maxsize=8) for _ in range(self.n)]
         errs: list[Exception | None] = [None] * self.n
@@ -1918,13 +1932,16 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 while qs[i].get() is not _WRITE_SENTINEL:
                     pass
 
-        threads = [
-            threading.Thread(target=obs.ctx_wrap(writer), args=(i, d),
-                             daemon=True)
-            for i, d in enumerate(shuffled)
-        ]
-        for t in threads:
-            t.start()
+        # One writer thread per drive, started per PUT: each start hands
+        # the GIL to the new thread and waits for it to say it runs.
+        with flight.span("enc_spawn", "erasure"):
+            threads = [
+                threading.Thread(target=obs.ctx_wrap(writer), args=(i, d),
+                                 daemon=True)
+                for i, d in enumerate(shuffled)
+            ]
+            for t in threads:
+                t.start()
 
         # Device-fused digests share the encode launch (ops/fused.py); any
         # other algorithm is hashed host-side per chunk.
@@ -1954,12 +1971,15 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
 
         def drain_one() -> None:
             chunk_rows, dig_rows = pending.pop(0).wait()
-            for bi, chunks in enumerate(chunk_rows):
-                digs = dig_rows[bi] if dig_rows is not None else None
-                for i in range(self.n):
-                    # digest None -> the writer thread hashes the chunk.
-                    feed(i, (digs[i] if digs is not None else None,
-                             chunks[i]))
+            # Blocks when the drives are behind (bounded writer queues).
+            with flight.span("enc_feed", "erasure"):
+                for bi, chunks in enumerate(chunk_rows):
+                    digs = dig_rows[bi] if dig_rows is not None else None
+                    for i in range(self.n):
+                        # digest None -> the writer thread hashes the
+                        # chunk.
+                        feed(i, (digs[i] if digs is not None else None,
+                                 chunks[i]))
             alive = sum(1 for e in errs if e is None)
             if alive < write_quorum:
                 raise se.InsufficientWriteQuorum(bucket, obj, "write fan-out lost quorum")
@@ -1971,7 +1991,8 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 data, min(bs, size) if size >= 0 else bs
             )
             while block:
-                md5.update(block)
+                with flight.span("enc_read", "erasure"):
+                    md5.update(block)
                 total += len(block)
                 batch.append(block)
                 if len(batch) >= self.batch_blocks:
@@ -1980,44 +2001,35 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                     if len(pending) >= pipeline_depth:
                         drain_one()
                 remaining = bs if size < 0 else min(bs, size - total)
-                block = _read_full(data, remaining)
+                with flight.span("enc_read", "erasure"):
+                    block = _read_full(data, remaining)
             if batch:
                 pending.append(begin_encode(batch))
             while pending:
                 drain_one()
         finally:
-            for i, q in enumerate(qs):
-                try:
-                    q.put(_WRITE_SENTINEL,
-                          timeout=0.1 if gave_up[i] else put_timeout)
-                except queue.Full:
-                    gave_up[i] = True
-            # Bounded join: a healthy writer drains to its sentinel well
-            # inside the deadline; a wedged one is declared timed out and
-            # left behind (daemon) rather than blocking the PUT forever.
-            join_end = time.monotonic() + put_timeout
-            for i, t in enumerate(threads):
-                t.join(timeout=0.1 if gave_up[i]
-                       else max(0.1, join_end - time.monotonic()))
-                if t.is_alive():
-                    gave_up[i] = True
-                    if errs[i] is None:
-                        errs[i] = se.OperationTimedOut(
-                            msg="drive shard writer did not finish")
-                        note_leaked_worker()
-        self._note_encode_rate(total, time.perf_counter() - t_enc)
+            with flight.span("enc_join", "erasure"):
+                for i, q in enumerate(qs):
+                    try:
+                        q.put(_WRITE_SENTINEL,
+                              timeout=0.1 if gave_up[i] else put_timeout)
+                    except queue.Full:
+                        gave_up[i] = True
+                # Bounded join: a healthy writer drains to its sentinel
+                # well inside the deadline; a wedged one is declared
+                # timed out and left behind (daemon) rather than
+                # blocking the PUT forever.
+                join_end = time.monotonic() + put_timeout
+                for i, t in enumerate(threads):
+                    t.join(timeout=0.1 if gave_up[i]
+                           else max(0.1, join_end - time.monotonic()))
+                    if t.is_alive():
+                        gave_up[i] = True
+                        if errs[i] is None:
+                            errs[i] = se.OperationTimedOut(
+                                msg="drive shard writer did not finish")
+                            note_leaked_worker()
         return total, md5.hexdigest(), errs
-
-    def _note_encode_rate(self, nbytes: int, wall: float) -> None:
-        """Rolling encode throughput: EWMA over per-fan-out bytes/wall —
-        a regression in the codec or shard path shows up in the gauge
-        without re-running bench.py."""
-        if nbytes <= 0 or wall <= 0.0:
-            return
-        gibps = nbytes / wall / (1 << 30)
-        e = self._encode_gibps
-        self._encode_gibps = gibps if e is None else 0.7 * e + 0.3 * gibps
-        _ENCODE_GIBPS.set(self._encode_gibps)
 
     def _inline_commit_fast(self, shuffled, bucket: str, obj: str,
                             fi: FileInfo, raw: bytes, journal):
@@ -2118,7 +2130,8 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
             # invalidates at the next lookup instead of serving the
             # pre-mutation election under post-mutation signatures.
             pre_sigs = sc.snapshot_sigs(bucket, obj, self.drives)
-        with obs.span("quorum-read", bucket=bucket, object=obj):
+        with flight.span("quorum-read", "metaplane", timeline=False,
+                         bucket=bucket, object=obj):
             fi = self._read_quorum_fileinfo_inner(bucket, obj, version_id)
         if sc is not None:
             sc.populate(bucket, obj, version_id, fi, self.drives,

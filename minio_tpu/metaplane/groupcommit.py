@@ -797,10 +797,14 @@ class DriveWAL:
         frames = [walfmt.frame_record(rtype, mt, vol, path, raw)
                   for rtype, vol, path, raw, _m, mt, _l, _f, _t in staged]
         try:
-            n = walfmt.append_records(self._fd, frames)
-            if self._test_hold_fsync:
-                time.sleep(self._test_hold_fsync)
-            os.fsync(self._fd)
+            # The committer's own span around the shared append + fsync;
+            # the members' timeline entries are the stamps below.
+            with flight.span("wal_fsync", "metaplane", timeline=False,
+                             members=len(staged)):
+                n = walfmt.append_records(self._fd, frames)
+                if self._test_hold_fsync:
+                    time.sleep(self._test_hold_fsync)
+                os.fsync(self._fd)
         except OSError as e:
             self._broken = str(e)
             err = se.FaultyDisk(f"wal append/fsync failed: {e}")
@@ -829,7 +833,8 @@ class DriveWAL:
             if tid:
                 members.append(tid)
             if tl is not None:
-                tl.stamp("wal_fsync_wait", t_ack - t_sub, "metaplane")
+                tl.stamp("wal_fsync_wait", t_ack - t_sub, "metaplane",
+                         end=t_ack)
         if obs.has_subscribers():
             obs.publish({"type": "batch", "plane": "metaplane",
                          "records": len(staged), "members": members,

@@ -19,7 +19,8 @@ observable:
 - `bench.py` stamps `fingerprint()` into every BENCH row so a result
   file is forever attributable to the host that produced it, and
   `publish_build_info()` exposes the standing
-  `minio_tpu_build_info{version,platform,devices}` info-gauge.
+  `minio_tpu_build_info{version,platform,devices,device_kind}`
+  info-gauge.
 
 Schema is documented in docs/SLO.md (calibration section).
 """
@@ -53,7 +54,7 @@ _STALE = gauge(
 _BUILD = gauge(
     "minio_tpu_build_info",
     "Constant 1; labels carry build/runtime identity",
-    ("version", "platform", "devices"))
+    ("version", "platform", "devices", "device_kind"))
 
 
 def _accel() -> tuple[str, int]:
@@ -178,7 +179,13 @@ def boot(drive0_root: str) -> dict:
 
 
 def publish_build_info() -> None:
-    """Expose minio_tpu_build_info{version,platform,devices} = 1."""
+    """Expose minio_tpu_build_info{version,platform,devices,device_kind}
+    = 1. `device_kind` is the accelerator's own name as JAX reports it
+    (`TPU v5 lite`): the platform alone cannot tell one chip generation
+    from the next."""
+    import jax
+
     platform, devices = _accel()
     _BUILD.set(1.0, version=__version__, platform=platform,
-               devices=str(devices))
+               devices=str(devices),
+               device_kind=jax.devices()[0].device_kind)

@@ -33,6 +33,10 @@ the family into true device-complete latency for profiling sessions —
 never the default, because a forced sync would serialize the
 dispatch-ahead encode pipeline it is measuring.
 
+Compiles: `minio_tpu_jit_compiles_total{program}` and
+`minio_tpu_jit_compile_seconds_total{program}` count JAX's own
+backend-compile events (`count_compiles`).
+
 Typed `kernel` trace records ride the bus under the same zero-overhead
 subscriber gate as every other plane.
 """
@@ -84,7 +88,42 @@ _DP_REJECTED = _counter(
     "Requests rejected at the bounded submission queue (503 SlowDown)",
     ("op",))
 
+# Compiles, counted by the program itself: JAX reports every backend
+# compile (a persistent-cache hit included, at the time the retrieval
+# took) to its monitoring listeners. A request that meets a new shape
+# pays this on its own thread; in a warm window both stay flat.
+_JIT_COMPILES = _counter(
+    "minio_tpu_jit_compiles_total",
+    "XLA backend compiles (persistent-cache retrievals included) by "
+    "program", ("program",))
+_JIT_COMPILE_SECONDS = _counter(
+    "minio_tpu_jit_compile_seconds_total",
+    "Seconds spent in XLA backend compiles by program", ("program",))
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_on = False
+
 _SYNC = os.environ.get("MTPU_KERNEL_SYNC", "") in ("1", "true", "on")
+
+
+def _on_event_duration(event: str, duration: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    # `fun_name` reads `jit(encode_with_digests)`.
+    program = str(kw.get("fun_name") or "unknown")
+    _JIT_COMPILES.labels(program=program).inc()
+    _JIT_COMPILE_SECONDS.labels(program=program).inc(duration)
+
+
+def count_compiles() -> None:
+    """Register the compile listener, once a process (listeners are
+    process-global in JAX). Called where the server initialises JAX."""
+    global _compile_listener_on
+    if _compile_listener_on:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+    _compile_listener_on = True
 
 
 def set_sync(on: bool) -> None:

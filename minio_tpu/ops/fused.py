@@ -99,26 +99,31 @@ def _observed(kernel: str, out_of=None):
 
 def _encode_dispatch(data: jax.Array, k: int, m: int) -> jax.Array:
     b, _, s = data.shape
-    if rs_pallas.use_pallas():
-        pad = (-s) % rs_pallas.TILE
-        if pad:
-            dp = jnp.pad(data, ((0, 0), (0, 0), (0, pad)))
-            return rs_pallas.encode(dp, k, m)[:, :, :s]
-        return rs_pallas.encode(data, k, m)
-    return rs_xla.encode(data, k, m)
+    # named_scope: `rs_encode` goes into the HLO metadata of the pad, the
+    # kernel and the slice (xprof, HLO dumps); trace events keep HLO names.
+    with jax.named_scope("rs_encode"):
+        if rs_pallas.use_pallas():
+            pad = (-s) % rs_pallas.TILE
+            if pad:
+                dp = jnp.pad(data, ((0, 0), (0, 0), (0, pad)))
+                return rs_pallas.encode(dp, k, m)[:, :, :s]
+            return rs_pallas.encode(data, k, m)
+        return rs_xla.encode(data, k, m)
 
 
 def _reconstruct_dispatch(shards: jax.Array, k: int, n: int,
                           survivors: tuple[int, ...],
                           targets: tuple[int, ...]) -> jax.Array:
     b, _, s = shards.shape
-    if rs_pallas.use_pallas():
-        pad = (-s) % rs_pallas.TILE
-        if pad:
-            sp = jnp.pad(shards, ((0, 0), (0, 0), (0, pad)))
-            return rs_pallas.reconstruct(sp, k, n, survivors, targets)[:, :, :s]
-        return rs_pallas.reconstruct(shards, k, n, survivors, targets)
-    return rs_xla.reconstruct(shards, k, n, survivors, targets)
+    with jax.named_scope("rs_reconstruct"):
+        if rs_pallas.use_pallas():
+            pad = (-s) % rs_pallas.TILE
+            if pad:
+                sp = jnp.pad(shards, ((0, 0), (0, 0), (0, pad)))
+                return rs_pallas.reconstruct(
+                    sp, k, n, survivors, targets)[:, :, :s]
+            return rs_pallas.reconstruct(shards, k, n, survivors, targets)
+        return rs_xla.reconstruct(shards, k, n, survivors, targets)
 
 
 @_observed("encode")
@@ -188,15 +193,16 @@ def _weights_matmul_dispatch(surv: jax.Array, w_t: jax.Array,
     """Runtime-weights contraction with kernel dispatch: surv [B, k, S],
     w_t [t*8, k*8] (pre-transposed) -> [B, t, S]."""
     b, _, s = surv.shape
-    if rs_pallas.use_pallas():
-        pad = (-s) % rs_pallas.TILE
-        if pad:
-            sp = jnp.pad(surv, ((0, 0), (0, 0), (0, pad)))
-            return rs_pallas.gf2_matmul_with_weights(
-                sp, w_t, out_shards)[:, :, :s]
-        return rs_pallas.gf2_matmul_with_weights(surv, w_t, out_shards)
-    return rs_xla.gf2_matmul_with_weights(surv, jnp.transpose(w_t),
-                                          out_shards)
+    with jax.named_scope("rs_reconstruct"):
+        if rs_pallas.use_pallas():
+            pad = (-s) % rs_pallas.TILE
+            if pad:
+                sp = jnp.pad(surv, ((0, 0), (0, 0), (0, pad)))
+                return rs_pallas.gf2_matmul_with_weights(
+                    sp, w_t, out_shards)[:, :, :s]
+            return rs_pallas.gf2_matmul_with_weights(surv, w_t, out_shards)
+        return rs_xla.gf2_matmul_with_weights(surv, jnp.transpose(w_t),
+                                              out_shards)
 
 
 @_observed("reconstruct_weights", out_of=lambda out: out[0])

@@ -162,14 +162,17 @@ def digest_device(chunks, lengths):
     import jax.numpy as jnp
 
     b, s = chunks.shape
-    acc = jnp.zeros((b, COLS), dtype=jnp.int32)
-    if s:
-        k = jnp.asarray(_key_rows(s))                          # [S, 8] i8
-        acc = jax.lax.dot_general(
-            chunks.astype(jnp.int8), k,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)                  # [B, 8]
-    return pack_words_device(acc + len_term_device(lengths))
+    # The scope goes into the ops' HLO metadata, which xprof and an HLO
+    # dump show; a `ProfileData` event keeps its HLO name (`%fusion.5`).
+    with jax.named_scope("mxsum256"):
+        acc = jnp.zeros((b, COLS), dtype=jnp.int32)
+        if s:
+            k = jnp.asarray(_key_rows(s))                      # [S, 8] i8
+            acc = jax.lax.dot_general(
+                chunks.astype(jnp.int8), k,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)              # [B, 8]
+        return pack_words_device(acc + len_term_device(lengths))
 
 
 class MXSum256:

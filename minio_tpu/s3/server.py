@@ -87,6 +87,12 @@ _REQ_LATENCY = obs.histogram(
 _REQ_TTFB = obs.histogram(
     "minio_tpu_s3_ttfb_seconds",
     "Time to first response byte by API", ("api",))
+# How late a 100 ms timer on the server's event loop wakes: the queueing
+# every request pays on the one loop thread (_loop_lag_sampler).
+_LOOP_LAG = obs.histogram(
+    "minio_tpu_entry_loop_lag_seconds",
+    "Lateness of a periodic wake-up on the S3 event loop").labels()
+_LOOP_LAG_PERIOD_S = 0.1
 # Per-tenant SLO families (QoS plane, docs/QOS.md): tenant = the
 # "access_key/bucket" key bound in _dispatch. Always on — the noisy-
 # neighbor chaos gate reads scrape deltas of these to prove each
@@ -128,6 +134,27 @@ def _int_q(q: dict, name: str, default: int, lo: int = 0, hi: int = 100_000) -> 
     return v
 
 
+async def _loop_lag_sampler(_app):
+    """Every request's body chunks, signature checks and response writes
+    queue on this ONE event-loop thread. A 100 ms timer that observes how
+    late it fired measures that queue: what any callback scheduled on
+    the loop waits before it runs. (A timer, not a task: a loop that is
+    stopped without cleanup drops a timer silently.)"""
+    loop = asyncio.get_running_loop()
+    handle = None
+
+    def tick(due: float) -> None:
+        nonlocal handle
+        now = loop.time()
+        _LOOP_LAG.observe(max(0.0, now - due))
+        handle = loop.call_at(now + _LOOP_LAG_PERIOD_S, tick,
+                              now + _LOOP_LAG_PERIOD_S)
+
+    tick(loop.time())
+    yield
+    handle.cancel()
+
+
 class S3Server:
     def __init__(self, object_layer, credentials: sigv4.Credentials,
                  region: str = "us-east-1", versioned_buckets: bool = False,
@@ -140,6 +167,7 @@ class S3Server:
         self.versioned_buckets = versioned_buckets
         self.app = web.Application(client_max_size=1 << 30)
         self.app.router.add_route("*", "/{tail:.*}", self._entry)
+        self.app.cleanup_ctx.append(_loop_lag_sampler)
 
         # Security + CORS headers on every response, including prepared
         # streams (reference addSecurityHeaders + CrossDomainPolicy/CORS,
@@ -304,8 +332,10 @@ class S3Server:
         # counters into the ring. Keyed source: a rebuilt server in the
         # same process replaces its predecessor's stats feed.
         from minio_tpu.obs import calibration as _calibration
+        from minio_tpu.obs import kernel as _obs_kernel
         from minio_tpu.obs import slo as _slo
-        _calibration.publish_build_info()
+        _calibration.publish_build_info()   # initialises the JAX backend
+        _obs_kernel.count_compiles()
         _slo_engine = _slo.ensure_started(store=store)
         if _slo_engine is not None:
             _slo_engine.db.add_source(self._slo_stats_source,
@@ -1264,6 +1294,7 @@ class S3Server:
                      and request.content_type == "multipart/form-data")
         action = action_for(m, sub, bucket, key, request.headers)
         request["api"] = "PostPolicy" if post_form else action.split(":", 1)[-1]
+        flight.set_api(request["api"])  # spans name their API from here on
         bulk_delete = m == "POST" and not key and "delete" in q
         # Built once per request, reused by in-handler re-checks
         # (RestoreObject, bulk delete) — the values don't change
@@ -2442,20 +2473,32 @@ class S3Server:
             chunked = sigv4.ChunkedSigV4Reader(
                 req_creds, auth_sig.signature, amz_date, auth_sig.scope_date,
                 auth_sig.region, auth_sig.service)
+        # rx_drain, where the work happens: the socket wait (and the wait
+        # for this one event-loop thread), the hashing, the spool write.
+        chunks = aiter(request.content.iter_chunked(1 << 20))
         try:
-            async for chunk in request.content.iter_chunked(1 << 20):
+            while True:
+                with flight.span("rx_wait"):
+                    chunk = await anext(chunks, None)
+                if chunk is None:
+                    break
                 delay = self.bw_throttle.delay(bucket, len(chunk), "rx")
                 if delay > 0:
                     await asyncio.sleep(delay)
                 if chunked is not None:
                     # Verified chunk views stream straight to the spool
                     # (valid until the next feed — written before it).
-                    for piece in chunked.feed(chunk):
-                        spool.write(piece)
+                    with flight.span("rx_hash"):
+                        pieces = chunked.feed(chunk)
+                    with flight.span("rx_spool"):
+                        for piece in pieces:
+                            spool.write(piece)
                 else:
                     if sha is not None:
-                        sha.update(chunk)
-                    spool.write(chunk)
+                        with flight.span("rx_hash"):
+                            sha.update(chunk)
+                    with flight.span("rx_spool"):
+                        spool.write(chunk)
             if chunked is not None and not chunked.done:
                 raise S3Error("IncompleteBody")
             if sha is not None and sha.hexdigest() != payload_hash:
@@ -2682,15 +2725,20 @@ class S3Server:
         # reads run inside next() on the executor and their storage/RPC
         # records must keep this request's trace id.
         drain_next = obs.ctx_wrap(lambda: next(it, None))
+        # resp_drain, split: the loop waits for the object layer's next
+        # chunk (drive read, verify, decode), then sends it.
         while True:
-            chunk = await loop.run_in_executor(None, drain_next)
+            with flight.span("tx_next"):
+                chunk = await loop.run_in_executor(None, drain_next)
             if chunk is None:
                 break
             delay = self.bw_throttle.delay(bucket, len(chunk))
             if delay > 0:
                 await asyncio.sleep(delay)
-            await resp.write(chunk)
-        await resp.write_eof()
+            with flight.span("tx_send"):
+                await resp.write(chunk)
+        with flight.span("tx_send"):
+            await resp.write_eof()
         return resp
 
     async def _delete_objects(self, request, bucket, hdr, run):

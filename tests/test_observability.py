@@ -27,15 +27,10 @@ def _free_port() -> int:
     return p
 
 
-@pytest.fixture(scope="module")
-def server(tmp_path_factory):
+def _serve(srv):
+    """srv.app on a loop thread of its own -> (base URL, the loop)."""
     import asyncio
 
-    from minio_tpu.s3.server import build_server
-
-    root = tmp_path_factory.mktemp("obs-drives")
-    srv = build_server([str(root / f"d{i}") for i in range(4)], ACCESS,
-                       SECRET)
     port = _free_port()
     loop = asyncio.new_event_loop()
     started = threading.Event()
@@ -46,8 +41,7 @@ def server(tmp_path_factory):
         async def start():
             runner = web.AppRunner(srv.app)
             await runner.setup()
-            site = web.TCPSite(runner, "127.0.0.1", port)
-            await site.start()
+            await web.TCPSite(runner, "127.0.0.1", port).start()
             started.set()
 
         loop.run_until_complete(start())
@@ -55,7 +49,18 @@ def server(tmp_path_factory):
 
     threading.Thread(target=run, daemon=True).start()
     assert started.wait(30)
-    yield f"http://127.0.0.1:{port}", srv
+    return f"http://127.0.0.1:{port}", loop
+
+
+@pytest.fixture(scope="module")
+def server(tmp_path_factory):
+    from minio_tpu.s3.server import build_server
+
+    root = tmp_path_factory.mktemp("obs-drives")
+    srv = build_server([str(root / f"d{i}") for i in range(4)], ACCESS,
+                       SECRET)
+    base, loop = _serve(srv)
+    yield base, srv
     loop.call_soon_threadsafe(loop.stop)
 
 
@@ -208,9 +213,17 @@ def test_drive_and_api_labels(server, client, traffic):
 
 
 def test_encode_gauge_after_streaming_put(client, traffic):
+    """The streaming PUT's encode + fan-out time is on the scrape: the
+    flight recorder's `encode` stage (the rolling `minio_tpu_encode_gibps`
+    gauge it replaced measured the same wall)."""
     _, samples = parse_exposition(_scrape(client).text)
-    vals = [v for n, _l, v in samples if n == "minio_tpu_encode_gibps"]
+    vals = [v for n, lbl, v in samples
+            if n == "minio_tpu_stage_seconds_sum"
+            and lbl.get("stage") == "encode"
+            and lbl.get("api") == "PutObject"]
     assert vals and vals[0] > 0
+    assert not [n for n, _l, _v in samples
+                if n == "minio_tpu_encode_gibps"]
 
 
 def test_4xx_export(client, traffic):
@@ -635,6 +648,220 @@ def test_exemplar_disarmed_zero_overhead(server, client):
             "exemplar captured while disarmed"
     finally:
         obs.set_exemplars(True, every=8)
+
+
+# ---------------------------------------------------------------------------
+# flight.span: the one span primitive (timeline, trace bus, device profile)
+# ---------------------------------------------------------------------------
+
+MX_BODY = bytes(range(256)) * (5 << 12)     # 5 MiB: five 1 MiB blocks
+
+
+@pytest.fixture(scope="module")
+def mx(tmp_path_factory):
+    """A server of its own on the per-object device-codec path that the
+    10 MiB cells take on the chip: `mxsum256` given explicitly (the CPU
+    default, sip256, takes the native C++ lane and never meets the
+    codec), 512 KiB shard rows (wider than the lane gate), and batches
+    of two blocks, so a 5 MiB object is three encode launches on PUT and
+    three read batches behind the read-ahead thread on GET."""
+    from minio_tpu.s3.server import build_server
+
+    root = tmp_path_factory.mktemp("obs-mx-drives")
+    srv = build_server([str(root / f"d{i}") for i in range(4)], ACCESS,
+                       SECRET)
+    for es in srv.obj.pools[0].sets:
+        es.bitrot_algorithm = "mxsum256"
+        es.batch_blocks = 2
+    base, loop = _serve(srv)
+    cl = SigV4Client(base, ACCESS, SECRET)
+    assert cl.put("/mxbkt").status_code == 200
+    # Compile every program the tests below launch, outside any session.
+    assert cl.put("/mxbkt/warm", data=MX_BODY).status_code == 200
+    assert cl.get("/mxbkt/warm").content == MX_BODY
+    yield cl, srv
+    loop.call_soon_threadsafe(loop.stop)
+
+
+def _timeline(cl, resp) -> dict:
+    doc = _perf_query(cl, traceid=resp.headers["x-amz-request-id"])
+    assert doc["timelines"], "no timeline recorded"
+    return doc["timelines"][0]
+
+
+@pytest.fixture(scope="module")
+def mx_profile(mx):
+    """One `tpu`-kind session (on this backend: a host trace) around one
+    PUT and one GET -> (PUT's id, GET's id, the /host:CPU plane's mtpu/
+    events as (name, line index, stats))."""
+    import io
+    import zipfile
+
+    from jax.profiler import ProfileData
+
+    cl, _srv = mx
+    r = cl.request("POST", "/minio/admin/v3/profiling/start",
+                   query={"profilerType": "tpu"})
+    assert r.status_code == 200, r.text
+    rp = cl.put("/mxbkt/profiled", data=MX_BODY)
+    rg = cl.get("/mxbkt/profiled")
+    assert rp.status_code == 200 and rg.content == MX_BODY
+    r = cl.get("/minio/admin/v3/profiling/download")
+    assert r.status_code == 200
+    outer = zipfile.ZipFile(io.BytesIO(r.content))
+    inner = zipfile.ZipFile(io.BytesIO(outer.read("local/tpu_trace.zip")))
+    pb = next(n for n in inner.namelist() if n.endswith(".xplane.pb"))
+    data = ProfileData.from_serialized_xspace(inner.read(pb))
+    host = next(p for p in data.planes if p.name == "/host:CPU")
+    events = [(e.name, li, dict(e.stats))
+              for li, ln in enumerate(host.lines) for e in ln.events
+              if e.name.startswith("mtpu/")]
+    return (rp.headers["x-amz-request-id"],
+            rg.headers["x-amz-request-id"], events)
+
+
+@pytest.mark.parametrize("span,verb", [
+    ("mtpu/rx_wait", "PUT"), ("mtpu/enc_dispatch", "PUT"),
+    ("mtpu/enc_wait", "PUT"), ("mtpu/verify_wait", "GET"),
+    ("mtpu/tx_send", "GET")])
+def test_device_profile_holds_the_request_spans(mx_profile, span, verb):
+    """During a session every span is also a TraceAnnotation on its
+    thread's line of the /host:CPU plane, carrying the request's id."""
+    put_id, get_id, events = mx_profile
+    want = put_id if verb == "PUT" else get_id
+    mine = [(li, st) for name, li, st in events
+            if name == span and st.get("trace_id") == want]
+    assert mine, (span, sorted({n for n, _l, _s in events}))
+    api = "PutObject" if verb == "PUT" else "GetObject"
+    assert all(st.get("api") == api for _li, st in mine), mine[:3]
+
+
+def test_device_profile_spans_share_the_id_across_threads(mx_profile):
+    """rx_wait runs on the event-loop thread, enc_wait on an executor
+    thread, verify_wait on the read-ahead thread: one request's spans on
+    different lines carry one trace_id."""
+    put_id, get_id, events = mx_profile
+    for rid, a, b in ((put_id, "mtpu/rx_wait", "mtpu/enc_wait"),
+                      (get_id, "mtpu/tx_send", "mtpu/verify_wait")):
+        lines = {name: {li for n, li, st in events
+                        if n == name and st.get("trace_id") == rid}
+                 for name in (a, b)}
+        assert lines[a] and lines[b], lines
+        assert lines[a].isdisjoint(lines[b]), lines
+
+
+def test_no_session_no_annotation_no_bus_span(mx):
+    """With no profiling session and no bus subscriber a PUT and a GET
+    construct no TraceAnnotation and no obs.Span; the spans still reach
+    the timeline."""
+    from minio_tpu.obs import Span, flight
+
+    cl, srv = mx
+    assert _wait_no_subscribers(srv.trace_bus), "stale trace subscriber"
+    assert not flight._PROFILING
+    ann, spans = flight.annotations, Span.allocated
+    rp = cl.put("/mxbkt/quiet", data=MX_BODY)
+    rg = cl.get("/mxbkt/quiet")
+    assert rp.status_code == 200 and rg.content == MX_BODY
+    assert flight.annotations == ann, "TraceAnnotation built with no session"
+    assert Span.allocated == spans, "obs.Span built with no subscriber"
+    assert {"enc_wait", "rx_wait"} <= {
+        s["stage"] for s in _timeline(cl, rp)["stages"]}
+
+
+def test_repeated_spans_accumulate_into_one_entry():
+    """A stage entered once per body chunk is `flight.span` like any
+    other: its repeats land as ONE entry (`dur` summed, `n` counted,
+    `start` the first), and with no session no annotation is built."""
+    from minio_tpu.obs import flight
+
+    tl = flight.begin("REPEAT", "GetObject")
+    assert tl is not None
+    try:
+        ann = flight.annotations
+        for _ in range(5):
+            with flight.span("tx_next"):
+                pass
+        (entry,) = [s for s in tl._stages if s[0] == "tx_next"]
+        assert entry[6] == 5 and entry[2] > 0 and not entry[3]
+        assert flight.annotations == ann
+    finally:
+        flight.end()
+
+
+def test_one_span_primitive_in_the_tree():
+    """No call to obs.span( outside minio_tpu/obs/, and TraceAnnotation
+    named in obs/flight.py only."""
+    import pathlib
+
+    pkg = pathlib.Path(__file__).resolve().parent.parent / "minio_tpu"
+    bus, ann = [], []
+    for path in pkg.rglob("*.py"):
+        rel = path.relative_to(pkg).as_posix()
+        text = path.read_text()
+        if "obs.span(" in text and not rel.startswith("obs/"):
+            bus.append(rel)
+        if "TraceAnnotation" in text and rel != "obs/flight.py":
+            ann.append(rel)
+    # admin/profiling.py's docstring names the class it arms.
+    assert bus == [] and ann in ([], ["admin/profiling.py"]), (bus, ann)
+
+
+@pytest.mark.parametrize("verb,parent,children", [
+    ("PUT", "rx_drain", ("rx_wait", "rx_hash", "rx_spool")),
+    ("PUT", "encode", ("enc_spawn", "enc_read", "enc_stage",
+                       "enc_dispatch", "enc_wait", "enc_feed", "enc_join")),
+    ("GET", "resp_drain", ("tx_next", "tx_send")),
+])
+def test_detail_spans_fit_inside_their_stage(mx, verb, parent, children):
+    """The new stages split an existing one where the work happens: per
+    request they sum to no more than it, each is ONE accumulated entry,
+    and the sequential stage keeps its name and extent (the stage-sum
+    fidelity test above passes unchanged)."""
+    cl, _srv = mx
+    key = f"/mxbkt/fit-{parent}"
+    r = cl.put(key, data=MX_BODY)
+    if verb == "GET":
+        r = cl.get(key)
+    assert r.status_code == 200
+    snap = _timeline(cl, r)
+    by: dict = {}
+    for s in snap["stages"]:
+        by.setdefault(s["stage"], []).append(s)
+    assert len(by[parent]) == 1 and by[parent][0]["seq"]
+    outer = by[parent][0]
+    inner = 0
+    for name in children:
+        assert len(by.get(name, [])) == 1, (name, sorted(by))
+        s = by[name][0]
+        assert not s["seq"] and s["n"] >= 1
+        assert s["start_ns"] >= outer["start_ns"]
+        inner += s["dur_ns"]
+    assert 0 < inner <= outer["dur_ns"], (inner, outer)
+    seq = _seq_sum_ns(snap)
+    assert abs(seq - snap["e2e_ns"]) <= 0.1 * snap["e2e_ns"]
+
+
+def test_timeline_entries_carry_start_parent_n(mx):
+    """/perf/timeline: every entry has its start offset from the
+    request's t0, a parent and a count; the GET's object-layer spans sit
+    under resp_drain, repeated ones are counted, not repeated."""
+    cl, _srv = mx
+    assert cl.put("/mxbkt/shape", data=MX_BODY).status_code == 200
+    snap = _timeline(cl, cl.get("/mxbkt/shape"))
+    assert snap["t0"] > 0
+    for s in snap["stages"]:
+        assert {"start_ns", "parent", "n"} <= set(s), s
+        assert 0 <= s["start_ns"] <= snap["e2e_ns"]
+    by = {s["stage"]: s for s in snap["stages"]}
+    for name in ("shard_read", "verify_wait", "decode", "readahead_wait"):
+        assert by[name]["parent"] == "resp_drain", by[name]
+    assert by["shard_read"]["n"] == 3 and by["decode"]["n"] == 3
+    assert by["auth"]["parent"] is None and by["auth"]["start_ns"] == 0
+    # Sequential segments tile the request: each starts where the last ended.
+    seq = [s for s in snap["stages"] if s["seq"]]
+    for a, b in zip(seq, seq[1:]):
+        assert abs(a["start_ns"] + a["dur_ns"] - b["start_ns"]) <= 1000
 
 
 def test_exposition_never_tears_under_mutation(client, traffic):
