@@ -103,6 +103,14 @@ _TENANT_LATENCY = obs.histogram(
 _TENANT_REQS = obs.counter(
     "minio_tpu_tenant_requests_total",
     "Requests by tenant and status class", ("tenant", "code"))
+# The streamed GET's drain (_get_object): executor hops, and the chunks
+# they carried to the loop. chunks / hops says how often grouping engages.
+_DRAIN_HOPS = obs.counter(
+    "minio_tpu_get_drain_hops_total",
+    "Executor round trips of streamed GET bodies").labels()
+_DRAIN_CHUNKS = obs.counter(
+    "minio_tpu_get_drain_chunks_total",
+    "Chunks those round trips handed to the event loop").labels()
 # Inline-object streams are plain list iterators (zero IO behind next()) —
 # the GET fast path detects them by type to drain on the event loop.
 _LIST_ITER = type(iter([]))
@@ -132,6 +140,20 @@ def _int_q(q: dict, name: str, default: int, lo: int = 0, hi: int = 100_000) -> 
     if not lo <= v <= hi:
         raise S3Error("InvalidArgument", f"{name} out of range")
     return v
+
+
+def _drain_group(it, budget: int) -> tuple[list, bool]:
+    """Blocking: pull chunks from `it` until they hold `budget` bytes or
+    it ends -> (chunks, ended). The end comes back with the last chunks:
+    no hop for a sentinel, unless the budget filled on the very last chunk
+    (then one more call returns ([], True))."""
+    chunks, size = [], 0
+    for chunk in it:
+        chunks.append(chunk)
+        size += len(chunk)
+        if size >= budget:
+            return chunks, False
+    return chunks, True
 
 
 async def _loop_lag_sampler(_app):
@@ -2641,6 +2663,15 @@ class S3Server:
     # same executor hop that opened them and returned as one body — the
     # per-chunk executor round trips dominate small-object GET latency.
     _GET_DRAIN_LIMIT = 256 << 10
+    # Larger bodies stream: one executor hop hands the loop every chunk
+    # the stream has up to this many bytes (_drain_group). An erasure
+    # stream's chunks are views of a read batch that is resident until
+    # its last view is sent, so grouping them holds nothing more; a
+    # transformed stream (decrypt, decompress, tier, gateway) yields fresh
+    # buffers, and this is the most a request keeps of them. 4 MiB: a
+    # quarter of a 16-block read batch, so an aligned read never waits on
+    # the next batch with chunks in hand; a 10 MiB object is 3 hops.
+    _GET_GROUP_BYTES = 4 << 20
 
     async def _get_object(self, request, bucket, key, opts, hdr, run):
         rng = request.headers.get("Range")
@@ -2724,19 +2755,24 @@ class S3Server:
         # sequential, so the copy is never entered concurrently): shard
         # reads run inside next() on the executor and their storage/RPC
         # records must keep this request's trace id.
-        drain_next = obs.ctx_wrap(lambda: next(it, None))
+        drain_group = obs.ctx_wrap(
+            lambda: _drain_group(it, self._GET_GROUP_BYTES))
         # resp_drain, split: the loop waits for the object layer's next
-        # chunk (drive read, verify, decode), then sends it.
-        while True:
+        # group of chunks (drive read, verify, decode), then sends them
+        # as they are: the views stay views, nothing is joined.
+        done = False
+        while not done:
             with flight.span("tx_next"):
-                chunk = await loop.run_in_executor(None, drain_next)
-            if chunk is None:
-                break
-            delay = self.bw_throttle.delay(bucket, len(chunk))
+                chunks, done = await loop.run_in_executor(None, drain_group)
+            _DRAIN_HOPS.inc()
+            _DRAIN_CHUNKS.inc(len(chunks))
+            delay = self.bw_throttle.delay(
+                bucket, sum(len(c) for c in chunks))
             if delay > 0:
                 await asyncio.sleep(delay)
             with flight.span("tx_send"):
-                await resp.write(chunk)
+                for chunk in chunks:
+                    await resp.write(chunk)
         with flight.span("tx_send"):
             await resp.write_eof()
         return resp

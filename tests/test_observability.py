@@ -807,17 +807,24 @@ def test_one_span_primitive_in_the_tree():
     assert bus == [] and ann in ([], ["admin/profiling.py"]), (bus, ann)
 
 
-@pytest.mark.parametrize("verb,parent,children", [
-    ("PUT", "rx_drain", ("rx_wait", "rx_hash", "rx_spool")),
+@pytest.mark.parametrize("verb,parent,children,counts", [
+    ("PUT", "rx_drain", ("rx_wait", "rx_hash", "rx_spool"), {}),
     ("PUT", "encode", ("enc_spawn", "enc_read", "enc_stage",
-                       "enc_dispatch", "enc_wait", "enc_feed", "enc_join")),
-    ("GET", "resp_drain", ("tx_next", "tx_send")),
+                       "enc_dispatch", "enc_wait", "enc_feed", "enc_join"),
+     {}),
+    # A 5 MiB body leaves in two groups (4 MiB, then the rest with the
+    # stream's end): two hops, and a send each plus write_eof. One entry
+    # a chunk would read 11 and 11 (five blocks of two data chunks).
+    ("GET", "resp_drain", ("tx_next", "tx_send"),
+     {"tx_next": 2, "tx_send": 3}),
 ])
-def test_detail_spans_fit_inside_their_stage(mx, verb, parent, children):
+def test_detail_spans_fit_inside_their_stage(mx, verb, parent, children,
+                                             counts):
     """The new stages split an existing one where the work happens: per
     request they sum to no more than it, each is ONE accumulated entry,
     and the sequential stage keeps its name and extent (the stage-sum
-    fidelity test above passes unchanged)."""
+    fidelity test above passes unchanged). GET's two are entered once a
+    group of chunks, not once a chunk."""
     cl, _srv = mx
     key = f"/mxbkt/fit-{parent}"
     r = cl.put(key, data=MX_BODY)
@@ -835,6 +842,7 @@ def test_detail_spans_fit_inside_their_stage(mx, verb, parent, children):
         assert len(by.get(name, [])) == 1, (name, sorted(by))
         s = by[name][0]
         assert not s["seq"] and s["n"] >= 1
+        assert s["n"] == counts.get(name, s["n"]), (name, s["n"])
         assert s["start_ns"] >= outer["start_ns"]
         inner += s["dur_ns"]
     assert 0 < inner <= outer["dur_ns"], (inner, outer)
