@@ -45,6 +45,7 @@ from minio_tpu.erasure.metadata import (
     run_bounded,
     shuffle_by_distribution,
 )
+from minio_tpu.erasure.writer_pool import shard_writers
 from minio_tpu.storage import healthcheck as _health
 from minio_tpu.erasure.types import (
     BucketInfo,
@@ -1932,16 +1933,14 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 while qs[i].get() is not _WRITE_SENTINEL:
                     pass
 
-        # One writer thread per drive, started per PUT: each start hands
-        # the GIL to the new thread and waits for it to say it runs.
+        # One writer per drive, each handed to a parked thread: a queue
+        # append and a wake-up, where starting a thread gives the GIL away
+        # and waits for it. ctx_wrap per job: a reused thread carries this
+        # request's trace context, never its last one's.
+        writers = shard_writers()
         with flight.span("enc_spawn", "erasure"):
-            threads = [
-                threading.Thread(target=obs.ctx_wrap(writer), args=(i, d),
-                                 daemon=True)
-                for i, d in enumerate(shuffled)
-            ]
-            for t in threads:
-                t.start()
+            jobs = [writers.submit(obs.ctx_wrap(writer), i, d)
+                    for i, d in enumerate(shuffled)]
 
         # Device-fused digests share the encode launch (ops/fused.py); any
         # other algorithm is hashed host-side per chunk.
@@ -2015,20 +2014,29 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                               timeout=0.1 if gave_up[i] else put_timeout)
                     except queue.Full:
                         gave_up[i] = True
-                # Bounded join: a healthy writer drains to its sentinel
+                # Bounded wait: a healthy writer drains to its sentinel
                 # well inside the deadline; a wedged one is declared
-                # timed out and left behind (daemon) rather than
-                # blocking the PUT forever.
+                # timed out and left behind rather than blocking the PUT
+                # forever (its thread parks again only if its call ever
+                # returns).
                 join_end = time.monotonic() + put_timeout
-                for i, t in enumerate(threads):
-                    t.join(timeout=0.1 if gave_up[i]
-                           else max(0.1, join_end - time.monotonic()))
-                    if t.is_alive():
+                for i, job in enumerate(jobs):
+                    try:
+                        exc = job.exception(
+                            timeout=0.1 if gave_up[i]
+                            else max(0.1, join_end - time.monotonic()))
+                    except _FutTimeout:
                         gave_up[i] = True
                         if errs[i] is None:
                             errs[i] = se.OperationTimedOut(
                                 msg="drive shard writer did not finish")
                             note_leaked_worker()
+                    else:
+                        # writer() records an Exception itself; what
+                        # reaches the future ended the job some other way.
+                        if exc is not None and errs[i] is None:
+                            errs[i] = se.FaultyDisk(
+                                f"drive shard writer died: {exc!r}")
         return total, md5.hexdigest(), errs
 
     def _inline_commit_fast(self, shuffled, bucket: str, obj: str,
