@@ -4,7 +4,8 @@ Aggregates concurrent codec work — PUT shard-encodes, GET
 reconstructions, bitrot verifies — from request threads into coalesced
 fused-kernel launches (batcher.py) staged through a ring of
 pre-allocated device-bound buffers (ring.py), instead of one dispatch
-per object.
+per object. Which work rides a lane and which launches directly is
+decided in one place, route.py.
 
 ON BY DEFAULT since the pipeline convergence (PR 12): the env gate is
 opt-OUT — `MTPU_BATCHED_DATAPLANE=0` restores per-object dispatch,

@@ -46,9 +46,11 @@ from minio_tpu.dataplane import ring
 from minio_tpu import obs
 from minio_tpu.obs import flight
 from minio_tpu.obs import kernel as obs_kernel
+from minio_tpu.ops import staging
 from minio_tpu import qos
 from minio_tpu.utils import admission
 from minio_tpu.utils import errors as se
+from minio_tpu.utils.shardmath import ceil_div as _ceil_div, pow2_bucket
 
 _CLOSE = object()
 
@@ -57,13 +59,6 @@ DEFAULT_VERIFY_ROWS = 128   # verify chunks per launch
 DEFAULT_MAX_WAIT_US = 500   # lone-request latency bound (microseconds)
 DEFAULT_QUEUE_CAP = 256     # bounded submission queue (requests)
 DEFAULT_RING_DEPTH = 4      # staging slots per lane (double buffer+)
-DEFAULT_MAX_WIDTH = 65536   # widest chunk the serving gate coalesces
-# Reconstruct lanes have a narrower CPU crossover than encode lanes:
-# per-row decode matrices make the coalesced kernel heavier per byte
-# (measured: +15% at 16 KiB chunks, -19% at 64 KiB on the 8-dev CPU
-# mesh), so heal/degraded-GET coalescing gates lower by default.
-# Accelerator deployments raise it (MTPU_DP_MAX_RECON_WIDTH).
-DEFAULT_MAX_RECON_WIDTH = 16384
 
 
 def _backend() -> str:
@@ -72,10 +67,6 @@ def _backend() -> str:
     from minio_tpu.ops import fused
 
     return fused._backend()
-
-
-def _ceil_div(a: int, b: int) -> int:
-    return -(-a // b)
 
 
 class _BaseKey(tuple):
@@ -149,17 +140,8 @@ class PendingBatchedEncode:
             parity, digs = req.future.result()
             if digs is not None and out_digs is None:
                 out_digs = []
-            for bi, block in enumerate(blocks):
-                s = lens[bi]
-                src = flats[bi] if flats[bi] is not None else block
-                mv = memoryview(src)
-                row = [mv[i * s:(i + 1) * s] for i in range(k)]
-                if m:
-                    row += [memoryview(parity[bi, j])[:s] for j in range(m)]
-                out_chunks.append(row)
-                if out_digs is not None:
-                    out_digs.append([digs[bi, i].tobytes()
-                                     for i in range(k + m)])
+            staging.encode_rows(k, m, blocks, lens, flats, parity, digs,
+                                out_chunks, out_digs)
         return out_chunks, out_digs
 
 
@@ -172,15 +154,12 @@ class PendingBatchedReconstruct:
     dispatch per object — parity with codec.begin_reconstruct's fused
     digests."""
 
-    def __init__(self, plane: "BatchPlane", targets: tuple[int, ...],
-                 chunk_lens: list[int], groups, with_digests: bool,
-                 digest_cap: int):
+    def __init__(self, targets: tuple[int, ...], chunk_lens: list[int],
+                 groups, with_digests: bool):
         self.targets = targets
-        self._plane = plane
         self._lens = chunk_lens
         self._groups = groups  # list of (request, nrows)
         self._digests = with_digests
-        self._cap = digest_cap
 
     def wait(self):
         t = len(self.targets)
@@ -190,14 +169,9 @@ class PendingBatchedReconstruct:
         for req, nrows in self._groups:
             res = req.future.result()
             rebuilt, digs = res if isinstance(res, tuple) else (res, None)
-            for r in range(nrows):
-                s = self._lens[bi]
-                out_chunks.append([rebuilt[r, ti, :s].tobytes()
-                                   for ti in range(t)])
-                if out_digs is not None:
-                    out_digs.append([digs[r, ti].tobytes()
-                                     for ti in range(t)])
-                bi += 1
+            staging.rebuilt_rows(rebuilt, digs, self._lens[bi:bi + nrows],
+                                 t, out_chunks, out_digs)
+            bi += nrows
         return out_chunks, out_digs
 
 
@@ -223,10 +197,6 @@ class BatchPlane:
             env("MTPU_DP_VERIFY_ROWS", str(DEFAULT_VERIFY_ROWS)))
         self.max_wait_s = max_wait_s if max_wait_s is not None else float(
             env("MTPU_DP_MAX_WAIT_US", str(DEFAULT_MAX_WAIT_US))) / 1e6
-        self.max_width = int(env("MTPU_DP_MAX_WIDTH",
-                                 str(DEFAULT_MAX_WIDTH)))
-        self.max_recon_width = int(env("MTPU_DP_MAX_RECON_WIDTH",
-                                       str(DEFAULT_MAX_RECON_WIDTH)))
         cap = queue_cap if queue_cap is not None else int(
             env("MTPU_DP_QUEUE", str(DEFAULT_QUEUE_CAP)))
         depth = ring_depth if ring_depth is not None else int(
@@ -268,21 +238,6 @@ class BatchPlane:
     # submission API (request threads)
     # ------------------------------------------------------------------
 
-    def accepts_chunk(self, s: int) -> bool:
-        """Serving-gate width check: the plane targets the small/mid
-        object regime where the per-launch tax dominates; blocks wider
-        than MTPU_DP_MAX_WIDTH already amortize their own launches in
-        per-object batches (and on CPU backends a coalesced wide launch
-        can LOSE to concurrent per-object ones — PERF.md). Integration
-        points fall back to per-object dispatch above the gate."""
-        return s <= self.max_width
-
-    def accepts_recon_chunk(self, s: int) -> bool:
-        """Reconstruct-lane width gate (MTPU_DP_MAX_RECON_WIDTH) — the
-        heal/degraded-GET analogue of accepts_chunk with the narrower
-        measured crossover."""
-        return s <= self.max_recon_width
-
     def begin_encode(self, k: int, m: int, block_size: int,
                      blocks: list[bytes],
                      with_digests: bool = False) -> PendingBatchedEncode:
@@ -312,18 +267,11 @@ class BatchPlane:
             lens: list[int] = []
             flats: list[np.ndarray | None] = []
             views: list[np.ndarray] = []
-            for bi, block in enumerate(grp):
-                s = _ceil_div(len(block), k)
+            for block in grp:
+                s, flat, view = staging.split_block(block, k)
                 lens.append(s)
-                if len(block) == k * s:
-                    flats.append(None)
-                    views.append(np.frombuffer(block, dtype=np.uint8)
-                                 .reshape(k, s))
-                else:
-                    flat = np.zeros(k * s, dtype=np.uint8)
-                    flat[:len(block)] = np.frombuffer(block, dtype=np.uint8)
-                    flats.append(flat)
-                    views.append(flat.reshape(k, s))
+                flats.append(flat)
+                views.append(view)
 
             def stage(slot, row0, views=views, lens=lens):
                 for bi, v in enumerate(views):
@@ -392,23 +340,11 @@ class BatchPlane:
         n = k + m
         if not shard_chunks:
             return []
-        want = list(range(n) if need_all else range(k))
-        chunk_lens = [_ceil_div(bl, k) for bl in block_lens]
-        per_block: list[tuple[tuple[int, ...], tuple[int, ...]]] = []
-        t_max = 0
-        for bi, row in enumerate(shard_chunks):
-            present = [i for i in range(n) if row[i] is not None]
-            if len(present) < k:
-                raise se.InsufficientReadQuorum(
-                    "", "", f"block {bi}: only {len(present)} of {k} shards")
-            survivors = tuple(present[:k])
-            targets = tuple(i for i in want if row[i] is None)
-            per_block.append((survivors, targets))
-            t_max = max(t_max, len(targets))
+        want, per_block, t_max = staging.plan_rebuild(
+            shard_chunks, k, n, need_all)
         if t_max == 0:
             return [[row[i] for i in want] for row in shard_chunks]  # type: ignore[misc]
-
-        from minio_tpu.utils.shardmath import pow2_bucket
+        chunk_lens = [_ceil_div(bl, k) for bl in block_lens]
 
         t_pad = pow2_bucket(t_max)  # pow2 target-count lane
         width = ring.width_bucket(max(chunk_lens))
@@ -453,14 +389,8 @@ class BatchPlane:
 
         out: list[list[bytes]] = []
         for req, rows_grp, pb_grp, lens_grp in groups:
-            rebuilt = req.future.result()
-            for bi, row in enumerate(rows_grp):
-                _survivors, targets = pb_grp[bi]
-                s = lens_grp[bi]
-                fixed = list(row)
-                for ti, shard_idx in enumerate(targets):
-                    fixed[shard_idx] = rebuilt[bi, ti, :s].tobytes()
-                out.append([fixed[i] for i in want])
+            out += staging.patch_rows(rows_grp, pb_grp, lens_grp,
+                                      req.future.result(), want)
         return out
 
     def begin_reconstruct(self, k: int, m: int, block_size: int,
@@ -479,23 +409,10 @@ class BatchPlane:
         coalesced single launches instead of one dispatch per object.
         Same result contract as codec.begin_reconstruct."""
         from minio_tpu.ops import rs_xla
-        from minio_tpu.utils.shardmath import pow2_bucket
-
         n = k + m
         if not shard_chunks:
-            return PendingBatchedReconstruct(self, tuple(targets), [], [],
-                                             False, 0)
-        pattern = [c is not None for c in shard_chunks[0]]
-        for row in shard_chunks[1:]:
-            if [c is not None for c in row] != pattern:
-                raise ValueError(
-                    "begin_reconstruct needs one failure pattern per "
-                    "batch (use decode_blocks for mixed patterns)")
-        present = [i for i in range(n) if pattern[i]]
-        if len(present) < k:
-            raise se.InsufficientReadQuorum(
-                "", "", f"only {len(present)} of {k} shards available")
-        survivors = tuple(present[:k])
+            return PendingBatchedReconstruct(tuple(targets), [], [], False)
+        survivors = staging.one_pattern_survivors(shard_chunks, k, n)
         targets = tuple(targets)
         chunk_lens = [_ceil_div(bl, k) for bl in block_lens]
         t_pad = pow2_bucket(max(1, len(targets)))
@@ -536,9 +453,8 @@ class BatchPlane:
             req = CodecRequest(base, len(rows_grp), stage, finish)
             self._submit(req)
             groups.append((req, len(rows_grp)))
-        return PendingBatchedReconstruct(self, targets, chunk_lens,
-                                         groups, with_digests,
-                                         _ceil_div(block_size, k))
+        return PendingBatchedReconstruct(targets, chunk_lens, groups,
+                                         with_digests)
 
     # ------------------------------------------------------------------
     # plumbing

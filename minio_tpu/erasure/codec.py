@@ -21,7 +21,7 @@ import numpy as np
 
 from minio_tpu.obs import flight
 from minio_tpu.obs import kernel as obs_kernel
-from minio_tpu.ops import rs_xla
+from minio_tpu.ops import rs_xla, staging
 from minio_tpu.utils.shardmath import ceil_div as _ceil_div
 from minio_tpu.utils import shardmath
 
@@ -77,7 +77,7 @@ class PendingEncode:
     """
 
     def __init__(self, codec: "ErasureCodec", blocks: list[bytes],
-                 chunk_lens: list[int], padded: list[bytes | None],
+                 chunk_lens: list[int], padded: list[np.ndarray | None],
                  parity_dev, digs_dev):
         self._codec = codec
         self._blocks = blocks
@@ -98,16 +98,8 @@ class PendingEncode:
                     if self._digs_dev is not None else None)
         out_chunks: list[list[memoryview]] = []
         out_digs: list[list[bytes]] | None = [] if digs is not None else None
-        for bi, block in enumerate(self._blocks):
-            s = self._lens[bi]
-            src = self._padded[bi] if self._padded[bi] is not None else block
-            mv = memoryview(src)
-            chunks = [mv[i * s:(i + 1) * s] for i in range(k)]
-            if m:
-                chunks += [memoryview(parity[bi, j])[:s] for j in range(m)]
-            out_chunks.append(chunks)
-            if out_digs is not None:
-                out_digs.append([bytes(digs[bi, i]) for i in range(k + m)])
+        staging.encode_rows(k, m, self._blocks, self._lens, self._padded,
+                            parity, digs, out_chunks, out_digs)
         return out_chunks, out_digs
 
 
@@ -128,12 +120,8 @@ class PendingDecode:
         digs = (np.asarray(self._digs_dev)
                 if self._digs_dev is not None else None)
         out_chunks, out_digs = [], [] if digs is not None else None
-        for bi, s in enumerate(self._lens):
-            out_chunks.append([rebuilt[bi, ti, :s].tobytes()
-                               for ti in range(len(self.targets))])
-            if out_digs is not None:
-                out_digs.append([bytes(digs[bi, ti])
-                                 for ti in range(len(self.targets))])
+        staging.rebuilt_rows(rebuilt, digs, self._lens, len(self.targets),
+                             out_chunks, out_digs)
         return out_chunks, out_digs
 
 
@@ -195,26 +183,22 @@ class ErasureCodec:
         # mix and mxsum digests are cap-invariant; pad rows are zeros
         # with chunk_len 0 and every consumer iterates real blocks only.
         chunk_lens: list[int] = []
+        padded: list[np.ndarray | None] = []
+        views: list[np.ndarray] = []
         for bi, block in enumerate(blocks):
             if not 0 < len(block) <= self.block_size:
                 raise ValueError(f"block {bi} size {len(block)}")
-            chunk_lens.append(_ceil_div(len(block), self.k))
+            s, flat, view = staging.split_block(block, self.k)
+            chunk_lens.append(s)
+            padded.append(flat)
+            views.append(view)
         rows = fused.bucket_rows(len(blocks))
         s_stage = min(s_full, fused.bucket_width(max(chunk_lens)))
         batch = np.empty((rows, self.k, s_stage), dtype=np.uint8)
-        padded: list[bytes | None] = []
-        for bi, block in enumerate(blocks):
+        for bi, view in enumerate(views):
             s = chunk_lens[bi]
-            if s == s_stage and len(block) == self.k * s_stage:
-                padded.append(None)
-                batch[bi] = np.frombuffer(block, dtype=np.uint8).reshape(
-                    self.k, s_stage)
-            else:
-                flat = np.zeros(self.k * s, dtype=np.uint8)
-                flat[: len(block)] = np.frombuffer(block, dtype=np.uint8)
-                padded.append(flat.tobytes())
-                batch[bi, :, :s] = flat.reshape(self.k, s)
-                batch[bi, :, s:] = 0
+            batch[bi, :, :s] = view
+            batch[bi, :, s:] = 0
         if rows != len(blocks):
             batch[len(blocks):] = 0
         return batch, chunk_lens, padded
@@ -301,23 +285,12 @@ class ErasureCodec:
         import jax.numpy as jnp
 
         from minio_tpu.ops import fused
-        from minio_tpu.utils import errors as se
 
         if not shard_chunks:
             return PendingDecode(tuple(targets), [], None, None)
         n = self.k + self.m
         s_full = self.shard_size()
-        pattern = [c is not None for c in shard_chunks[0]]
-        for row in shard_chunks[1:]:
-            if [c is not None for c in row] != pattern:
-                raise ValueError(
-                    "begin_reconstruct needs one failure pattern per batch "
-                    "(use decode_blocks for mixed patterns)")
-        present = [i for i in range(n) if pattern[i]]
-        if len(present) < self.k:
-            raise se.InsufficientReadQuorum(
-                "", "", f"only {len(present)} of {self.k} shards available")
-        survivors = tuple(present[: self.k])
+        survivors = staging.one_pattern_survivors(shard_chunks, self.k, n)
         chunk_lens = [_ceil_div(bl, self.k) for bl in block_lens]
         # Survivor-compacted staging ([B, k, S], no dead parity rows) and
         # the decode matrix as runtime data — the failure pattern stays
@@ -402,14 +375,9 @@ class ErasureCodec:
         rebuilt = np.asarray(
             rs_xla.gf2_matmul_with_weights(batch, w, len(targets))
         )
-        out = []
-        for bi, row in enumerate(shard_chunks):
-            s = chunk_lens[bi]
-            fixed = list(row)
-            for ti, shard_idx in enumerate(targets):
-                fixed[shard_idx] = rebuilt[bi, ti, :s].tobytes()
-            out.append([fixed[i] for i in want])
-        return out
+        return staging.patch_rows(
+            shard_chunks, [(survivors, targets)] * len(shard_chunks),
+            chunk_lens, rebuilt, want)
 
     def decode_blocks_multi(
         self,
@@ -423,34 +391,18 @@ class ErasureCodec:
         TPU-native form of healing many objects with differing drive states
         in a single batched solve (cmd/erasure-healing.go heals pattern by
         pattern)."""
-        from minio_tpu.utils import errors as se
-
         from minio_tpu.ops import fused
 
         n = self.k + self.m
         if not shard_chunks:
             return []
-        want = list(range(n) if need_all else range(self.k))
+        want, per_block, t_max = staging.plan_rebuild(
+            shard_chunks, self.k, n, need_all)
+        if t_max == 0:
+            return [[row[i] for i in want] for row in shard_chunks]  # type: ignore[misc]
         chunk_lens = [_ceil_div(bl, self.k) for bl in block_lens]
         s_stage = min(self.shard_size(),
                       fused.bucket_width(max(chunk_lens)))
-
-        per_block: list[tuple[tuple[int, ...], tuple[int, ...]]] = []
-        t_max = 1
-        for bi, row in enumerate(shard_chunks):
-            present = [i for i in range(n) if row[i] is not None]
-            if len(present) < self.k:
-                raise se.InsufficientReadQuorum(
-                    "", "",
-                    f"block {bi}: only {len(present)} of {self.k} shards")
-            survivors = tuple(present[: self.k])
-            targets = tuple(i for i in want if row[i] is None)
-            per_block.append((survivors, targets))
-            t_max = max(t_max, len(targets))
-
-        if all(not t for _, t in per_block):
-            return [[row[i] for i in want] for row in shard_chunks]  # type: ignore[misc]
-
         batch = np.zeros((len(shard_chunks), self.k, s_stage),
                          dtype=np.uint8)
         weights = np.zeros((len(shard_chunks), self.k * 8, t_max * 8),
@@ -464,12 +416,5 @@ class ErasureCodec:
                 w = rs_xla._decode_weights_np(self.k, n, survivors, targets)
                 weights[bi, :, : len(targets) * 8] = w
         rebuilt = np.asarray(rs_xla.gf2_matmul_multi(batch, weights, t_max))
-        out = []
-        for bi, row in enumerate(shard_chunks):
-            _, targets = per_block[bi]
-            s = chunk_lens[bi]
-            fixed = list(row)
-            for ti, shard_idx in enumerate(targets):
-                fixed[shard_idx] = rebuilt[bi, ti, :s].tobytes()
-            out.append([fixed[i] for i in want])
-        return out
+        return staging.patch_rows(shard_chunks, per_block, chunk_lens,
+                                  rebuilt, want)

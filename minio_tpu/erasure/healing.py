@@ -28,7 +28,8 @@ import threading
 import uuid
 from dataclasses import dataclass, field
 
-from minio_tpu import dataplane, obs
+from minio_tpu import obs
+from minio_tpu.dataplane import route
 from minio_tpu.erasure.codec import ErasureCodec
 from minio_tpu.erasure.metadata import parallel_map, shuffle_by_distribution
 from minio_tpu.ops import bitrot
@@ -374,24 +375,12 @@ class HealingMixin:
                                        targets, sys_vol, tmp_dirs, pool)
         use_fused = algo == "mxsum256"
         t_tuple = tuple(targets)
-        # Batched data plane: a whole-set heal's reconstructs coalesce
-        # onto the mixed-failure-pattern lanes (per-row decode matrices
-        # ride as data), sharing launches with concurrent heals AND
-        # degraded GETs instead of one dispatch per object; the
-        # per-object codec path stays the fallback and the oracle.
-        plane = dataplane.maybe_plane() if m else None
+        # Lane or direct launch: dataplane/route.py. On the lanes a
+        # whole-set heal shares launches with concurrent heals and
+        # degraded GETs (per-row decode matrices ride as data).
 
         def begin_rebuild(rows, block_lens):
-            if (plane is not None and block_lens
-                    and plane.accepts_recon_chunk(
-                        -(-max(block_lens) // k))):
-                try:
-                    return plane.begin_reconstruct(
-                        k, m, latest.erasure.block_size, rows,
-                        block_lens, t_tuple, with_digests=use_fused)
-                except se.OperationTimedOut:
-                    pass  # plane saturated: per-object dispatch serves
-            return codec.begin_reconstruct(rows, block_lens, t_tuple,
+            return route.begin_reconstruct(codec, rows, block_lens, t_tuple,
                                            with_digests=use_fused)
 
         try:

@@ -29,7 +29,8 @@ import uuid
 from concurrent.futures import TimeoutError as _FutTimeout
 from typing import BinaryIO, Iterator
 
-from minio_tpu import dataplane, hottier, metaplane, obs
+from minio_tpu import hottier, metaplane, obs
+from minio_tpu.dataplane import route
 from minio_tpu.obs import flight
 from minio_tpu.erasure.codec import DEFAULT_BLOCK_SIZE, ErasureCodec
 from minio_tpu.erasure import listing
@@ -998,7 +999,7 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         The `decode` span closes before the first slice is handed out: it
         must not stay open while the caller sends."""
         with flight.span("decode", "erasure"):
-            decoded = self._decode_rows(codec, rows, block_lens)
+            decoded = route.decode_blocks(codec, rows, block_lens)
         for j, b in enumerate(batch_ids):
             blk_start = b * block_size
             lo = max(offset, blk_start) - blk_start
@@ -1477,38 +1478,12 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 raise se.FileCorrupt(f"shard {i}: {e}") from e
         return results
 
-    def _decode_rows(self, codec: ErasureCodec, rows, lens):
-        """GET-path reconstruction: through the batched plane when
-        enabled (concurrent GETs with even DIFFERENT failure patterns
-        share one launch — per-row decode matrices ride as data), else
-        the per-object codec path."""
-        plane = dataplane.maybe_plane() if codec.m else None
-        if plane is not None and lens and plane.accepts_recon_chunk(
-                -(-max(lens) // codec.k)):
-            try:
-                return plane.decode_blocks(codec.k, codec.m,
-                                           codec.block_size, rows, lens)
-            except se.OperationTimedOut:
-                pass  # plane saturated: per-object dispatch still serves
-        return codec.decode_blocks(rows, lens)
-
     def _verify_records(self, records, codec, readers, dead,
                         corrupt=None) -> None:
         """One batched mxsum256 launch over every chunk just read; a digest
         mismatch marks the drive dead and retriggers shard selection."""
-        from minio_tpu.ops import fused
-
-        plane = dataplane.maybe_plane()
-        got = None
-        if plane is not None and plane.accepts_chunk(codec.shard_size()):
-            try:
-                got = plane.digest_chunks([c for _i, _w, c in records],
-                                          codec.shard_size())
-            except se.OperationTimedOut:
-                got = None  # plane saturated: per-object launch below
-        if got is None:
-            got = fused.digest_chunks_host([c for _i, _w, c in records],
-                                           codec.shard_size())
+        got = route.digest_chunks([c for _i, _w, c in records],
+                                  codec.shard_size())
         for ri, (i, want, _chunk) in enumerate(records):
             if got[ri] != want:
                 dead.add(i)
@@ -1945,19 +1920,6 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         # Device-fused digests share the encode launch (ops/fused.py); any
         # other algorithm is hashed host-side per chunk.
         use_fused = self.bitrot_algorithm == "mxsum256"
-        # Batched data plane (MTPU_BATCHED_DATAPLANE=1): concurrent PUTs
-        # coalesce their encode launches; per-object dispatch is the
-        # fallback (and the bit-exactness oracle). Parity-less
-        # geometries stay per-object (nothing to coalesce but digests).
-        plane = dataplane.maybe_plane() if codec.m else None
-
-        def begin_encode(blocks: list[bytes]):
-            if plane is not None and plane.accepts_chunk(
-                    -(-max(len(b) for b in blocks) // codec.k)):
-                return plane.begin_encode(codec.k, codec.m,
-                                          codec.block_size, blocks,
-                                          with_digests=use_fused)
-            return codec.begin_encode(blocks, with_digests=use_fused)
         bitrot_algo = bitrot.get_algorithm(self.bitrot_algorithm)
         md5 = hashlib.md5()
         total = 0
@@ -1995,7 +1957,8 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 total += len(block)
                 batch.append(block)
                 if len(batch) >= self.batch_blocks:
-                    pending.append(begin_encode(batch))
+                    pending.append(route.begin_encode(
+                        codec, batch, with_digests=use_fused))
                     batch = []
                     if len(pending) >= pipeline_depth:
                         drain_one()
@@ -2003,7 +1966,8 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 with flight.span("enc_read", "erasure"):
                     block = _read_full(data, remaining)
             if batch:
-                pending.append(begin_encode(batch))
+                pending.append(route.begin_encode(
+                    codec, batch, with_digests=use_fused))
             while pending:
                 drain_one()
         finally:

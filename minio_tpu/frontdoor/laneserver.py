@@ -7,8 +7,8 @@ traffic from every worker coalesces with the owner's own request
 threads into shared fused-kernel launches.
 
 `LaneClient` runs inside every other worker and implements the subset
-of the `BatchPlane` surface the serving integration points call
-(`accepts_chunk`, `begin_encode`, `digest_chunks`, `decode_blocks`,
+of the `BatchPlane` surface that `dataplane/route.py` calls
+(`begin_encode`, `digest_chunks`, `decode_blocks`,
 `begin_reconstruct`). Encode, digest and heal-shaped reconstruct
 batches ride the ring (OP_RECONSTRUCT: one failure pattern per batch,
 so a whole-set heal running in ANY worker coalesces into the owner's
@@ -246,12 +246,6 @@ class LaneClient:
         from minio_tpu import dataplane
 
         return dataplane.get_plane()
-
-    def accepts_chunk(self, s: int) -> bool:
-        return self.local().accepts_chunk(s)
-
-    def accepts_recon_chunk(self, s: int) -> bool:
-        return self.local().accepts_recon_chunk(s)
 
     def decode_blocks(self, *a, **kw):
         return self.local().decode_blocks(*a, **kw)

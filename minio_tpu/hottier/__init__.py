@@ -9,8 +9,8 @@ zero drive opens, no quorum fan-out, no per-request host reassembly.
 
 Gate: `MTPU_HOTTIER=1` (opt-in). The drive path is never removed — it
 is the fallback on every miss AND the bit-exactness oracle
-(tests/test_hottier.py, bench.py hot_get). Correctness never rests on
-invalidation timeliness: a tier hit requires the *freshly elected*
+(tests/test_hottier.py). Correctness never rests on invalidation
+timeliness: a tier hit requires the *freshly elected*
 FileInfo (signature-validated by the metaplane set cache when armed)
 to match the resident entry's identity exactly, so a stale entry can
 only ever miss, never serve.

@@ -1,7 +1,7 @@
 """Per-host calibration profiles: was this node tuned for THIS host?
 
-The performance gates shipped in env defaults (`MTPU_DP_MAX_WIDTH`,
-`MTPU_DP_MAX_RECON_WIDTH`, the hedge-delay policy) were measured on a
+The performance gates shipped as constants (the two lane-width gates
+of dataplane/route.py, the hedge-delay policy) were measured on a
 specific host class; a node image moved to different hardware silently
 serves with the wrong crossover points. This module makes that drift
 observable:
@@ -16,11 +16,10 @@ observable:
   a mismatch raises `minio_tpu_calibration_stale` to 1 (the stored
   profile is left in place as the tuning evidence) instead of silently
   serving gates tuned for other hardware.
-- `bench.py` stamps `fingerprint()` into every BENCH row so a result
-  file is forever attributable to the host that produced it, and
-  `publish_build_info()` exposes the standing
+- `publish_build_info()` exposes the standing
   `minio_tpu_build_info{version,platform,devices,device_kind}`
-  info-gauge.
+  info-gauge (a benchmark run, `python3 benchmarks/run.py`, names its
+  device in every result line itself).
 
 Schema is documented in docs/SLO.md (calibration section).
 """
@@ -115,13 +114,15 @@ def fingerprint(probe_root: str | None = None) -> dict:
 
 def gates() -> dict:
     """The tuned performance gates currently in force — the values the
-    fingerprint vouches for. Defaults mirror dataplane/batcher.py and
-    the hedge policy in erasure/objects.py."""
-    env = os.environ.get
+    fingerprint vouches for: the two lane-width constants of
+    dataplane/route.py, under the names of the options they replaced so
+    that a stored profile still compares, and the hedge policy in
+    erasure/objects.py."""
+    from minio_tpu.dataplane import route
+
     return {
-        "MTPU_DP_MAX_WIDTH": int(env("MTPU_DP_MAX_WIDTH", "65536")),
-        "MTPU_DP_MAX_RECON_WIDTH": int(
-            env("MTPU_DP_MAX_RECON_WIDTH", "16384")),
+        "MTPU_DP_MAX_WIDTH": route.ENCODE_GATE,
+        "MTPU_DP_MAX_RECON_WIDTH": route.RECON_GATE,
         # The hedge delay is an EWMA policy (4x rolling shard latency),
         # only a fixed number when an operator pins it.
         "hedge_delay": "adaptive-ewma-4x",

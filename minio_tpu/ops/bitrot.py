@@ -283,39 +283,29 @@ class BitrotReader:
 
 
 def verify_shard_file(src: BinaryIO, data_size: int, shard_size: int,
-                      algorithm: str = DEFAULT_ALGORITHM) -> None:
+                      algorithm: str = DEFAULT_ALGORITHM,
+                      digest_chunks: Callable[[list, int], list[bytes]]
+                      | None = None) -> None:
     """Whole-file deep verify (reference VerifyFile, cmd/xl-storage.go:2179).
 
     mxsum256 files verify in batched device launches (32 chunks per
     launch) — the host fallback math is a slow per-chunk matvec, and deep
-    scans touch every byte of every shard."""
+    scans touch every byte of every shard. `digest_chunks(chunks, cap)`
+    computes them: by default one launch a group
+    (fused.digest_chunks_host); storage/local.py passes the routed one,
+    so concurrent scanner and heal workers share lane launches."""
     reader = BitrotReader(src, data_size, shard_size, algorithm)
     if algorithm == "mxsum256" and data_size:
-        from minio_tpu import dataplane
-        from minio_tpu.ops import fused
+        if digest_chunks is None:
+            from minio_tpu.ops import fused
 
-        # Deep scans from concurrent scanner/heal workers coalesce their
-        # verify launches through the batched plane when it is enabled.
-        plane = dataplane.maybe_plane()
-        if plane is not None and not plane.accepts_chunk(shard_size):
-            plane = None
+            digest_chunks = fused.digest_chunks_host
         n_chunks = -(-data_size // shard_size)
         group = 32
         for start in range(0, n_chunks, group):
             records = [reader.read_record(ci)
                        for ci in range(start, min(start + group, n_chunks))]
-            cs = [c for _w, c in records]
-            if plane is not None:
-                try:
-                    got = plane.digest_chunks(cs, shard_size)
-                except se.OperationTimedOut:
-                    # Plane saturated (bounded-queue backpressure): fall
-                    # back to the per-object launch rather than letting
-                    # healing.py read load on the SHARED plane as a
-                    # per-drive failure and mark healthy drives offline.
-                    got = fused.digest_chunks_host(cs, shard_size)
-            else:
-                got = fused.digest_chunks_host(cs, shard_size)
+            got = digest_chunks([c for _w, c in records], shard_size)
             for ci, ((want, _c), g) in enumerate(zip(records, got),
                                                  start=start):
                 if g != want:

@@ -27,6 +27,7 @@ from collections import OrderedDict
 from typing import BinaryIO, Iterable, Iterator
 
 from minio_tpu import obs
+from minio_tpu.dataplane import route
 from minio_tpu.ops import bitrot
 from minio_tpu.storage.api import (
     MARKER_GROUP_PAD,
@@ -1165,7 +1166,8 @@ class LocalDrive(StorageAPI):
             shard_data_size = fi.erasure.shard_file_size(part.size)
             rel = f"{path}/{fi.data_dir}/part.{part.number}"
             with self.read_file_stream(volume, rel) as f:
-                bitrot.verify_shard_file(f, shard_data_size, shard_size, algo)
+                bitrot.verify_shard_file(f, shard_data_size, shard_size, algo,
+                                         digest_chunks=route.digest_chunks)
 
     def walk_dir(self, volume: str, prefix: str = "",
                  start_after: str = "") -> Iterator[WalkEntry]:

@@ -3,8 +3,8 @@
 The codec lanes mint one compiled program per (op, k, m, pow2 width,
 pow2 rows) (dataplane/ring.py, ops/fused.py); a fresh process otherwise
 re-compiles each of them on first use. s3/server.py main(),
-frontdoor/worker.py main(), bench.py and chip_smoke.py call enable()
-before first device use.
+frontdoor/worker.py main() and chip_smoke.py call enable() before
+first device use (so does the server `python3 benchmarks/run.py` starts).
 
 Placement comes from OUTSIDE when it is given: with
 JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and no directory is

@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 from minio_tpu import dataplane
-from minio_tpu.dataplane import ring
+from minio_tpu.dataplane import ring, route
 from minio_tpu.dataplane.batcher import BatchPlane
 from minio_tpu.erasure.codec import ErasureCodec
 from minio_tpu.ops import fused
@@ -294,14 +294,18 @@ def test_deep_verify_routes_through_plane(tmp_path, monkeypatch):
         for off in range(0, len(data), shard_size):
             w.write(data[off:off + shard_size])
         before = dataplane.get_plane().stats()["launches"]
-        bitrot.verify_shard_file(buf, len(data), shard_size, "mxsum256")
+        # As storage/local.py verify_file calls it: ops/ itself knows
+        # no plane, the caller hands it the routed digest.
+        bitrot.verify_shard_file(buf, len(data), shard_size, "mxsum256",
+                                 digest_chunks=route.digest_chunks)
         assert dataplane.get_plane().stats()["launches"] > before
         # Corruption still raises through the coalesced path.
         raw = bytearray(buf.getvalue())
         raw[40] ^= 0xFF
         with pytest.raises(se.FileCorrupt):
             bitrot.verify_shard_file(io.BytesIO(bytes(raw)), len(data),
-                                     shard_size, "mxsum256")
+                                     shard_size, "mxsum256",
+                                     digest_chunks=route.digest_chunks)
     finally:
         dataplane.reset_global()
 

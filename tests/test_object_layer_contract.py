@@ -19,7 +19,11 @@ from minio_tpu.layer import ObjectLayer
 from minio_tpu.storage.local import LocalDrive
 from minio_tpu.utils import errors as se
 
-BACKENDS = ["fs", "erasure4", "erasure-sets8"]
+# "erasure4-mxsum256" is the configuration the chip serves: the device
+# checksum sends PUT, GET and heal through dataplane/route.py and the
+# fused launches. Without an algorithm a CPU backend defaults to sip256,
+# whose objects ride the C++ host lane.
+BACKENDS = ["fs", "erasure4", "erasure4-mxsum256", "erasure-sets8"]
 
 
 @pytest.fixture(params=BACKENDS)
@@ -31,6 +35,9 @@ def layer(request, tmp_path):
     elif kind == "erasure4":
         drives = [LocalDrive(str(tmp_path / f"d{i}")) for i in range(4)]
         obj = ErasureObjects(drives, parity=2)
+    elif kind == "erasure4-mxsum256":
+        drives = [LocalDrive(str(tmp_path / f"d{i}")) for i in range(4)]
+        obj = ErasureObjects(drives, parity=2, bitrot_algorithm="mxsum256")
     else:
         drives = [LocalDrive(str(tmp_path / f"d{i}")) for i in range(8)]
         obj = ErasureServerPools([ErasureSets(drives, set_drive_count=4)])

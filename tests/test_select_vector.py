@@ -181,7 +181,7 @@ def _best_of(fn, reps: int = 2) -> tuple[float, bytes]:
     """min-of-N wall time: under full-suite load a single-shot timing
     measures the scheduler, not the engine — the minimum is the run
     that dodged preemption, which is the engine's actual cost (the
-    PR 12 flake note; same discipline as bench.py's median-of-N)."""
+    PR 12 flake note)."""
     import time
 
     best, out = float("inf"), b""

@@ -56,19 +56,10 @@ KNOB_DOCS: dict[str, tuple[str, str]] = {
     "MTPU_DP_LANE_BLOCKS": (
         "DATAPLANE.md",
         "Encode/reconstruct rows coalesced per device launch."),
-    "MTPU_DP_MAX_RECON_WIDTH": (
-        "DATAPLANE.md",
-        "Widest chunk (bytes) the reconstruct lane coalesces — lower "
-        "than the serving gate by default (wide-chunk batching loses "
-        "on CPU); accelerator deployments raise it."),
     "MTPU_DP_MAX_WAIT_US": (
         "DATAPLANE.md",
         "Lone-request latency bound: microseconds a lane waits to "
         "fill a batch before launching anyway."),
-    "MTPU_DP_MAX_WIDTH": (
-        "DATAPLANE.md",
-        "Widest chunk (bytes) the serving-path encode/decode gate "
-        "coalesces."),
     "MTPU_DP_QUEUE": (
         "DATAPLANE.md",
         "Bounded batch-lane submission queue (requests); a full queue "

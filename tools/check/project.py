@@ -19,7 +19,7 @@ summary:
   `*_REGISTRY` dict literals) and every module-qualified reference to
   their members (MTPU009).
 
-The index is cached two ways so `bench.py check_overhead` holds its
+The index is cached two ways so a warm `python -m tools.check` holds its
 10 s budget and `--changed` stays a ~seconds pre-commit lane:
 
 - on disk at `<root>/.mtpu-check-cache.json` keyed by each file's
