@@ -21,7 +21,8 @@ import urllib.parse
 import uuid
 from typing import Iterator
 
-from aiohttp import web
+from aiohttp import http_writer, web, web_response
+from aiohttp.http_writer import StreamWriter
 
 from minio_tpu import obs, qos
 from minio_tpu.obs import flight
@@ -111,6 +112,23 @@ _DRAIN_HOPS = obs.counter(
 _DRAIN_CHUNKS = obs.counter(
     "minio_tpu_get_drain_chunks_total",
     "Chunks those round trips handed to the event loop").labels()
+_VECTORED_GROUPS = obs.counter(
+    "minio_tpu_get_vectored_groups_total",
+    "Round trips whose chunks left the event loop as one vectored "
+    "write (transport.writelines), not one write a chunk").labels()
+# CPython 3.12's selector socket transport sends writelines' buffers as
+# they are with one sendmsg (up to IOV_MAX of them), where write() is one
+# send a call. Only that writelines is taken for vectored (_vectored_writer):
+# the base class's and the TLS transport's join or copy.
+try:
+    from asyncio.selector_events import _HAS_SENDMSG, _SelectorSocketTransport
+except ImportError:  # another asyncio: every group is written chunk by chunk
+    _HAS_SENDMSG, _SelectorSocketTransport = False, None
+# On CPython 3.12.0-3.12.8 and 3.13.0-3.13.1 that writelines never pauses
+# the protocol (CVE-2024-12254): drain() would return at once and a stalled
+# client would have the whole object queued. aiohttp keeps the flag for its
+# own writelines; an aiohttp without it is not one this was written against.
+_SKIP_WRITELINES = getattr(http_writer, "SKIP_WRITELINES", True)
 # Inline-object streams are plain list iterators (zero IO behind next()) —
 # the GET fast path detects them by type to drain on the event loop.
 _LIST_ITER = type(iter([]))
@@ -154,6 +172,68 @@ def _drain_group(it, budget: int) -> tuple[list, bool]:
         if size >= budget:
             return chunks, False
     return chunks, True
+
+
+def _vectored_writer(resp: web.StreamResponse) -> StreamWriter | None:
+    """A prepared response's payload writer, if a group of chunks can
+    leave it as one vectored write: it sends the bytes as they are (not
+    chunked, not compressing) and its transport's writelines is the
+    socket transport's sendmsg. None otherwise (TLS, another loop's
+    transport, a chunked or compressed response, a runtime whose
+    writelines never pauses the protocol, an aiohttp whose payload writer
+    is not the one this was written against): write chunk by chunk.
+    The vectored write stands in for aiohttp's own `StreamResponse.write`
+    only: where that is someone else's code (a subclass's, a wrapper put
+    on the class), every chunk goes through it."""
+    if _SKIP_WRITELINES or not _HAS_SENDMSG:
+        return None
+    code = getattr(getattr(type(resp), "write", None), "__code__", None)
+    if code is None or code.co_filename != web_response.__file__:
+        return None
+    w = getattr(resp, "_payload_writer", None)
+    if type(w) is not StreamWriter or w.chunked:
+        return None
+    # aiohttp's private names: one it does not have reads as "in use",
+    # so an unknown aiohttp falls back and does not fail.
+    if (getattr(w, "_compress", True) is not None
+            or getattr(w, "_on_chunk_sent", True) is not None
+            or not callable(getattr(w, "_writelines", None))
+            or not callable(getattr(w, "send_headers", None))):
+        return None
+    tr = w.transport
+    if tr is None or getattr(
+            type(tr), "writelines", None
+    ) is not _SelectorSocketTransport.writelines:
+        return None
+    return w
+
+
+async def _write_group(w: StreamWriter, chunks: list) -> None:
+    """What `await resp.write(chunk)` for every chunk does to the payload
+    writer (headers out first if aiohttp still holds them, Content-Length
+    counted down and never passed), with aiohttp's own ONE
+    transport.writelines of the views as they are (it keeps the sizes and
+    refuses a closing transport), then the writer's back-pressure once: a
+    slow client holds one group."""
+    out, left = [], w.length
+    for c in chunks:
+        if isinstance(c, memoryview) and c.nbytes != len(c):
+            c = c.cast("c")
+        if left is not None:
+            if len(c) > left:
+                c = c[:left]
+            left -= len(c)
+        if len(c):
+            out.append(c)
+    w.length = left
+    if not out:
+        return
+    # A StreamResponse's headers left at prepare(); a writer that still
+    # holds them (aiohttp >= 3.13 can, until the first write) sends them now.
+    w.send_headers()
+    w._writelines(out)
+    w.buffer_size = 0
+    await w.drain()
 
 
 async def _loop_lag_sampler(_app):
@@ -2759,7 +2839,9 @@ class S3Server:
             lambda: _drain_group(it, self._GET_GROUP_BYTES))
         # resp_drain, split: the loop waits for the object layer's next
         # group of chunks (drive read, verify, decode), then sends them
-        # as they are: the views stay views, nothing is joined.
+        # as they are: the views stay views, nothing is joined. A group
+        # is one vectored write where the connection allows it.
+        vectored = _vectored_writer(resp)
         done = False
         while not done:
             with flight.span("tx_next"):
@@ -2771,8 +2853,14 @@ class S3Server:
             if delay > 0:
                 await asyncio.sleep(delay)
             with flight.span("tx_send"):
-                for chunk in chunks:
-                    await resp.write(chunk)
+                if vectored is not None:
+                    # Counted beside the hop, before the next await: two
+                    # scrapes read the same number of both.
+                    _VECTORED_GROUPS.inc()
+                    await _write_group(vectored, chunks)
+                else:
+                    for chunk in chunks:
+                        await resp.write(chunk)
         with flight.span("tx_send"):
             await resp.write_eof()
         return resp
