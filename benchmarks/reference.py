@@ -13,6 +13,9 @@ that the program made.
   padded with zeros to k * w, w = ceil(L / k), and split into k data rows of
   w bytes; the m parity rows follow. Shard i of the object is the sequence of
   its row i of every block.
+* Any k rows of a block give its data rows back: take the k x k part of the
+  generator (identity over the parity rows) that made them, invert it,
+  multiply (`decode_rows`).
 * A shard file is one `[digest][chunk]` record per block (the streaming
   bitrot format), digest = mxsum256 of that row.
 * mxsum256: digest_c = sum_i int8(data_i) * K[i, c]
@@ -116,6 +119,31 @@ def encode_block(data_rows: np.ndarray, m: int) -> np.ndarray:
     for j, row in enumerate(parity_rows(k, m)):
         for i, c in enumerate(row):
             out[j] ^= _times_table(c)[data_rows[i]]
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def decode_matrix(k: int, m: int, have: tuple[int, ...]) -> tuple:
+    """The k x k matrix that gives the data rows back from rows `have` (k
+    row indices of the k + m, in the order the rows are handed over)."""
+    gen = [[int(i == j) for j in range(k)] for i in range(k)] + [
+        list(r) for r in parity_rows(k, m)]
+    return tuple(tuple(r) for r in _mat_inv([gen[i] for i in have]))
+
+
+def decode_rows(present: dict[int, np.ndarray], k: int, m: int) -> np.ndarray:
+    """{row index: [w] u8 row}, any k or more of a block's k + m rows ->
+    its [k, w] u8 data rows."""
+    have = tuple(sorted(present))[:k]
+    if len(have) < k or have[0] < 0 or have[-1] >= k + m:
+        raise ValueError(f"{len(present)} rows of a {k}+{m} block: "
+                         f"{sorted(present)}")
+    inv = decode_matrix(k, m, have)
+    out = np.zeros((k, present[have[0]].size), dtype=np.uint8)
+    for i in range(k):
+        for c, j in zip(inv[i], have):
+            if c:
+                out[i] ^= _times_table(c)[present[j]]
     return out
 
 

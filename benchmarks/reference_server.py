@@ -6,13 +6,20 @@ Takes the launcher's arguments (`serve.py`), holds no device, and serves
 PutObject and GetObject by the formats' definitions alone
 (`reference.py`): a PUT writes the object's k+m `[digest][chunk]` shard
 files to the drives the key names and answers with the body's MD5, a GET
-reads the data shards back. `BENCH_CONTROL_BREAK` makes it break ONE
+reads the data shards back. A drive whose root is not a directory is a
+drive that is gone (a configuration's `state`): a PUT leaves it out, and a
+GET that misses a data shard (its drive gone, or blank) checks every frame of the shards that are
+left against its digest and rebuilds the missing rows from any k of them
+(`reference.decode_rows`). `BENCH_CONTROL_BREAK` makes it break ONE
 guarantee that the configurations state, the step that would tempt a later
 change; the comparison has to come out not correct for each:
 
     quorum     an acknowledged PUT reaches one drive fewer than write quorum
     bitrot     frames carry BLAKE2b-256 digests in place of mxsum256
     bit-exact  a GET returns the object with one byte altered
+    rebuild    a GET sends the data rows it had to rebuild as zeros
+    state      a GET that meets a lost drive makes its root anew: the state
+               the configuration names no longer holds
 
 With nothing broken it has to come out correct: the comparison then agrees
 with a second implementation that shares no code with the program.
@@ -28,6 +35,8 @@ import signal
 import sys
 import threading
 import uuid
+
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -45,6 +54,7 @@ class Store:
         self.drives, self.m = drives, parity
         self.k = len(drives) - parity
         self.broken = broken
+        self.digest = blake if broken == "bitrot" else reference.mxsum256
         self.sizes: dict[str, int] = {}
         self._files: dict[bytes, list[bytes]] = {}
         self._mu = threading.Lock()
@@ -55,8 +65,7 @@ class Store:
             files = self._files.get(md5.digest())
         if files is None:
             files = reference.shard_files(
-                body, self.k, self.m, BLOCK,
-                digest=blake if self.broken == "bitrot" else reference.mxsum256)
+                body, self.k, self.m, BLOCK, digest=self.digest)
             with self._mu:
                 self._files[md5.digest()] = files
         n = self.k + self.m
@@ -65,6 +74,8 @@ class Store:
         data_dir = str(uuid.uuid4())
         for drive, shard in list(zip(
                 self.drives, reference.shard_of_drive(bucket, key, n)))[:reach]:
+            if not os.path.isdir(drive):
+                continue
             d = os.path.join(drive, bucket, key, data_dir)
             os.makedirs(d)
             with open(os.path.join(d, "part.1"), "wb") as f:
@@ -79,30 +90,56 @@ class Store:
         if size is None:
             return None
         n = self.k + self.m
-        rows: dict[int, bytes] = {}
+        held = []
         for drive, shard in zip(self.drives,
                                 reference.shard_of_drive(bucket, key, n)):
-            if shard >= self.k:
+            if os.path.isdir(os.path.join(drive, bucket, key)):
+                held.append((drive, shard))   # not gone, and not blank
+            elif self.broken == "state" and not os.path.isdir(drive):
+                with self._mu:
+                    if os.path.islink(drive):   # what `run.apply_state` left
+                        os.unlink(drive)
+                    os.makedirs(drive, exist_ok=True)
+        whole = sum(shard < self.k for _, shard in held) == self.k
+        files: dict[int, bytes] = {}
+        for drive, shard in held:
+            if whole and shard >= self.k:
                 continue
             d = os.path.join(drive, bucket, key)
             with open(os.path.join(d, os.listdir(d)[0], "part.1"), "rb") as f:
-                rows[shard] = f.read()
+                files[shard] = f.read()
         out = bytearray()
-        pos = [0] * self.k
+        pos = 0
         left = size
         while left > 0:
             block = min(left, BLOCK)
             w = -(-block // self.k)
-            piece = bytearray()
-            for s in range(self.k):
-                at = pos[s] + reference.DIGEST_LEN
-                piece += rows[s][at:at + w]
-                pos[s] = at + w
-            out += piece[:block]
+            at = pos + reference.DIGEST_LEN
+            if whole:
+                rows = [files[s][at:at + w] for s in range(self.k)]
+            else:
+                rows = self._rebuilt(files, pos, w)
+            out += b"".join(rows)[:block]
+            pos = at + w
             left -= block
         if self.broken == "bit-exact" and out:
             out[len(out) // 2] ^= 1
         return bytes(out)
+
+    def _rebuilt(self, files: dict[int, bytes], pos: int, w: int) -> list:
+        """One block's k data rows from the shards that are left, each
+        frame checked against its digest before it is used."""
+        at = pos + reference.DIGEST_LEN
+        present = {}
+        for s, raw in files.items():
+            row = np.frombuffer(raw, dtype=np.uint8, count=w, offset=at)
+            if self.digest(row) != raw[pos:at]:
+                raise OSError(f"shard {s}: a frame fails its digest")
+            present[s] = row
+        data = reference.decode_rows(present, self.k, self.m)
+        if self.broken == "rebuild":
+            data[[s for s in range(self.k) if s not in present]] = 0
+        return [r.tobytes() for r in data]
 
 
 def handler(store: Store):
@@ -136,9 +173,9 @@ def handler(store: Store):
             bucket, key = path.lstrip("/").split("/", 1)
             try:
                 body = store.get(bucket, key)
-            except FileNotFoundError:
-                # A data shard is not there, and this plain reference does
-                # not reconstruct: the read fails, as the client sees it.
+            except (OSError, ValueError):
+                # Fewer than k shards are there, or one fails its digest:
+                # the read fails, as the client sees it.
                 return self._send(503)
             if body is None:
                 return self._send(404)
@@ -155,6 +192,8 @@ def main(argv: list[str]) -> int:
     host, _, port = rest[rest.index("--address") + 1].rpartition(":")
     with open(os.path.join(run_dir, "device.json"), "w") as f:
         json.dump({"platform": "reference", "kind": "none", "count": chips}, f)
+    for d in drives:   # the program makes its drives' roots at its boot too
+        os.makedirs(d, exist_ok=True)
     store = Store(drives, parity, os.environ.get("BENCH_CONTROL_BREAK", ""))
     srv = http.server.ThreadingHTTPServer((host, int(port)), handler(store))
     srv.daemon_threads = True

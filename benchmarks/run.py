@@ -10,7 +10,10 @@ worker processes (`benchmarks/worker.py`) outside the server's process, and
 for a traced run a reduction child after the server has gone. Everything
 about a cell is data found by name: `BENCHMARK.json` names the workload's
 configuration and traffic mix, `configs/<config>.json`,
-`traffic/<traffic>.json` and `layer_metrics/<metric>.json` hold them.
+`traffic/<traffic>.json` and `layer_metrics/<metric>.json` hold them. A
+configuration may state a `state` (drives lost after the preload): set-up
+brings it about, and the comparison and the reckoning of codec work are
+made in it.
 
 The last line of stdout is the result object; earlier lines say what the
 run saw. A run that finds no TPU (or not the cell's number of chips), a
@@ -21,6 +24,7 @@ lane exits non-zero and prints no result.
 from __future__ import annotations
 
 import argparse
+import collections
 import ctypes
 import http.client
 import io
@@ -41,6 +45,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import reference  # noqa: E402
 import scrape  # noqa: E402
 import traffic  # noqa: E402
 import verify  # noqa: E402
@@ -87,16 +92,20 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
     def in_cell(metric: dict) -> bool:
         return workload in metric.get("workloads", [workload])
 
+    end_to_end = [m for m in bench["end_to_end"] if in_cell(m)]
+    reported = {m["name"] for m in end_to_end}
     return {
         "cell": cell,
         "config": load_json(os.path.join(root, conf["file"])),
         "mix": load_json(os.path.join(here, "traffic",
                                       cell["traffic"] + ".json")),
-        "end_to_end": [m for m in bench["end_to_end"] if in_cell(m)],
+        "end_to_end": end_to_end,
+        # a layer's metric belongs where the number it should move is read
         "per_layer": [
             {**m, **load_json(os.path.join(here, "layer_metrics",
                                            m["name"] + ".json"))}
-            for m in bench["per_layer"] if in_cell(m)],
+            for m in bench["per_layer"]
+            if in_cell(m) and m["moves"] in reported],
     }
 
 
@@ -344,6 +353,31 @@ def preload(server: Server, pool: traffic.BodyPool, objects: list,
         raise RunFailed(errors[0])
 
 
+def apply_state(drive_roots: list[str], state: dict) -> list[str]:
+    """Bring about the failure a configuration states -> the lost drives'
+    roots. A lost drive's tree is removed and a symbolic link to itself
+    left in its place: every system call under it then fails (ELOOP), as on
+    a disk that died or a node that is down, the program takes the drive
+    offline and can heal nothing onto it. (A root that is merely missing is
+    made anew by the heal a degraded GET queues, and a shard file removed
+    from a drive that stays is healed back: either way the cell would
+    measure a healthy set after its first seconds.)"""
+    n = int(state["drives_lost"])
+    if (state.get("which"), state.get("when")) != ("first", "after_preload") \
+            or not 0 < n < len(drive_roots):
+        raise RunFailed(f"a state this harness cannot bring about: {state}")
+    lost = drive_roots[:n]
+    for root in lost:
+        shutil.rmtree(root)
+        os.symlink(os.path.basename(root), root)
+    return lost
+
+
+def roots_present(roots: list[str]) -> set[str]:
+    """Those of the roots that are a directory (again)."""
+    return {r for r in roots if os.path.isdir(r)}
+
+
 # --- the traced slice ------------------------------------------------------
 
 
@@ -520,6 +554,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         objects = traffic.preload_objects(mix, seed)
         preload(server, pool, objects)
         phases["preload"] = time.monotonic() - t
+        lost: list[str] = []
+        if config.get("state"):
+            t = time.monotonic()
+            lost = apply_state(server.drive_roots, config["state"])
+            phases["state"] = time.monotonic() - t
+            say(f"state: {config['state']}: removed "
+                + " ".join(os.path.basename(r) for r in lost))
         t = time.monotonic()
         workers.wait_ready()
         phases["clients_ready"] = time.monotonic() - t
@@ -554,10 +595,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             admin.request("GET", "/minio/v2/metrics/node").body.decode())
 
         # The window: opens without a pause, closes `seconds` later.
+        lost_seen = roots_present(lost)
         t0 = time.monotonic()
         setup_s = t0 - T_START
         time.sleep(max(0.0, t0 + seconds - time.monotonic()))
         t1 = time.monotonic()
+        lost_seen |= roots_present(lost)
         after = scrape.parse(
             admin.request("GET", "/minio/v2/metrics/node").body.decode())
         entries2 = cache_entries(cdir)
@@ -575,6 +618,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         everything = [r for recs in threads for r in recs]
         done = [r for r in everything if t0 <= r["t_last"] <= t1]
         compared = verify.answers(done)
+        if lost:
+            compared.update(verify.state_held(lost_seen))
         for r in [r for r in everything if not r["ok"]][:8]:
             say(f"failed: {r['verb']} {r['key']} -> status {r['status']}"
                 + (", answer wrong" if r["wrong"] else "")
@@ -613,7 +658,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 lambda: ask("/minio/health/cluster").status == 200,
                 t1 + readback_wait_s)
             compared.update(verify.compare_puts(
-                sample, pool, fetch, config, server.drive_roots, BUCKET))
+                sample, pool, fetch, config, server.drive_roots, BUCKET,
+                lost))
             say(f"read back: asked again {fetch.asked_again} times, "
                 f"waited {fetch.waited_first_s:.2f} s for the program to "
                 "say it is healthy, the "
@@ -653,12 +699,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"window: {window_s:.3f} s, {len(done)} operations completed, "
             f"{window['client_ops']} good, clients idle "
             f"{window['client_gap_pct']:.2f} %")
+        say("the clients' clock, whether or not the cell reports it: "
+            + json.dumps(e2e))
 
         say("drive operations past their health deadline: "
             f"{scrape.total(before, 'minio_tpu_drive_timeouts_total'):.0f} "
             "before the window, "
             f"{scrape.total(after, 'minio_tpu_drive_timeouts_total'):.0f} "
-            "at its close")
+            "at its close; drives the program holds offline: "
+            f"{scrape.count_at(before, 'minio_tpu_drive_state', 2)} and "
+            f"{scrape.count_at(after, 'minio_tpu_drive_state', 2)}")
         say("stages, ms per request over the window: " + json.dumps(
             scrape.stage_table(before, after)))
 
@@ -677,6 +727,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             sliced = [r for r in everything
                       if tracer.t_begin <= r["t_last"] <= tracer.t_stop]
             in_slice = [(r["verb"], r["size"]) for r in sliced if r["ok"]]
+            if lost:   # and how many data shards each object has lost
+                k, n = int(config["data_shards"]), int(config["drives"])
+                lost_drives = [server.drive_roots.index(r) for r in lost]
+                in_slice = [(r["verb"], r["size"], work.lost_data_shards(
+                    reference.shard_of_drive(BUCKET, r["key"], n),
+                    lost_drives, k)) for r in sliced if r["ok"]]
+                say("traced slice: operations by data shards lost: "
+                    + json.dumps(dict(sorted(collections.Counter(
+                        o[2] for o in in_slice).items()))))
             say(f"traced slice: {len(sliced)} operations answered, "
                 f"{len(sliced) - len(in_slice)} of them failed")
             red = reduce_trace(tracer.out_path, device["platform"])

@@ -46,6 +46,12 @@ def total(samples: Samples, family: str, labels: dict | None = None) -> float:
     return s
 
 
+def count_at(samples: Samples, family: str, value: float) -> int:
+    """How many samples of a gauge `family` read `value`."""
+    return sum(v == value for (name, _), v in samples.items()
+               if name == family)
+
+
 def delta(before: Samples, after: Samples, term: dict) -> float:
     """One term of a metric file: {"family":, "labels":, "scale":}."""
     d = (total(after, term["family"], term.get("labels"))

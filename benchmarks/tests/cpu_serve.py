@@ -93,6 +93,37 @@ def plant(fault: str) -> None:
         healthcheck.HealthChecker.__getattr__ = lookup_then_outage
         healthcheck.HealthChecker.disk_info = probe_then_outage
         healthcheck.HealthChecker._begin = begin_or_offline
+    elif fault == "rebuilt-row":
+        # One byte of every rebuild altered as the launch hands it back.
+        import numpy as np
+
+        from minio_tpu.ops import rs_xla
+
+        launch = rs_xla.gf2_matmul_with_weights
+
+        def bad_launch(batch, w, n_out):
+            out = np.array(launch(batch, w, n_out))
+            out[0, 0, 0] ^= 1
+            return out
+
+        rs_xla.gf2_matmul_with_weights = bad_launch
+    elif fault == "drive-back":
+        # No fault of an answer: a blank drive is mounted where a lost one
+        # was, so the state the configuration names does not hold.
+        import threading
+        import time
+
+        roots = sys.argv[4:sys.argv.index("--parity")]
+
+        def remount():
+            while True:
+                time.sleep(0.2)
+                for r in roots:
+                    if os.path.islink(r):
+                        os.unlink(r)
+                        os.mkdir(r)
+
+        threading.Thread(target=remount, daemon=True).start()
     elif fault:
         raise SystemExit(f"unknown fault {fault!r}")
 

@@ -18,6 +18,11 @@ TINY_CONFIG = {
     "parity_shards": 2, "block_size": 1048576,
     "guarantees": {"write_quorum_drives": 3},
 }
+# The same set with both parity's worth of drives lost after the preload:
+# every key has lost 0, 1 or 2 of its 2 data shards.
+TINY_LOST = {**TINY_CONFIG, "name": "ec2p2-4d-2lost",
+             "state": {"drives_lost": 2, "which": "first",
+                       "when": "after_preload"}}
 
 
 def tiny_mix(name: str, verb: str, size: int, preload: int = 0) -> dict:
@@ -35,8 +40,11 @@ def tiny_mix(name: str, verb: str, size: int, preload: int = 0) -> dict:
 
 
 def make_checkout(tmp: str, mixes: list[dict],
-                  extra_metrics: dict | None = None) -> str:
-    """-> root of a temp checkout with one cell per mix on the tiny config."""
+                  extra_metrics: dict | None = None,
+                  lost_mixes: list[str] = ()) -> str:
+    """-> root of a temp checkout with one cell per mix on the tiny config,
+    and for each mix named in `lost_mixes` a second cell, `<cell>.2lost`, on
+    the tiny config with its state."""
     root = os.path.join(tmp, "checkout")
     os.makedirs(root)
     shutil.copytree(BENCH, os.path.join(root, "benchmarks"),
@@ -45,13 +53,13 @@ def make_checkout(tmp: str, mixes: list[dict],
         os.symlink(os.path.join(REPO, name), os.path.join(root, name))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    with open(os.path.join(root, "benchmarks", "configs",
-                           "ec2p2-4d.json"), "w") as f:
-        json.dump(TINY_CONFIG, f)
-    bench["configs"].append({
-        "name": "ec2p2-4d", "source": "test",
-        "file": "benchmarks/configs/ec2p2-4d.json", "reduced": [],
-        "why": "test"})
+    for conf in (TINY_CONFIG, TINY_LOST):
+        file = f"benchmarks/configs/{conf['name']}.json"
+        with open(os.path.join(root, file), "w") as f:
+            json.dump(conf, f)
+        bench["configs"].append({
+            "name": conf["name"], "source": "test", "file": file,
+            "reduced": [], "why": "test"})
     for mix in mixes:
         with open(os.path.join(root, "benchmarks", "traffic",
                                mix["name"] + ".json"), "w") as f:
@@ -59,6 +67,15 @@ def make_checkout(tmp: str, mixes: list[dict],
         bench["workloads"].append({
             "name": f"ec2p2-4d.{mix['name']}", "config": "ec2p2-4d",
             "traffic": mix["name"], "chips": 1, "why": "test"})
+        if mix["name"] in lost_mixes:
+            bench["workloads"].append({
+                "name": f"ec2p2-4d.{mix['name']}.2lost",
+                "config": "ec2p2-4d-2lost", "traffic": mix["name"],
+                "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:     # those that name their cells
+        if "workloads" in m and m["name"] != "ops_per_s":
+            m["workloads"] += [w["name"] for w in bench["workloads"]
+                               if w["config"].startswith("ec2p2-4d")]
     for name, (entry, spec) in (extra_metrics or {}).items():
         with open(os.path.join(root, "benchmarks", "layer_metrics",
                                name + ".json"), "w") as f:

@@ -8,6 +8,7 @@ import pytest
 
 import reference
 import rehearsal
+import run
 import scrape
 import trace_reduce
 import traffic
@@ -29,6 +30,51 @@ def test_gf_and_the_2_plus_2_code_by_hand():
     data = np.array([[1, 2], [3, 4]], dtype=np.uint8)
     # p0 = 3*d0 ^ 2*d1 = [3^6, 6^8]; p1 = 2*d0 ^ 3*d1 = [2^5, 4^12]
     assert reference.encode_block(data, 2).tolist() == [[5, 14], [7, 8]]
+
+
+ROWS_2P2 = np.array([[1, 2], [3, 4], [5, 14], [7, 8]], dtype=np.uint8)
+
+
+@pytest.mark.parametrize("lost", [(0, 1), (0, 2), (0, 3), (1, 2), (1, 3),
+                                  (2, 3)])
+def test_decode_rows_of_the_2_plus_2_code_by_hand(lost):
+    """The rows of the block above, two of four lost. By hand for (0, 2):
+    what is left is d1 and p1 = 2*d0 ^ 3*d1, so d0 = inv(2) * (p1 ^ 3*d1)
+    with inv(2) = 142 (2*142 = 0x11C = 0x11D ^ 1), and 142*3 = 142 ^ 1."""
+    assert reference.gf_mul(2, 142) == 1 and reference.gf_mul(142, 3) == 143
+    if lost == (0, 2):
+        assert reference.decode_matrix(2, 2, (1, 3)) == ((143, 142), (1, 0))
+    if lost == (0, 1):      # the parity block [[3, 2], [2, 3]] is its own
+        assert reference.decode_matrix(2, 2, (2, 3)) == ((3, 2), (2, 3))
+    present = {i: ROWS_2P2[i] for i in range(4) if i not in lost}
+    assert reference.decode_rows(present, 2, 2).tolist() == [[1, 2], [3, 4]]
+
+
+@pytest.mark.parametrize("start", range(16))
+def test_decode_rows_at_12_plus_4_for_every_four_drives_in_a_row(start):
+    """Drives d0-d3 lost: an object whose shards start at `start` has lost
+    shards start .. start+3 (mod 16); the twelve left give the data back."""
+    rng = np.random.default_rng([33, start])
+    data = rng.integers(0, 256, (12, 257), dtype=np.uint8)
+    rows = np.concatenate([data, reference.encode_block(data, 4)])
+    lost = {(start + i) % 16 for i in range(4)}
+    present = {i: rows[i] for i in range(16) if i not in lost}
+    assert np.array_equal(reference.decode_rows(present, 12, 4), data)
+    # and from the program's own ground truth, which reference.py never
+    # imports
+    from minio_tpu.ops import gf
+
+    targets = tuple(sorted(lost & set(range(12))))
+    if targets:
+        got = gf.reconstruct_ref(rows, 12, tuple(sorted(present)), targets)
+        assert np.array_equal(got, data[list(targets)])
+
+
+def test_decode_rows_wants_k_rows():
+    with pytest.raises(ValueError):
+        reference.decode_rows({1: ROWS_2P2[1]}, 2, 2)
+    with pytest.raises(ValueError):
+        reference.decode_rows({1: ROWS_2P2[1], 4: ROWS_2P2[3]}, 2, 2)
 
 
 def test_shard_files_layout_by_hand():
@@ -80,6 +126,191 @@ def test_codec_bytes_by_hand():
     assert least["hbm_s"] == pytest.approx(196992 / 819e9)
     with pytest.raises(KeyError):
         work.peaks("TPU v9")
+
+
+def test_codec_work_with_no_data_shard_lost_is_what_it_was():
+    """The numbers every line of the ledger was reckoned with (pinned), and
+    t = 0 said aloud gives the same."""
+    mib = 1 << 20
+    pinned = {("PUT", 10 * mib, 12, 4): (13986160, 5592448000),
+              ("GET", 10 * mib, 12, 4): (10489680, 167773440),
+              ("PUT", 131072, 8, 4): (196992, 70254592),
+              ("GET", 131072, 8, 4): (131328, 2097152),
+              ("GET", 3 * mib + 5, 2, 2): (3145990, 50331744)}
+    for (verb, size, k, m), (nbytes, nops) in pinned.items():
+        for t in ((), (0,)):
+            assert work.codec_bytes(verb, size, k, m, mib, *t) == nbytes
+            assert work.codec_int_ops(verb, size, k, m, mib, *t) == nops
+    two = work.least_seconds([("GET", 10 * mib), ("GET", 10 * mib, 0)],
+                             12, 4, mib, "TPU v5 lite")
+    assert two["bytes"] == 2 * 10489680 and two["int_ops"] == 2 * 167773440
+
+
+@pytest.mark.parametrize("t", [1, 2, 3, 4])
+def test_codec_work_of_a_get_that_rebuilds_t_rows(t):
+    """12+4, 10 MiB: the 12 rows that are left are read once, t rebuilt rows
+    written, 12 digests a block; the rebuild is a [w, 96] x [96, 8t]
+    contraction a block beside the 16 operations a byte hashed."""
+    mib, shard = 1 << 20, 10 * 87382
+    assert work.codec_bytes("GET", 10 * mib, 12, 4, mib, t) == (
+        shard * 12 + shard * t + 10 * 12 * 32)
+    assert work.codec_int_ops("GET", 10 * mib, 12, 4, mib, t) == (
+        shard * 12 * 16 + shard * 2 * 96 * 8 * t)
+    # a PUT encodes every parity row whatever is lost
+    assert work.codec_bytes("PUT", 10 * mib, 12, 4, mib, t) == 13986160
+    least = work.least_seconds([("GET", 10 * mib, t)], 12, 4, mib,
+                               "TPU v5 lite")
+    assert least["hbm_s"] == pytest.approx(
+        (10489680 + shard * t) / 819e9)
+
+
+def test_lost_data_shards_follow_from_the_key():
+    """Drives d0-d3 lost of 16: t is 4 for nine starts of the rotation, 3, 2
+    and 1 for two each, 0 for one (start 12: d0-d3 hold the parity)."""
+    by_start = {}
+    for i in range(400):
+        shard_of = reference.shard_of_drive("bench", f"s1/pre/{i:06d}", 16)
+        by_start[shard_of[0]] = work.lost_data_shards(shard_of, [0, 1, 2, 3],
+                                                      12)
+    assert [by_start[s] for s in range(16)] == [
+        4, 4, 4, 4, 4, 4, 4, 4, 4, 3, 2, 1, 0, 1, 2, 3]
+    assert work.lost_data_shards(list(range(16)), [], 12) == 0
+
+
+# --- a configuration's state -----------------------------------------------
+
+
+def _tree(tmp_path, n=4):
+    roots = [str(tmp_path / "drives" / f"d{i}") for i in range(n)]
+    for r in roots:
+        os.makedirs(os.path.join(r, "bench", "key", "dir"))
+        with open(os.path.join(r, "bench", "key", "dir", "part.1"), "wb") as f:
+            f.write(b"x")
+    return roots
+
+
+def test_apply_state_leaves_nothing_to_write_under_a_lost_root(tmp_path):
+    roots = _tree(tmp_path)
+    lost = run.apply_state(roots, {"drives_lost": 2, "which": "first",
+                                   "when": "after_preload"})
+    assert lost == roots[:2]
+    assert run.roots_present(lost) == set()
+    assert run.roots_present(roots) == set(roots[2:])
+    for r in lost:
+        assert not os.path.exists(r) and os.path.lexists(r)
+        # what the program's heal does to a root that is merely missing
+        with pytest.raises(OSError):
+            os.makedirs(os.path.join(r, ".mtpu.sys", "tmp"), exist_ok=True)
+        with pytest.raises(OSError):
+            open(os.path.join(r, "bench", "key", "dir", "part.1"), "rb")
+        with pytest.raises(OSError):
+            os.statvfs(r)
+    assert os.path.isfile(os.path.join(roots[2], "bench", "key", "dir",
+                                       "part.1"))
+    # a blank drive mounted in a lost one's place is seen
+    os.unlink(lost[0])
+    os.mkdir(lost[0])
+    assert run.roots_present(lost) == {lost[0]}
+    assert verify.state_held({lost[0]}) == {"lost_drives_present": {
+        "value": 1, "limit": 0, "better": "lower"}}
+    assert verify.verdict(verify.state_held(set())) is True
+    assert verify.verdict(verify.state_held({lost[0]})) is False
+
+
+@pytest.mark.parametrize("state", [
+    {"drives_lost": 0, "which": "first", "when": "after_preload"},
+    {"drives_lost": 4, "which": "first", "when": "after_preload"},
+    {"drives_lost": 1, "which": "last", "when": "after_preload"},
+    {"drives_lost": 1, "which": "first", "when": "in_window"},
+])
+def test_apply_state_refuses_what_it_cannot_bring_about(tmp_path, state):
+    roots = _tree(tmp_path)
+    with pytest.raises(run.RunFailed):
+        run.apply_state(roots, state)
+    assert run.roots_present(roots) == set(roots)
+
+
+def test_drive_check_does_not_look_under_lost_roots(tmp_path):
+    config = {"data_shards": 2, "parity_shards": 2, "block_size": 4}
+    roots = [str(tmp_path / f"d{i}") for i in range(4)]
+    want = reference.shard_files(bytes(range(1, 8)), 2, 2, 4)
+    key = "s1/w0t0/0000001"
+    for root, shard in zip(roots, reference.shard_of_drive("bench", key, 4)):
+        d = os.path.join(root, "bench", key, "datadir")
+        os.makedirs(d)
+        with open(os.path.join(d, "part.1"), "wb") as f:
+            # d0 holds a wrong file, d1 the right one: both are lost
+            f.write(b"wrong" if root == roots[0] else want[shard])
+    assert verify.DriveCheck(config, roots, "bench").check(key, want) == (
+        3, 1)
+    assert verify.DriveCheck(config, roots, "bench", roots[:2]).check(
+        key, want) == (2, 0)
+
+
+def test_the_degraded_cell_loads_by_name():
+    loaded = run.load_cell("ec12p4-16d.get-10MiB.4lost")
+    assert loaded["config"]["state"] == {
+        "drives_lost": 4, "which": "first", "when": "after_preload"}
+    assert loaded["config"]["guarantees"]["write_quorum_drives"] == 12
+    twin = run.load_cell("ec12p4-16d.get-10MiB")
+    assert "state" not in twin["config"]
+    assert loaded["mix"] == twin["mix"]
+    for key in ("drives", "data_shards", "parity_shards", "block_size"):
+        assert loaded["config"][key] == twin["config"][key]
+    # `op_p90_ms` is not this cell's (its spread does not fit the bound,
+    # PERF.md section 2), so neither are the layer metrics that move it
+    assert [m["name"] for m in loaded["end_to_end"]] == [
+        "goodput_mibps", "setup_s"]
+    assert [m["name"] for m in loaded["per_layer"]] == [
+        "client_gap_pct", "codec_roofline", "device_idle_pct",
+        "get_read_ms_per_op", "get_verify_ms_per_op", "get_send_ms_per_op",
+        "get_chunks_per_hop", "get_vectored_send_pct",
+        "get_decode_ms_per_op", "mrf_requeues_per_op"]
+    assert all(m["moves"] == "goodput_mibps" for m in loaded["per_layer"])
+    twin_names = [m["name"] for m in twin["per_layer"]]
+    assert {"entry_ms_per_op", "ttfb_p90_ms", "loop_lag_ms",
+            "get_decode_ms_per_op", "mrf_requeues_per_op"} <= set(twin_names)
+    put = [m["name"] for m in run.load_cell("ec12p4-16d.put-10MiB")[
+        "per_layer"]]
+    assert "get_decode_ms_per_op" not in put
+
+
+@pytest.mark.parametrize("cell,n_end_to_end,n_per_layer", [
+    ("ec12p4-16d.put-10MiB", 3, 14), ("ec8p4-12d.put-128KiB", 4, 15),
+    ("ec12p4-16d.get-10MiB", 3, 15)])
+def test_the_cells_that_stood_keep_their_metrics(cell, n_end_to_end,
+                                                 n_per_layer):
+    """What they reported at the parent, and for the GET cell the two of
+    the degraded read."""
+    loaded = run.load_cell(cell)
+    assert len(loaded["end_to_end"]) == n_end_to_end
+    assert len(loaded["per_layer"]) == n_per_layer
+
+
+def test_the_two_metrics_of_the_degraded_read_on_recorded_expositions():
+    before, after = rehearsal.recorded_scrapes()
+    loaded = run.load_cell("ec12p4-16d.get-10MiB.4lost")
+    specs = {m["name"]: m for m in loaded["per_layer"]}
+    ctx = {"before": before, "after": after, "window": {"client_ops": 4},
+           "trace": {}}
+    # no GET between the two scrapes: nothing to divide by, nothing reported
+    assert run.layer_value(specs["get_decode_ms_per_op"], ctx) is None
+    # the counter is in neither scrape: no heal was put back
+    assert run.layer_value(specs["mrf_requeues_per_op"], ctx) == 0.0
+    after = dict(after)
+    after[("minio_tpu_mrf_requeues_total", ())] = 6.0
+    assert run.layer_value(specs["mrf_requeues_per_op"],
+                           {**ctx, "after": after}) == pytest.approx(1.5)
+    g = (("api", "GetObject"), ("plane", "s3"))
+    for stage, secs in (("auth", 0.001), ("decode", 0.25)):
+        after[("minio_tpu_stage_seconds_sum", g + (("stage", stage),))] = (
+            before.get(("minio_tpu_stage_seconds_sum",
+                        g + (("stage", stage),)), 0.0) + secs)
+        after[("minio_tpu_stage_seconds_count", g + (("stage", stage),))] = (
+            before.get(("minio_tpu_stage_seconds_count",
+                        g + (("stage", stage),)), 0.0) + 2)
+    assert run.layer_value(specs["get_decode_ms_per_op"],
+                           {**ctx, "after": after}) == pytest.approx(125.0)
 
 
 # --- scrape deltas ---------------------------------------------------------

@@ -16,6 +16,7 @@ import rehearsal
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 SIZE = 3 * 1048576 + 5            # three blocks and a ragged fourth
 SEED = 2147483659                 # more than 32 signed bits hold
+LOST = "ec2p2-4d.get-tiny.2lost"   # d0 and d1 gone after the preload
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 ENV.pop("BENCH_TEST_FAULT", None)
 
@@ -36,7 +37,7 @@ def checkout(tmp_path_factory):
     return rehearsal.make_checkout(
         tmp, [rehearsal.tiny_mix("put-tiny", "PUT", SIZE),
               rehearsal.tiny_mix("get-tiny", "GET", SIZE, preload=4)],
-        EXTRA_METRIC)
+        EXTRA_METRIC, lost_mixes=["get-tiny"])
 
 
 def cpu_run(checkout, workload, fault=""):
@@ -101,15 +102,42 @@ def test_put_cell_whole_and_its_last_line(checkout):
 
 
 def test_get_cell_whole(checkout):
-    r = last_line(cpu_run(checkout, "ec2p2-4d.get-tiny"))
+    p = cpu_run(checkout, "ec2p2-4d.get-tiny")
+    assert "drives the program holds offline: 0 and 0\n" in p.stdout
+    r = last_line(p)
     assert r["correct"] is True and r["attempted"] > 0
     assert set(r["compared"]) == {"failed_ops", "wrong_answers"}
+
+
+def test_degraded_get_cell_whole(checkout):
+    """Two of the four drives lost after the preload: the state is brought
+    about in set-up, holds, and every GET is rebuilt bit-exact."""
+    p = cpu_run(checkout, LOST)
+    r = last_line(p)
+    assert list(r) == KEYS
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert r["compared"] == {k: {"value": 0, "limit": 0} for k in (
+        "failed_ops", "wrong_answers", "lost_drives_present")}
+    assert set(r["metrics"]) == {"goodput_mibps", "op_p90_ms", "setup_s"}
+    assert "\nstate: {'drives_lost': 2" in p.stdout
+    assert "removed d0 d1\n" in p.stdout
+    setup = next(ln for ln in p.stdout.splitlines()
+                 if ln.startswith("set-up: "))
+    assert ", preload " in setup and ", state " in setup
+    assert "drives the program holds offline: 2 and 2\n" in p.stdout
+    # the rebuild ran: the healthy twin's `decode` is a few hundredths of a ms
+    stages = json.loads(next(
+        ln for ln in p.stdout.splitlines()
+        if ln.startswith("stages, ")).split(": ", 1)[1])
+    assert stages["GetObject"]["decode"][0] > 1.0
 
 
 @pytest.mark.parametrize("workload,fault,caught_by", [
     ("ec2p2-4d.put-tiny", "parity", "shards_wrong"),
     ("ec2p2-4d.put-tiny", "lost-drives", "drives_holding_min"),
     ("ec2p2-4d.get-tiny", "get-body", "wrong_answers"),
+    (LOST, "rebuilt-row", "wrong_answers"),
+    (LOST, "drive-back", "lost_drives_present"),
 ])
 def test_a_fault_under_the_timed_path_reads_not_correct(
         checkout, workload, fault, caught_by):
@@ -141,6 +169,10 @@ def test_drives_offline_at_the_read_back_make_it_late_not_wrong(checkout):
     ("ec2p2-4d.put-tiny", "quorum", False, "drives_holding_min"),
     ("ec2p2-4d.put-tiny", "bitrot", False, "shards_wrong"),
     ("ec2p2-4d.get-tiny", "bit-exact", False, "wrong_answers"),
+    (LOST, "none", True, None),
+    (LOST, "bit-exact", False, "wrong_answers"),
+    (LOST, "rebuild", False, "wrong_answers"),
+    (LOST, "state", False, "lost_drives_present"),
 ])
 def test_the_control_reads_not_correct(checkout, workload, broken, correct,
                                        caught_by):

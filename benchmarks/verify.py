@@ -14,6 +14,12 @@ the quorum's, which the configuration states):
                         drive (data, parity, every [digest][chunk] frame) <= 0
     drives_holding_min  over the sampled PUTs, the least number of drives
                         that hold the reference's shard file   >= write quorum
+    lost_drives_present in a configuration's `state` only: the lost drives
+                        whose root directory is there at the window's open
+                        or at its close (the state did not hold)        <= 0
+
+In a state the drives' check does not look under the lost drives' roots, and
+`drives_holding_min` keeps the configuration's limit.
 
 The reference encodes each distinct body once (bodies repeat, keys do not);
 which drive holds which shard follows from the key.
@@ -48,11 +54,13 @@ def sample_puts(records: list[dict], n: int, seed: int) -> list[dict]:
 class DriveCheck:
     """Shard files on the drives against the reference's."""
 
-    def __init__(self, config: dict, drive_roots: list[str], bucket: str):
+    def __init__(self, config: dict, drive_roots: list[str], bucket: str,
+                 lost_roots: list[str] = ()):
         self.k = int(config["data_shards"])
         self.m = int(config["parity_shards"])
         self.block = int(config["block_size"])
         self.roots = drive_roots
+        self.lost = set(lost_roots)
         self.bucket = bucket
         self._files: dict[tuple[int, int], list[bytes]] = {}
 
@@ -69,6 +77,8 @@ class DriveCheck:
         shard_of = reference.shard_of_drive(self.bucket, key, self.k + self.m)
         right = wrong = 0
         for root, shard in zip(self.roots, shard_of):
+            if root in self.lost:
+                continue
             found = glob.glob(os.path.join(
                 glob.escape(os.path.join(root, self.bucket, key)),
                 "*", "part.1"))
@@ -154,12 +164,19 @@ def answers(done: list[dict]) -> dict:
     }
 
 
+def state_held(seen: set[str]) -> dict:
+    """`seen`: the lost roots that were there when the harness looked."""
+    return {"lost_drives_present": {
+        "value": len(seen), "limit": 0, "better": "lower"}}
+
+
 def compare_puts(sample: list[dict], bodies, fetch, config: dict,
-                 drive_roots: list[str], bucket: str) -> dict:
+                 drive_roots: list[str], bucket: str,
+                 lost_roots: list[str] = ()) -> dict:
     """The sampled PUTs read back through `fetch(key) -> Reply`, and their
     drives against the plain reference. `bodies.get(size, index).data` is
     what was sent."""
-    check = DriveCheck(config, drive_roots, bucket)
+    check = DriveCheck(config, drive_roots, bucket, lost_roots)
     readback_wrong = shards_wrong = 0
     holding = []
     for r in sample:
