@@ -3,7 +3,7 @@
 comparison that decides `correct`, against the plain reference put in the
 program's place with one guarantee broken (`reference_server.py`).
 
-    python benchmarks/control.py --workload <name> --seeds 1,2,3 --seconds 8 --break quorum|bitrot|bit-exact|rebuild|state|none
+    python benchmarks/control.py --workload <name> --seeds 1,2,3 --seconds 8 --break quorum|bitrot|bit-exact|rebuild|state|heal-zeros|heal-skip|none
 
 Prints one line per seed: the numbers compared, and `correct`. It prints no
 rate: nothing here is a measurement of the system.
@@ -44,7 +44,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--break", dest="broken", required=True,
                     choices=("quorum", "bitrot", "bit-exact", "rebuild", "state",
-                             "none"))
+                             "heal-zeros", "heal-skip", "none"))
     ap.add_argument("--readback-wait", type=float,
                     default=run.READBACK_WAIT_S,
                     help="seconds past the close that a read back waits "

@@ -11,9 +11,11 @@ for a traced run a reduction child after the server has gone. Everything
 about a cell is data found by name: `BENCHMARK.json` names the workload's
 configuration and traffic mix, `configs/<config>.json`,
 `traffic/<traffic>.json` and `layer_metrics/<metric>.json` hold them. A
-configuration may state a `state` (drives lost after the preload): set-up
-brings it about, and the comparison and the reckoning of codec work are
-made in it.
+configuration may state a `state`, one of two: drives lost after the
+preload, or drives that came back blank after it (online, formatted, with
+the bucket and no object; made blank again for a group of objects before
+each heal of it). Set-up brings it about, and the comparison and the
+reckoning of codec work are made in it.
 
 The last line of stdout is the result object; earlier lines say what the
 run saw. A run that finds no TPU (or not the cell's number of chips), a
@@ -45,6 +47,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import blank  # noqa: E402
 import reference  # noqa: E402
 import scrape  # noqa: E402
 import traffic  # noqa: E402
@@ -56,6 +59,7 @@ ACCESS, SECRET = "benchadmin", "benchsecret123"
 BUCKET = "bench"
 READBACK_WAIT_S = 60.0   # past the window's close, for answers that are late
 READBACK_STALL_S = 10.0  # no byte of a read back for so long: ask again
+AT_REST_WAIT_S = 30.0    # for acknowledged journals to be files on the drives
 MIB = 1 << 20
 TRACE_SLICE_S = 3.0   # traces are large and the tracer slows the host
 RECORD_FIELDS = ("verb", "key", "size", "body_index", "t_send", "t_first",
@@ -150,16 +154,23 @@ def free_port() -> int:
 
 
 def written_bytes(mix: dict, config: dict, seconds: float,
-                  rate_mibps: float = 150.0) -> int:
+                  rate_mibps: float = 150.0,
+                  heal_rate_mibps: float = 500.0) -> int:
     """What a run may write at the most: the preload, and PUTs at twice the
     highest goodput a cell has shown (PERF.md) over warm-up and window, all
-    of it times (k+m)/k."""
+    of it times (k+m)/k; and of the bytes healed, at twice what heal has
+    shown, the blank drives' share, (drives blank)/k."""
     pre = mix.get("preload", {})
     total = pre.get("objects", 0) * max(
         [s for s, _ in pre.get("sizes", [])] or [0])
     if any(o["verb"] == "PUT" for o in mix["ops"]):
         total += int(rate_mibps * MIB * (seconds + 20))
-    return total * int(config["drives"]) // int(config["data_shards"])
+    total = total * int(config["drives"]) // int(config["data_shards"])
+    if any(o["verb"] == "HEAL" for o in mix["ops"]):
+        total += (int(heal_rate_mibps * MIB * (seconds + 20))
+                  * int((config.get("state") or {}).get("drives_blank", 0))
+                  // int(config["data_shards"]))
+    return total
 
 
 class Server:
@@ -237,7 +248,8 @@ class Server:
 class Workers:
     """The client worker processes of one run."""
 
-    def __init__(self, run_dir: str, mix: dict, seed: int, port: int):
+    def __init__(self, run_dir: str, mix: dict, seed: int, port: int,
+                 blank_roots: list[str] = ()):
         self.run_dir = run_dir
         self.go = os.path.join(run_dir, "go")
         self.stop_file = os.path.join(run_dir, "stop")
@@ -250,6 +262,10 @@ class Workers:
                     "go": self.go, "stop": self.stop_file,
                     "progress": os.path.join(run_dir, f"progress.{w}"),
                     "out": os.path.join(run_dir, f"records.{w}.json")}
+            if blank_roots:   # and where what is taken off them goes
+                spec["blank_roots"] = list(blank_roots)
+                spec["blank_aside"] = os.path.join(run_dir, "taken")
+                os.makedirs(spec["blank_aside"], exist_ok=True)
             path = os.path.join(run_dir, f"worker.{w}.json")
             with open(path, "w") as f:
                 json.dump(spec, f)
@@ -354,14 +370,30 @@ def preload(server: Server, pool: traffic.BodyPool, objects: list,
 
 
 def apply_state(drive_roots: list[str], state: dict) -> list[str]:
-    """Bring about the failure a configuration states -> the lost drives'
-    roots. A lost drive's tree is removed and a symbolic link to itself
+    """Bring about what a configuration states -> the roots of the drives
+    it names, lost or blank.
+
+    `drives_blank`: the first n drives hold no object any more (`blank.py`:
+    the bucket's directory emptied, the root and the format left), as after
+    a node was rebuilt; they stay online, and the client workers make them
+    blank again for a group of objects before each heal of that group.
+
+    `drives_lost`: a lost drive's tree is removed and a symbolic link to itself
     left in its place: every system call under it then fails (ELOOP), as on
     a disk that died or a node that is down, the program takes the drive
     offline and can heal nothing onto it. (A root that is merely missing is
     made anew by the heal a degraded GET queues, and a shard file removed
     from a drive that stays is healed back: either way the cell would
     measure a healthy set after its first seconds.)"""
+    if "drives_blank" in state:
+        roots = blank.blank_roots(drive_roots, state)
+        if (state.get("which"), state.get("when"), state.get("again")) != (
+                "first", "after_preload", "before_each_heal") \
+                or not 0 < len(roots) < len(drive_roots):
+            raise RunFailed("a state this harness cannot bring about: "
+                            f"{state}")
+        blank.blank_drives(roots, BUCKET)
+        return roots
     n = int(state["drives_lost"])
     if (state.get("which"), state.get("when")) != ("first", "after_preload") \
             or not 0 < n < len(drive_roots):
@@ -538,7 +570,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         t = time.monotonic()
         server = Server(run_dir, config, chips, platform,
                         launcher or os.path.join(HERE, "serve.py"), root)
-        workers = Workers(run_dir, mix, seed, server.port)
+        blank_roots = blank.blank_roots(server.drive_roots,
+                                        config.get("state"))
+        workers = Workers(run_dir, mix, seed, server.port, blank_roots)
         pool = traffic.BodyPool(mix, seed)   # while the backend comes up
         device = server.wait_device()
         phases["backend_init"] = time.monotonic() - t
@@ -557,10 +591,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         lost: list[str] = []
         if config.get("state"):
             t = time.monotonic()
-            lost = apply_state(server.drive_roots, config["state"])
+            if blank_roots:
+                # Files at rest: what the program acknowledged from memory
+                # is written out before anything is taken from under it.
+                keys = [o.key for o in objects]
+                while not blank.journals_at_rest(blank_roots, BUCKET, keys):
+                    if time.monotonic() - t > AT_REST_WAIT_S:
+                        raise RunFailed("the preloaded objects' journals "
+                                        "did not come to rest on the drives")
+                    time.sleep(0.1)
+                say(f"state: the journals of {len(keys)} objects were at "
+                    f"rest after {time.monotonic() - t:.2f} s")
+            roots = apply_state(server.drive_roots, config["state"])
             phases["state"] = time.monotonic() - t
-            say(f"state: {config['state']}: removed "
-                + " ".join(os.path.basename(r) for r in lost))
+            if blank_roots:
+                say(f"state: {config['state']}: no object left on "
+                    + " ".join(os.path.basename(r) for r in roots))
+            else:
+                lost = roots
+                say(f"state: {config['state']}: removed "
+                    + " ".join(os.path.basename(r) for r in lost))
         t = time.monotonic()
         workers.wait_ready()
         phases["clients_ready"] = time.monotonic() - t
@@ -633,8 +683,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
         # Sampled PUTs of the window: read back, then their drives against
         # the plain reference.
-        sample = verify.sample_puts(done, int(mix.get("verify_sample", 0)),
-                                    seed)
+        heals = any(o["verb"] == "HEAL" for o in mix["ops"])
+        if heals:   # the preloaded objects, once the last heal has answered
+            sample = verify.sample_preloaded(
+                objects, int(mix.get("verify_sample", 0)), seed)
+        else:
+            sample = verify.sample_puts(
+                done, int(mix.get("verify_sample", 0)), seed)
         if sample:
             t = time.monotonic()
 
@@ -659,7 +714,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 t1 + readback_wait_s)
             compared.update(verify.compare_puts(
                 sample, pool, fetch, config, server.drive_roots, BUCKET,
-                lost))
+                lost, "heal_drives" if heals else "write_quorum_drives"))
             say(f"read back: asked again {fetch.asked_again} times, "
                 f"waited {fetch.waited_first_s:.2f} s for the program to "
                 "say it is healthy, the "
@@ -672,8 +727,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             phases["compare"] = time.monotonic() - t
             if compared["readback_wrong"]["value"]:
                 note("the server's log, its end:\n" + server.log_tail(2000))
-            say(f"compared {len(sample)} sampled PUTs with the plain "
-                f"reference in {phases['compare']:.1f} s")
+            say(f"compared {len(sample)} "
+                + ("preloaded objects" if heals else "sampled PUTs")
+                + f" with the plain reference in {phases['compare']:.1f} s")
 
         admin.close()
         memory = server.stop()
@@ -690,8 +746,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         window_s = t1 - t0
         e2e = end_to_end(done, window_s, setup_s)
         # What the clients' own clocks say, for the layer metrics too.
+        groups = traffic.preload_groups(mix, objects)   # a HEAL is of one
         window = {**e2e,
                   "client_ops": len([r for r in done if r["ok"]]),
+                  "client_objects": sum(
+                      len(groups[r["body_index"]]) if r["verb"] == "HEAL"
+                      else 1 for r in done if r["ok"]),
                   "client_gap_pct": client_gap_pct(threads, t0, t1)}
         say("set-up: " + ", ".join(f"{k} {v:.2f} s" for k, v in phases.items()
                                    if k != "compare")
@@ -709,6 +769,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "at its close; drives the program holds offline: "
             f"{scrape.count_at(before, 'minio_tpu_drive_state', 2)} and "
             f"{scrape.count_at(after, 'minio_tpu_drive_state', 2)}")
+        if blank_roots:
+            say("drives the program holds online: "
+                f"{scrape.count_at(before, 'minio_tpu_drive_state', 0)} and "
+                f"{scrape.count_at(after, 'minio_tpu_drive_state', 0)} of "
+                f"{len(server.drive_roots)}")
         say("stages, ms per request over the window: " + json.dumps(
             scrape.stage_table(before, after)))
 
@@ -726,18 +791,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         else:
             sliced = [r for r in everything
                       if tracer.t_begin <= r["t_last"] <= tracer.t_stop]
-            in_slice = [(r["verb"], r["size"]) for r in sliced if r["ok"]]
+            good = [r for r in sliced if r["ok"]]
+            in_slice = [(r["verb"], r["size"]) for r in good]
             if lost:   # and how many data shards each object has lost
                 k, n = int(config["data_shards"]), int(config["drives"])
                 lost_drives = [server.drive_roots.index(r) for r in lost]
                 in_slice = [(r["verb"], r["size"], work.lost_data_shards(
                     reference.shard_of_drive(BUCKET, r["key"], n),
-                    lost_drives, k)) for r in sliced if r["ok"]]
+                    lost_drives, k)) for r in good]
                 say("traced slice: operations by data shards lost: "
                     + json.dumps(dict(sorted(collections.Counter(
                         o[2] for o in in_slice).items()))))
+            if heals:   # object by object, each rebuilt on every blank drive
+                in_slice = [op for op in in_slice if op[0] != "HEAL"] + [
+                    ("HEAL", o.size, len(blank_roots))
+                    for r in good if r["verb"] == "HEAL"
+                    for o in groups[r["body_index"]]]
+                say(f"traced slice: {len(in_slice)} objects healed or "
+                    "otherwise served")
             say(f"traced slice: {len(sliced)} operations answered, "
-                f"{len(sliced) - len(in_slice)} of them failed")
+                f"{len(sliced) - len(good)} of them failed")
             red = reduce_trace(tracer.out_path, device["platform"])
             least = work.least_seconds(
                 in_slice, int(config["data_shards"]),
@@ -749,7 +822,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             if least is not None and least["bytes"] > 0:
                 tr["codec_roofline"] = (100.0 * least["hbm_s"]
                                             / red["busy_s_busiest"])
-                say(f"traced slice: {len(in_slice)} operations completed, "
+                say(f"traced slice: {len(good)} operations completed, "
                     f"{least['bytes']} codec bytes -> {least['hbm_s']:.6f} s "
                     f"at HBM speed, {least['int_ops']} int8 ops -> "
                     f"{least['int8_s']:.6f} s at the int8 peak; busiest "

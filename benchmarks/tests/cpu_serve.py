@@ -124,6 +124,50 @@ def plant(fault: str) -> None:
                         os.mkdir(r)
 
         threading.Thread(target=remount, daemon=True).start()
+    elif fault == "healed-shard":
+        # One byte of a rebuilt shard altered on its way to the drive.
+        from minio_tpu.erasure import healing
+
+        put = healing._ShardWriterPool.put
+
+        def bad_put(self, pos, framed):
+            framed = bytearray(framed)
+            framed[-1] ^= 1
+            return put(self, pos, bytes(framed))
+
+        healing._ShardWriterPool.put = bad_put
+    elif fault == "heal-withheld":
+        # The heal commits and says so, and d0 does not keep the shard file.
+        import glob
+
+        from minio_tpu.storage import local
+
+        rename = local.LocalDrive.rename_data
+
+        def rename_and_lose(self, src_vol, src_path, fi, dst_vol, dst_path,
+                            **kw):
+            out = rename(self, src_vol, src_path, fi, dst_vol, dst_path, **kw)
+            if (src_path.startswith("tmp/heal-")
+                    and self.root.rstrip("/").endswith("d0")):
+                for part in glob.glob(os.path.join(
+                        self.root, dst_vol, dst_path, "*", "part.*")):
+                    os.unlink(part)
+            return out
+
+        local.LocalDrive.rename_data = rename_and_lose
+    elif fault == "heal-leaves-blank":
+        # The heal leaves d0 as it found it, and says so.
+        from minio_tpu.erasure import healing
+
+        rebuild = healing.HealingMixin._reconstruct_to_targets
+
+        def rebuild_but_d0(self, bucket, obj, latest, drives, avail,
+                           targets):
+            return rebuild(self, bucket, obj, latest, drives, avail, [
+                t for t in targets
+                if not str(drives[t].endpoint()).rstrip("/").endswith("d0")])
+
+        healing.HealingMixin._reconstruct_to_targets = rebuild_but_d0
     elif fault:
         raise SystemExit(f"unknown fault {fault!r}")
 

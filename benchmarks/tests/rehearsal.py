@@ -23,6 +23,27 @@ TINY_CONFIG = {
 TINY_LOST = {**TINY_CONFIG, "name": "ec2p2-4d-2lost",
              "state": {"drives_lost": 2, "which": "first",
                        "when": "after_preload"}}
+# The same set after half of it came back blank: every object has 2 of its 4
+# shards, and a heal has to put all 4 back.
+TINY_BLANK = {**TINY_CONFIG, "name": "ec2p2-4d-2blank",
+              "guarantees": {"write_quorum_drives": 3, "heal_drives": 4},
+              "state": {"drives_blank": 2, "which": "first",
+                        "when": "after_preload",
+                        "again": "before_each_heal"}}
+
+
+def tiny_heal_mix(name: str, size: int, groups: int = 2,
+                  group_objects: int = 2) -> dict:
+    return {
+        "name": name, "loop": "closed", "clients": 1, "processes": 1,
+        "ops": [{"verb": "HEAL", "weight": 1, "sizes": []}],
+        "body_pool": 3,
+        "preload": {"objects": groups * group_objects,
+                    "group_objects": group_objects, "sizes": [[size, 1]]},
+        "warmup": {"min_seconds": 1, "min_ops": groups, "quiet_seconds": 1,
+                   "max_seconds": 120},
+        "verify_sample": groups * group_objects,
+    }
 
 
 def tiny_mix(name: str, verb: str, size: int, preload: int = 0) -> dict:
@@ -41,10 +62,13 @@ def tiny_mix(name: str, verb: str, size: int, preload: int = 0) -> dict:
 
 def make_checkout(tmp: str, mixes: list[dict],
                   extra_metrics: dict | None = None,
-                  lost_mixes: list[str] = ()) -> str:
+                  lost_mixes: list[str] = (),
+                  blank_mixes: list[str] = ()) -> str:
     """-> root of a temp checkout with one cell per mix on the tiny config,
     and for each mix named in `lost_mixes` a second cell, `<cell>.2lost`, on
-    the tiny config with its state."""
+    the tiny config with its state; a mix named in `blank_mixes` gets one
+    cell only, `<cell>.2blank`, on the tiny config that came back blank,
+    which like the cell it rehearses reports no `op_p90_ms`."""
     root = os.path.join(tmp, "checkout")
     os.makedirs(root)
     shutil.copytree(BENCH, os.path.join(root, "benchmarks"),
@@ -53,7 +77,7 @@ def make_checkout(tmp: str, mixes: list[dict],
         os.symlink(os.path.join(REPO, name), os.path.join(root, name))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    for conf in (TINY_CONFIG, TINY_LOST):
+    for conf in (TINY_CONFIG, TINY_LOST, TINY_BLANK):
         file = f"benchmarks/configs/{conf['name']}.json"
         with open(os.path.join(root, file), "w") as f:
             json.dump(conf, f)
@@ -64,6 +88,8 @@ def make_checkout(tmp: str, mixes: list[dict],
         with open(os.path.join(root, "benchmarks", "traffic",
                                mix["name"] + ".json"), "w") as f:
             json.dump(mix, f)
+        if mix["name"] in blank_mixes:
+            continue
         bench["workloads"].append({
             "name": f"ec2p2-4d.{mix['name']}", "config": "ec2p2-4d",
             "traffic": mix["name"], "chips": 1, "why": "test"})
@@ -76,6 +102,10 @@ def make_checkout(tmp: str, mixes: list[dict],
         if "workloads" in m and m["name"] != "ops_per_s":
             m["workloads"] += [w["name"] for w in bench["workloads"]
                                if w["config"].startswith("ec2p2-4d")]
+    for name in blank_mixes:     # after the lists above were filled
+        bench["workloads"].append({
+            "name": f"ec2p2-4d.{name}.2blank", "config": "ec2p2-4d-2blank",
+            "traffic": name, "chips": 1, "why": "test"})
     for name, (entry, spec) in (extra_metrics or {}).items():
         with open(os.path.join(root, "benchmarks", "layer_metrics",
                                name + ".json"), "w") as f:

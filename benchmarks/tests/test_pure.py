@@ -6,6 +6,7 @@ import os
 import numpy as np
 import pytest
 
+import blank
 import reference
 import rehearsal
 import run
@@ -14,6 +15,7 @@ import trace_reduce
 import traffic
 import verify
 import work
+import worker
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -177,6 +179,27 @@ def test_lost_data_shards_follow_from_the_key():
     assert work.lost_data_shards(list(range(16)), [], 12) == 0
 
 
+def test_codec_work_of_a_heal_by_hand():
+    """12+4, 10 MiB healed onto four blank drives: the 12 rows that are left
+    are read once and checked, 4 rows written with their digests, whatever
+    kernel does it and whether the rows are data or parity; pinned beside
+    the t = 0 numbers above."""
+    mib, shard = 1 << 20, 10 * 87382
+    assert work.codec_bytes("HEAL", 10 * mib, 12, 4, mib, 4) == (
+        shard * 12 + shard * 4 + 10 * 16 * 32) == 13986240
+    assert work.codec_int_ops("HEAL", 10 * mib, 12, 4, mib, 4) == (
+        shard * 16 * 16 + shard * 2 * 96 * 32) == 5592448000
+    # one blank drive of the tiny set, a ragged object
+    assert work.codec_bytes("HEAL", 3 * mib + 5, 2, 2, mib, 1) == (
+        (3 * 524288 + 3) * 3 + 4 * 3 * 32)
+    assert work.codec_bytes("HEAL", 10 * mib, 12, 4, mib) == (
+        work.codec_bytes("GET", 10 * mib, 12, 4, mib))   # nothing to rebuild
+    group = work.least_seconds([("HEAL", 10 * mib, 4)] * 8, 12, 4, mib,
+                               "TPU v5 lite")
+    assert group["bytes"] == 8 * 13986240
+    assert group["hbm_s"] == pytest.approx(8 * 13986240 / 819e9)
+
+
 # --- a configuration's state -----------------------------------------------
 
 
@@ -230,6 +253,66 @@ def test_apply_state_refuses_what_it_cannot_bring_about(tmp_path, state):
     assert run.roots_present(roots) == set(roots)
 
 
+BLANK_STATE = {"drives_blank": 2, "which": "first", "when": "after_preload",
+               "again": "before_each_heal"}
+
+
+def test_apply_state_leaves_a_blank_drive_its_root_and_its_bucket(tmp_path):
+    roots = _tree(tmp_path)
+    for r in roots:
+        os.makedirs(os.path.join(r, ".mtpu.sys"))
+        with open(os.path.join(r, ".mtpu.sys", "format.json"), "w") as f:
+            f.write("{}")
+    assert blank.shard_files_absent(roots, "bench", ["key"]) == 0
+    assert run.apply_state(roots, BLANK_STATE) == roots[:2]
+    assert blank.blank_roots(roots, BLANK_STATE) == roots[:2]
+    for r in roots[:2]:   # online, formatted, with the bucket, no object
+        assert os.listdir(os.path.join(r, "bench")) == []
+        assert os.path.isfile(os.path.join(r, ".mtpu.sys", "format.json"))
+    for r in roots[2:]:
+        assert os.path.isfile(os.path.join(r, "bench", "key", "dir",
+                                           "part.1"))
+    assert run.roots_present(roots) == set(roots)
+    assert blank.shard_files_absent(roots, "bench", ["key"]) == 2
+    assert blank.shard_files_absent(roots[2:], "bench", ["key", "no"]) == 2
+    # the worker's part: again, for some objects, where they are back
+    aside = str(tmp_path / "taken")
+    os.mkdir(aside)
+    blank.blank_objects(roots[2:3], "bench", ["key", "never-there"], aside,
+                        iter(["a", "b"]))
+    assert os.listdir(os.path.join(roots[2], "bench")) == []
+    assert os.listdir(aside) == ["a"] and os.path.isfile(
+        os.path.join(aside, "a", "dir", "part.1"))
+    assert blank.shard_files_absent(roots, "bench", ["key"]) == 3
+    # no other state names blank drives
+    assert blank.blank_roots(roots, None) == []
+    assert blank.blank_roots(roots, {"drives_lost": 2}) == []
+
+
+def test_journals_at_rest_wants_every_journal_as_a_file(tmp_path):
+    roots = _tree(tmp_path)
+    assert not blank.journals_at_rest(roots[:2], "bench", ["key"])
+    for r in roots[:2]:
+        open(os.path.join(r, "bench", "key", "meta.mp"), "w").close()
+    assert blank.journals_at_rest(roots[:2], "bench", ["key"])
+    assert not blank.journals_at_rest(roots[:3], "bench", ["key"])
+    assert not blank.journals_at_rest(roots[:2], "bench", ["key", "other"])
+
+
+@pytest.mark.parametrize("state", [
+    {**BLANK_STATE, "drives_blank": 0}, {**BLANK_STATE, "drives_blank": 4},
+    {**BLANK_STATE, "which": "last"}, {**BLANK_STATE, "when": "in_window"},
+    {**BLANK_STATE, "again": "never"},
+    {k: v for k, v in BLANK_STATE.items() if k != "again"},
+])
+def test_apply_state_refuses_a_blank_state_it_cannot_bring_about(tmp_path,
+                                                                 state):
+    roots = _tree(tmp_path)
+    with pytest.raises(run.RunFailed):
+        run.apply_state(roots, state)
+    assert blank.shard_files_absent(roots, "bench", ["key"]) == 0
+
+
 def test_drive_check_does_not_look_under_lost_roots(tmp_path):
     config = {"data_shards": 2, "parity_shards": 2, "block_size": 4}
     roots = [str(tmp_path / f"d{i}") for i in range(4)]
@@ -273,6 +356,93 @@ def test_the_degraded_cell_loads_by_name():
     put = [m["name"] for m in run.load_cell("ec12p4-16d.put-10MiB")[
         "per_layer"]]
     assert "get_decode_ms_per_op" not in put
+
+
+def test_the_heal_cell_loads_by_name_and_is_data_alone():
+    """Configuration, mix and the four metrics it brings are files found by
+    name; nothing that stood lists the cell."""
+    cell = "ec12p4-16d.heal-10MiB.4blank"
+    loaded = run.load_cell(cell)
+    assert loaded["cell"]["chips"] == 1
+    assert loaded["config"]["state"] == {
+        "drives_blank": 4, "which": "first", "when": "after_preload",
+        "again": "before_each_heal"}
+    assert loaded["config"]["guarantees"]["heal_drives"] == 16
+    twin = run.load_cell("ec12p4-16d.get-10MiB.4lost")
+    for key in ("drives", "data_shards", "parity_shards", "block_size",
+                "bitrot", "versioned", "pools", "nodes"):
+        assert loaded["config"][key] == twin["config"][key]
+    mix = loaded["mix"]
+    assert [o["verb"] for o in mix["ops"]] == ["HEAL"]
+    assert (mix["clients"], mix["processes"]) == (1, 1)
+    assert mix["preload"]["objects"] == mix["verify_sample"] == 64
+    assert [m["name"] for m in loaded["end_to_end"]] == [
+        "goodput_mibps", "setup_s"]
+    assert [m["name"] for m in loaded["per_layer"]] == [
+        "client_gap_pct", "codec_roofline", "device_idle_pct",
+        "heal_rebuild_ms_per_object", "heal_verify_ms_per_object",
+        "heal_launches_per_object", "heal_compile_ms_per_object"]
+    with open(os.path.join(rehearsal.REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    listed = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]
+              if cell in m.get("workloads", [])]
+    assert listed == [m["name"] for m in loaded["per_layer"][3:]]
+    for other in bench["workloads"]:
+        if other["name"] != cell:
+            assert not any(m["name"].startswith("heal_") for m in
+                           run.load_cell(other["name"])["per_layer"])
+
+
+def test_the_heal_metrics_on_expositions_by_hand():
+    loaded = run.load_cell("ec12p4-16d.heal-10MiB.4blank")
+    specs = {m["name"]: m for m in loaded["per_layer"]}
+
+    def kernel(kind, name):
+        return (f"minio_tpu_kernel_seconds_{kind}",
+                (("backend", "tpu:pallas"), ("kernel", name)))
+
+    before = {kernel("sum", "reconstruct_weights"): 1.0,
+              kernel("count", "reconstruct_weights"): 100.0,
+              kernel("sum", "verify_digests"): 2.0,
+              kernel("count", "verify_digests"): 100.0,
+              kernel("sum", "encode_digests"): 5.0,
+              kernel("count", "encode_digests"): 64.0}
+    after = {**before,
+             kernel("sum", "reconstruct_weights"): 1.048,
+             kernel("count", "reconstruct_weights"): 116.0,
+             kernel("sum", "verify_digests"): 2.024,
+             kernel("count", "verify_digests"): 116.0,
+             ("minio_tpu_jit_compile_seconds_total",
+              (("program", "jit(verify_digests)"),)): 0.032}
+    # two HEALs of eight objects each
+    ctx = {"before": before, "after": after, "trace": {},
+           "window": {"client_ops": 2, "client_objects": 16}}
+    assert run.layer_value(specs["heal_rebuild_ms_per_object"],
+                           ctx) == pytest.approx(3.0)
+    assert run.layer_value(specs["heal_verify_ms_per_object"],
+                           ctx) == pytest.approx(1.5)
+    assert run.layer_value(specs["heal_launches_per_object"], ctx) == 2.0
+    assert run.layer_value(specs["heal_compile_ms_per_object"],
+                           ctx) == pytest.approx(2.0)
+    # no object healed in the window: nothing to divide by, nothing reported
+    none = {**ctx, "window": {"client_ops": 0, "client_objects": 0}}
+    assert all(run.layer_value(specs[n], none) is None for n in specs
+               if n.startswith("heal_"))
+
+
+def test_written_bytes_knows_heals_share():
+    """What the cells that stood may write is what it was; a heal writes
+    the blank drives' share, 4/12, of the bytes it heals."""
+    mib = 1 << 20
+    for cell, want in (("ec12p4-16d.put-10MiB", 8388608000),
+                       ("ec8p4-12d.put-128KiB", 9437184000),
+                       ("ec12p4-16d.get-10MiB", 894784853),
+                       ("ec12p4-16d.get-10MiB.4lost", 894784853)):
+        loaded = run.load_cell(cell)
+        assert run.written_bytes(loaded["mix"], loaded["config"], 20) == want
+    loaded = run.load_cell("ec12p4-16d.heal-10MiB.4blank")
+    assert run.written_bytes(loaded["mix"], loaded["config"], 20) == (
+        64 * 10 * mib * 16 // 12 + 500 * mib * 40 * 4 // 12)
 
 
 @pytest.mark.parametrize("cell,n_end_to_end,n_per_layer", [
@@ -407,6 +577,113 @@ def test_traffic_follows_from_the_seed():
     assert sum(traffic.split_clients(mix)) == 20
     assert traffic.make_body(5, 64, 1) == traffic.make_body(5, 64, 1)
     assert traffic.make_body(5, 64, 1) != traffic.make_body(6, 64, 1)
+
+
+def _heal_mix():
+    with open(os.path.join(os.path.dirname(os.path.dirname(DATA)), "traffic",
+                           "heal-10MiB.json")) as f:
+        return json.load(f)
+
+
+def test_a_heal_is_of_a_group_and_goes_round_them_in_a_seeded_order():
+    mix, seed = _heal_mix(), 3000000000
+    pre = traffic.preload_objects(mix, seed)
+    groups = traffic.preload_groups(mix, pre)
+    assert [len(g) for g in groups] == [8] * 8
+    assert [o for g in groups for o in g] == pre
+    prefixes = [traffic.group_prefix(seed, g) for g in range(8)]
+    assert prefixes[3] == "s3000000000/pre/g0003/"
+    for g, (prefix, objs) in enumerate(zip(prefixes, groups)):
+        assert all(o.key.startswith(prefix) for o in objs)
+        assert not any(o.key.startswith(prefix) for o in pre
+                       if o not in objs)
+        assert not any(p.startswith(prefix) for p in prefixes if p != prefix)
+    a = traffic.OpStream(mix, seed, 0, 0)
+    ops = [a.next() for _ in range(24)]
+    assert {op.verb for op in ops} == {"HEAL"}
+    assert all(op.key == prefixes[op.body_index] and op.size == 8 * 10485760
+               for op in ops)
+    order = [op.body_index for op in ops]
+    assert sorted(order[:8]) == list(range(8))       # every group once,
+    assert order[8:16] == order[:8] == order[16:]    # then round again
+    b = traffic.OpStream(mix, seed, 0, 0)
+    assert [b.next() for _ in range(24)] == ops
+    # every seed the same work, in another order
+    def first_round(s):
+        gen = traffic.OpStream(mix, s, 0, 0)
+        return tuple(gen.next().body_index for _ in range(8))
+
+    orders = {first_round(s) for s in range(1, 7)}
+    assert len(orders) > 1 and all(sorted(o) == list(range(8))
+                                   for o in orders)
+    # a mix without groups keeps the keys it had
+    with open(os.path.join(os.path.dirname(os.path.dirname(DATA)), "traffic",
+                           "get-10MiB.json")) as f:
+        get = json.load(f)
+    assert traffic.preload_groups(
+        get, traffic.preload_objects(get, seed)) == []
+    assert traffic.preload_objects(get, seed)[5].key == (
+        "s3000000000/pre/000005")
+    with pytest.raises(ValueError, match="group_objects"):
+        traffic.OpStream({**mix, "preload": get["preload"]}, seed, 0,
+                         0).next()
+
+
+def _item(key, before, after, **more):
+    return {"bucket": "bench", "object": key, **more,
+            "before": [{"endpoint": f"d{i}", "state": s}
+                       for i, s in enumerate(before)],
+            "after": [{"endpoint": f"d{i}", "state": s}
+                      for i, s in enumerate(after)]}
+
+
+@pytest.mark.parametrize("items,good", [
+    ([_item("a", ["missing", "ok", "ok"], ["ok"] * 3),
+      _item("b", ["corrupt", "ok", "ok"], ["ok"] * 3)], True),
+    # the bucket's own item names no object
+    ([{"bucket": "bench", "object": ""},
+      _item("a", ["missing", "ok", "ok"], ["ok"] * 3),
+      _item("b", ["missing", "ok", "ok"], ["ok"] * 3)], True),
+    ([_item("a", ["missing", "ok", "ok"], ["ok"] * 3)], False),   # b?
+    ([_item("a", ["missing", "ok", "ok"], ["ok"] * 3),
+      _item("b", ["missing", "ok", "ok"], ["ok"] * 3),
+      _item("c", ["missing", "ok", "ok"], ["ok"] * 3)], False),   # c?
+    ([_item("a", ["missing", "ok", "ok"], ["ok"] * 3),
+      _item("b", ["missing", "ok", "ok"], ["ok"] * 3, error="Lock")],
+     False),
+    ([_item("a", ["missing", "ok", "ok"], ["ok"] * 3),
+      _item("b", ["missing", "ok", "ok"], ["missing", "ok", "ok"])], False),
+    # found nothing to heal: the state did not hold before it
+    ([_item("a", ["ok"] * 3, ["ok"] * 3),
+      _item("b", ["missing", "ok", "ok"], ["ok"] * 3)], False),
+    ([_item("a", ["missing", "ok", "ok"], ["ok"] * 3)] * 2
+     + [_item("b", ["missing", "ok", "ok"], ["ok"] * 3)], False),
+])
+def test_what_a_heals_reply_has_to_say(items, good):
+    body = json.dumps({"items": items}).encode()
+    assert worker.healed(body, ["a", "b"], 1) is good
+
+
+def test_a_heals_reply_that_is_no_such_reply():
+    for body in (b"", b"<Error/>", b"[]", b'{"items": 3}',
+                 b'{"items": [{"object": "a"}]}'):
+        assert worker.healed(body, ["a"], 1) is False
+    with pytest.raises(ValueError, match="sends PUT, GET, HEAL"):
+        worker.one_request(None, "bench", traffic.Op("HEAD", "k", 1, 0),
+                           None)
+
+
+def test_the_sample_of_a_mix_that_heals_is_of_the_preloaded_objects():
+    mix = _heal_mix()
+    pre = traffic.preload_objects(mix, 7)
+    whole = verify.sample_preloaded(pre, 64, 7)
+    assert [(r["key"], r["size"], r["body_index"]) for r in whole] == [
+        (o.key, o.size, o.body_index) for o in pre]
+    some = verify.sample_preloaded(pre, 10, 7)
+    assert 10 <= len(some) <= 12 and some[0] == whole[0] \
+        and some[-1] == whole[-1]
+    assert some == verify.sample_preloaded(pre, 10, 7)
+    assert verify.sample_preloaded(pre, 0, 7) == []
 
 
 def test_reduction_of_a_recorded_v5e_trace(tmp_path):

@@ -17,6 +17,7 @@ KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 SIZE = 3 * 1048576 + 5            # three blocks and a ragged fourth
 SEED = 2147483659                 # more than 32 signed bits hold
 LOST = "ec2p2-4d.get-tiny.2lost"   # d0 and d1 gone after the preload
+BLANK = "ec2p2-4d.heal-tiny.2blank"   # d0 and d1 back, blank, after it
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
 ENV.pop("BENCH_TEST_FAULT", None)
 
@@ -36,8 +37,9 @@ def checkout(tmp_path_factory):
     tmp = str(tmp_path_factory.mktemp("bench"))
     return rehearsal.make_checkout(
         tmp, [rehearsal.tiny_mix("put-tiny", "PUT", SIZE),
-              rehearsal.tiny_mix("get-tiny", "GET", SIZE, preload=4)],
-        EXTRA_METRIC, lost_mixes=["get-tiny"])
+              rehearsal.tiny_mix("get-tiny", "GET", SIZE, preload=4),
+              rehearsal.tiny_heal_mix("heal-tiny", SIZE)],
+        EXTRA_METRIC, lost_mixes=["get-tiny"], blank_mixes=["heal-tiny"])
 
 
 def cpu_run(checkout, workload, fault=""):
@@ -132,12 +134,48 @@ def test_degraded_get_cell_whole(checkout):
     assert stages["GetObject"]["decode"][0] > 1.0
 
 
+def test_heal_cell_whole(checkout):
+    """Two of the four drives blank after the preload and again before each
+    heal of a group: they stay online, every HEAL of the window puts a
+    group's two objects back on them, and after the last one all four
+    drives hold every object as the plain reference encodes it."""
+    p = cpu_run(checkout, BLANK)
+    r = last_line(p)
+    assert list(r) == KEYS
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert set(r["metrics"]) == {"goodput_mibps", "setup_s"}
+    c = r["compared"]
+    assert list(c) == ["failed_ops", "wrong_answers", "readback_wrong",
+                       "shards_wrong", "drives_holding_min"]
+    assert c["drives_holding_min"] == {"value": 4, "limit": 4}
+    assert all(c[k] == {"value": 0, "limit": 0} for k in list(c)[:4])
+    assert "\nstate: the journals of 4 objects were at rest after " in p.stdout
+    assert "\nstate: {'drives_blank': 2" in p.stdout
+    assert "no object left on d0 d1\n" in p.stdout
+    assert "drives the program holds offline: 0 and 0\n" in p.stdout
+    assert "drives the program holds online: 4 and 4 of 4\n" in p.stdout
+    assert "compared 4 preloaded objects with the plain reference" in p.stdout
+    window = next(ln for ln in p.stdout.splitlines()
+                  if ln.startswith("the clients' clock"))
+    e2e = json.loads(window.split(": ", 1)[1])
+    # an operation is a group: two objects' bytes
+    assert e2e["goodput_mibps"] == pytest.approx(
+        e2e["ops_per_s"] * 2 * SIZE / 1048576)
+    stages = json.loads(next(
+        ln for ln in p.stdout.splitlines()
+        if ln.startswith("stages, ")).split(": ", 1)[1])
+    assert stages["admin.heal"]["auth"][1] == r["attempted"]
+
+
 @pytest.mark.parametrize("workload,fault,caught_by", [
     ("ec2p2-4d.put-tiny", "parity", "shards_wrong"),
     ("ec2p2-4d.put-tiny", "lost-drives", "drives_holding_min"),
     ("ec2p2-4d.get-tiny", "get-body", "wrong_answers"),
     (LOST, "rebuilt-row", "wrong_answers"),
     (LOST, "drive-back", "lost_drives_present"),
+    (BLANK, "healed-shard", "shards_wrong"),
+    (BLANK, "heal-withheld", "wrong_answers"),
+    (BLANK, "heal-leaves-blank", "drives_holding_min"),
 ])
 def test_a_fault_under_the_timed_path_reads_not_correct(
         checkout, workload, fault, caught_by):
@@ -173,6 +211,11 @@ def test_drives_offline_at_the_read_back_make_it_late_not_wrong(checkout):
     (LOST, "bit-exact", False, "wrong_answers"),
     (LOST, "rebuild", False, "wrong_answers"),
     (LOST, "state", False, "lost_drives_present"),
+    (BLANK, "none", True, None),
+    (BLANK, "bit-exact", False, "readback_wrong"),
+    (BLANK, "heal-zeros", False, "shards_wrong"),
+    (BLANK, "heal-skip", False, "wrong_answers"),
+    (BLANK, "heal-skip", False, "drives_holding_min"),
 ])
 def test_the_control_reads_not_correct(checkout, workload, broken, correct,
                                        caught_by):

@@ -4,16 +4,22 @@ A mix (`traffic/<name>.json`) gives
 
     clients, processes   closed-loop clients in all, and the worker processes
                          they are spread over
-    ops                  [{"verb": "PUT"|"GET", "weight": w,
+    ops                  [{"verb": "PUT"|"GET"|"HEAL", "weight": w,
                            "sizes": [[bytes, weight], ...]}]
     body_pool            distinct bodies per size, made from the seed
     preload              {"objects": n, "sizes": [[bytes, weight], ...]}:
                          objects that set-up PUTs and that GETs draw from,
-                         uniformly
+                         uniformly. With "group_objects": g the objects
+                         lie in groups of g, each under a prefix of its own
+                         (none a prefix of another's); a HEAL names one
+                         group's prefix, its size is the group's object
+                         bytes, and a client goes round the groups in an
+                         order drawn from the seed
     warmup               {"min_seconds":, "min_ops":, "quiet_seconds":,
                           "max_seconds":}
-    verify_sample        PUTs of the window whose drives are compared with
-                         the plain reference
+    verify_sample        PUTs of the window (in a mix that heals: preloaded
+                         objects) whose drives are compared with the plain
+                         reference
 
 Everything a client sends follows from (--seed, worker, thread): the order
 of verbs, sizes and keys. Bodies come from a pool made from the seed once,
@@ -85,11 +91,27 @@ def preload_objects(mix: dict, seed: int) -> list[Op]:
     n = int(pre.get("objects", 0))
     rng = np.random.default_rng([seed, 0x9E3779B9])
     pool = int(mix["body_pool"])
+    group = int(pre.get("group_objects", 0))
     out = []
     for i in range(n):
         size = int(pre["sizes"][_draw(rng, pre["sizes"])][0])
-        out.append(Op("PUT", f"s{seed}/pre/{i:06d}", size, i % pool))
+        under = group_prefix(seed, i // group) if group else f"s{seed}/pre/"
+        out.append(Op("PUT", f"{under}{i:06d}", size, i % pool))
     return out
+
+
+def group_prefix(seed: int, g: int) -> str:
+    """The prefix of group g: of one width and closed by a slash, so that
+    none is a prefix of another's."""
+    return f"s{seed}/pre/g{g:04d}/"
+
+
+def preload_groups(mix: dict, pre: list[Op]) -> list[list[Op]]:
+    """The preloaded objects `pre` by group, in order; [] where the mix has
+    no groups."""
+    group = int((mix.get("preload") or {}).get("group_objects", 0))
+    return [pre[i:i + group] for i in range(0, len(pre), group)] \
+        if group else []
 
 
 class OpStream:
@@ -97,10 +119,16 @@ class OpStream:
 
     def __init__(self, mix: dict, seed: int, worker: int, thread: int):
         self.mix = mix
+        self.seed = seed
         self.rng = np.random.default_rng([seed, worker, thread])
         self.prefix = f"s{seed}/w{worker}t{thread}"
         self.pool = int(mix["body_pool"])
         self.pre = preload_objects(mix, seed)
+        self.groups = preload_groups(mix, self.pre)
+        # a HEAL goes round the groups, in this client's own order
+        self.round = [int(g) for g in np.random.default_rng(
+            [seed, worker, thread, 0x4EA1]).permutation(len(self.groups))]
+        self.heals = 0
         self.count = 0
 
     def next(self) -> Op:
@@ -112,6 +140,14 @@ class OpStream:
                 raise ValueError("a mix with GETs needs preload.objects")
             src = self.pre[int(self.rng.integers(len(self.pre)))]
             return Op("GET", src.key, src.size, src.body_index)
+        if spec["verb"] == "HEAL":
+            if not self.groups:
+                raise ValueError("a mix with HEALs needs preload.objects "
+                                 "and preload.group_objects")
+            g = self.round[self.heals % len(self.round)]
+            self.heals += 1
+            return Op("HEAL", group_prefix(self.seed, g),
+                      sum(o.size for o in self.groups[g]), g)
         size = int(spec["sizes"][_draw(self.rng, spec["sizes"])][0])
         return Op(spec["verb"], f"{self.prefix}/{self.count:07d}", size,
                   int(self.rng.integers(self.pool)))

@@ -19,7 +19,11 @@ the quorum's, which the configuration states):
                         or at its close (the state did not hold)        <= 0
 
 In a state the drives' check does not look under the lost drives' roots, and
-`drives_holding_min` keeps the configuration's limit.
+`drives_holding_min` keeps the configuration's limit. Where the mix heals,
+the sample is of the preloaded objects, which the window healed again and
+again; it is taken once the last heal has answered, every drive is looked
+at, and `drives_holding_min` has the limit the configuration states for a
+healed object (`guarantees.heal_drives`: every drive of the set).
 
 The reference encodes each distinct body once (bodies repeat, keys do not);
 which drive holds which shard follows from the key.
@@ -34,21 +38,31 @@ import time
 import reference
 
 
+def _drawn(items: list, n: int, seed: int, salt: int) -> list:
+    """n of the items (all where there are no more), drawn from the seed,
+    the first and the last among them, in their order."""
+    import numpy as np
+
+    if n <= 0 or len(items) <= n:
+        return items if n > 0 else []
+    rng = np.random.default_rng([seed, salt])
+    idx = set(rng.choice(len(items), size=n, replace=False).tolist())
+    idx |= {0, len(items) - 1}
+    return [items[i] for i in sorted(idx)]
+
+
 def sample_puts(records: list[dict], n: int, seed: int) -> list[dict]:
     """n acknowledged PUTs of the window, drawn from the seed, the first and
     the last acknowledged among them."""
-    import numpy as np
-
     puts = sorted((r for r in records if r["verb"] == "PUT" and r["ok"]),
                   key=lambda r: r["t_last"])
-    if n <= 0 or not puts:
-        return []
-    if len(puts) <= n:
-        return puts
-    rng = np.random.default_rng([seed, 0x5A17])
-    idx = set(rng.choice(len(puts), size=n, replace=False).tolist())
-    idx |= {0, len(puts) - 1}
-    return [puts[i] for i in sorted(idx)]
+    return _drawn(puts, n, seed, 0x5A17)
+
+
+def sample_preloaded(objects: list, n: int, seed: int) -> list[dict]:
+    """n of the preloaded objects, drawn likewise, as records to compare."""
+    return _drawn([{"key": o.key, "size": o.size, "body_index": o.body_index}
+                   for o in objects], n, seed, 0x4EA1)
 
 
 class DriveCheck:
@@ -172,10 +186,12 @@ def state_held(seen: set[str]) -> dict:
 
 def compare_puts(sample: list[dict], bodies, fetch, config: dict,
                  drive_roots: list[str], bucket: str,
-                 lost_roots: list[str] = ()) -> dict:
+                 lost_roots: list[str] = (),
+                 guarantee: str = "write_quorum_drives") -> dict:
     """The sampled PUTs read back through `fetch(key) -> Reply`, and their
     drives against the plain reference. `bodies.get(size, index).data` is
-    what was sent."""
+    what was sent. `guarantee` names the one that `drives_holding_min` is
+    held to."""
     check = DriveCheck(config, drive_roots, bucket, lost_roots)
     readback_wrong = shards_wrong = 0
     holding = []
@@ -194,7 +210,7 @@ def compare_puts(sample: list[dict], bodies, fetch, config: dict,
                          "better": "lower"},
         "drives_holding_min": {
             "value": min(holding),
-            "limit": int(config["guarantees"]["write_quorum_drives"]),
+            "limit": int(config["guarantees"][guarantee]),
             "better": "higher"},
     }
 

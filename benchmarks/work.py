@@ -7,6 +7,9 @@ the k data shards' bytes once (to checksum them) and writes their digests.
 In a configuration's `state` an object has lost t of its data shards (which
 ones follows from its key): a GET then reads k rows that are left once, to
 checksum them and to rebuild from them, and writes the t rebuilt rows too.
+A HEAL of one object onto t drives that hold none of it does the same to
+every block of the object, whether the t rows are data or parity: k rows
+read once, t written, and a digest for each of the k + t.
 """
 
 from __future__ import annotations
@@ -49,12 +52,16 @@ def lost_data_shards(shard_of_drive: list[int], lost_drives: list[int],
 
 def codec_bytes(verb: str, size: int, k: int, m: int,
                 block_size: int, lost_data: int = 0) -> int:
-    """HBM bytes the codec has to move for one operation, at the least."""
+    """HBM bytes the codec has to move for one operation, at the least.
+    `lost_data` is t: a GET's lost data shards, a HEAL's rebuilt shards."""
     blocks, shard = _rows(size, k, block_size)
     if verb == "PUT":
         return size + shard * m + blocks * (k + m) * DIGEST_LEN
     if verb == "GET":
         return shard * k + shard * lost_data + blocks * k * DIGEST_LEN
+    if verb == "HEAL":
+        return (shard * k + shard * lost_data
+                + blocks * (k + lost_data) * DIGEST_LEN)
     return 0
 
 
@@ -69,6 +76,9 @@ def codec_int_ops(verb: str, size: int, k: int, m: int,
         return shard * 2 * (k * 8) * (m * 8) + shard * (k + m) * 16
     if verb == "GET":
         return shard * k * 16 + shard * 2 * (k * 8) * (lost_data * 8)
+    if verb == "HEAL":
+        return (shard * (k + lost_data) * 16
+                + shard * 2 * (k * 8) * (lost_data * 8))
     return 0
 
 

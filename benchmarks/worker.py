@@ -5,15 +5,18 @@
 The spec (written by run.py) names the endpoint, the seed, this worker's
 index, its thread count, the traffic mix and three paths: a `ready` file this
 process creates when its bodies are prepared, a `go` file it waits for, and a
-`stop` file that ends the loops. Every request is recorded with the client's
-own clock (time.monotonic(), one clock for all processes of a machine) and
-written to `out` when the threads have ended. Imports nothing of the program
-and never touches JAX.
+`stop` file that ends the loops; for a mix that heals, `blank_roots`: the
+drives that came back blank, whose objects of a group this process takes
+off again before that group's heal, into `blank_aside` (`blank.py`). Every request is recorded with
+the client's own clock (time.monotonic(), one clock for all processes of a
+machine) and written to `out` when the threads have ended. Imports nothing
+of the program and never touches JAX.
 """
 
 from __future__ import annotations
 
 import http.client
+import itertools
 import json
 import os
 import sys
@@ -22,6 +25,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import blank  # noqa: E402
 import traffic  # noqa: E402
 from s3http import S3Http  # noqa: E402
 
@@ -33,11 +37,40 @@ from s3http import S3Http  # noqa: E402
 RETRIES = 9
 BACKOFF_S = 0.2
 BACKOFF_CAP_S = 1.0
+# What `mc admin heal -r bucket/prefix` sends (madmin HealOpts; scanMode 1
+# is the normal scan).
+HEAL_OPTS = json.dumps({"dryRun": False, "scanMode": 1}).encode()
+VERBS = ("PUT", "GET", "HEAL")
 
 
-def one_request(c: S3Http, bucket: str, op: traffic.Op, body: traffic.Body):
+def healed(reply_body: bytes, keys: list[str], n_blank: int) -> bool:
+    """The heal's reply calls every one of these objects healed: one item
+    each, no `error`, `n_blank` drives not `ok` before it (it met the state
+    the configuration names, and had that much to do) and every drive `ok`
+    after it."""
+    try:
+        items = json.loads(reply_body)["items"]
+        said = {}
+        for it in items:
+            if it.get("object"):
+                said.setdefault(it["object"], []).append(
+                    not it.get("error") and bool(it.get("after"))
+                    and all(d["state"] == "ok" for d in it["after"])
+                    and sum(d["state"] != "ok"
+                            for d in it["before"]) == n_blank)
+    except (ValueError, KeyError, TypeError, AttributeError):
+        return False
+    return set(said) == set(keys) and all(v == [True] for v in said.values())
+
+
+def one_request(c: S3Http, bucket: str, op: traffic.Op,
+                body: traffic.Body | None, group: list[str] = (),
+                blank_roots: list[str] = ()):
     """-> (reply, good, wrong): `wrong` is an answer that says the wrong
-    thing, `good` a complete 2xx."""
+    thing, `good` a complete 2xx. A HEAL is given its group's keys and the
+    roots of the drives that came back blank: its answer is wrong where it
+    calls an object healed of which a shard file is not on such a drive
+    (looked for after the reply, outside the request's times)."""
     if op.verb == "PUT":
         r = c.request("PUT", f"/{bucket}/{op.key}", body=body.data,
                       body_sha256=body.sha256)
@@ -47,7 +80,14 @@ def one_request(c: S3Http, bucket: str, op: traffic.Op, body: traffic.Body):
         r = c.request("GET", f"/{bucket}/{op.key}")
         return (r, r.status == 200 and r.size == op.size,
                 r.status == 200 and not r.matches(body.data))
-    raise ValueError(f"verb {op.verb!r} is not generated")
+    if op.verb == "HEAL":
+        r = c.request("POST", f"/minio/admin/v3/heal/{bucket}/{op.key}",
+                      body=HEAL_OPTS)
+        good = r.status == 200 and healed(r.body, group, len(blank_roots))
+        return r, good, good and blank.shard_files_absent(
+            blank_roots, bucket, group) > 0
+    raise ValueError(f"verb {op.verb!r} is not generated: this worker "
+                     f"sends {', '.join(VERBS)}")
 
 
 def client_loop(spec: dict, thread: int, pool: traffic.BodyPool,
@@ -57,16 +97,31 @@ def client_loop(spec: dict, thread: int, pool: traffic.BodyPool,
     c = S3Http(spec["host"], spec["port"], spec["access"], spec["secret"])
     bucket = spec["bucket"]
     stop = spec["stop"]
+    blank_roots = spec.get("blank_roots", [])
+    aside_names = (f"w{spec['worker']}t{thread}.{n}"
+                   for n in itertools.count())
     try:
         while not os.path.exists(stop):
             op = gen.next()
-            body = pool.get(op.size, op.body_index)
+            group: list[str] = []
+            if op.verb == "HEAL":
+                # the drives are blank again for this group, then its heal
+                if not blank_roots:
+                    raise ValueError("a mix with HEALs needs a configuration "
+                                     "whose state names drives_blank")
+                body = None
+                group = [o.key for o in gen.groups[op.body_index]]
+                blank.blank_objects(blank_roots, bucket, group,
+                                    spec["blank_aside"], aside_names)
+            else:
+                body = pool.get(op.size, op.body_index)
             try:
                 # A 503 is the server shedding load and asks for a retry:
                 # S3 clients (warp's minio-go among them) back off and send
                 # again, and the operation's time runs from its first send.
                 for attempt in range(RETRIES + 1):
-                    r, good, wrong = one_request(c, bucket, op, body)
+                    r, good, wrong = one_request(c, bucket, op, body, group,
+                                                 blank_roots)
                     if attempt == 0:
                         t_send = r.t_send
                     if r.status != 503:
@@ -81,7 +136,8 @@ def client_loop(spec: dict, thread: int, pool: traffic.BodyPool,
                                 t_send, r.t_first, r.t_last, r.status,
                                 bool(good and not wrong), bool(wrong),
                                 attempt))
-                if not r.ok and r.status != 503:
+                if (not r.ok and r.status != 503) or (
+                        op.verb == "HEAL" and r.ok and not good):
                     print(f"worker {spec['worker']}.{thread}: {op.verb} "
                           f"{op.key} -> {r.status} {r.body[:300]!r}",
                           file=sys.stderr)
