@@ -79,6 +79,16 @@ _HEDGED_WINS = obs.counter(
     "minio_tpu_hedged_reads_won_total",
     "Hedged shard reads that made quorum before the straggler").labels()
 
+# What _read_shards asked of the shards' readers: one read a shard a
+# batch (BitrotReader.read_records), so reads / GET is the shards read
+# times the batches, and records / reads the batch's blocks.
+_SHARD_READS = obs.counter(
+    "minio_tpu_get_shard_reads_total",
+    "Reads GET's shard tasks issued to a shard's reader").labels()
+_SHARD_RECORDS = obs.counter(
+    "minio_tpu_get_shard_records_total",
+    "Records ([digest][chunk]) those reads returned").labels()
+
 # Shared with cache/disk.py (the registry dedupes by family name):
 # latest-only caches — the disk cache and the HBM hot tier — bypass
 # explicitly-versioned reads and account them here instead of
@@ -1296,8 +1306,9 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         the batch, for the first k shards that answer.
 
         Shards read in PARALLEL (one worker per shard, each reading its
-        batch sequentially — per-drive sequential I/O, cross-drive
-        concurrency, the reference's parallelReader goroutine layout,
+        batch's contiguous records with one read, BitrotReader.read_records
+        — per-drive sequential I/O, cross-drive concurrency, the
+        reference's parallelReader goroutine layout,
         cmd/erasure-decode.go:120-188); host hashing and preads release
         the GIL in native code. mxsum256 shard files verify in ONE device
         launch per batch (fused.verify_digests) instead of per-chunk host
@@ -1312,8 +1323,13 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         deadline) are abandoned, never awaited."""
         shard_size = codec.shard_size()
         chunk_lens = [-(-bl // codec.k) for bl in block_lens]
+        # _stream_one_part cuts batches as range(bi, ...); a caller that
+        # ever passes other ids gets a read a block.
+        consecutive = list(batch_ids) == list(
+            range(batch_ids[0], batch_ids[0] + len(batch_ids)))
 
-        def read_shard(i: int) -> list[tuple[bytes | None, bytes]]:
+        def read_shard(
+                i: int) -> list[tuple[bytes | None, bytes | memoryview]]:
             r = readers[i]
             if r is None:
                 if open_reader is None:
@@ -1328,18 +1344,24 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                         pass
                     raise se.FaultyDisk(f"shard {i}: abandoned")
                 readers[i] = r
-            out: list[tuple[bytes | None, bytes]] = []
-            for j, b in enumerate(batch_ids):
-                if batched_verify:
-                    want, chunk = r.read_record(b)
-                    if len(chunk) != chunk_lens[j]:
-                        raise se.FileCorrupt(
-                            f"chunk {b} length {len(chunk)} != "
-                            f"{chunk_lens[j]}")
-                    out.append((want, chunk))
-                else:
-                    out.append((None, r.read_at(
-                        b * shard_size, chunk_lens[j])))
+            reads = len(batch_ids)
+            if not batched_verify:
+                out = [(None, r.read_at(b * shard_size, chunk_lens[j]))
+                       for j, b in enumerate(batch_ids)]
+            elif consecutive:
+                # One read a shard a batch: the records are contiguous,
+                # and the chunks come back as views of that one buffer.
+                out = r.read_records(batch_ids[0], len(batch_ids))
+                reads = 1
+            else:
+                out = [r.read_record(b) for b in batch_ids]
+            _SHARD_READS.inc(reads)
+            _SHARD_RECORDS.inc(len(out))
+            for j, (_want, chunk) in enumerate(out):
+                if len(chunk) != chunk_lens[j]:
+                    raise se.FileCorrupt(
+                        f"chunk {batch_ids[j]} length {len(chunk)} != "
+                        f"{chunk_lens[j]}")
             return out
 
         from concurrent.futures import FIRST_COMPLETED, CancelledError

@@ -22,6 +22,8 @@ key is fine — it only has to be stable across the cluster.
 from __future__ import annotations
 
 import hashlib
+import io
+import os
 import time
 from typing import BinaryIO, Callable
 
@@ -34,6 +36,8 @@ try:
     _HAVE_XXHASH = True
 except ImportError:  # pragma: no cover - baked into this image
     _HAVE_XXHASH = False
+
+_HAVE_PREAD = hasattr(os, "pread")  # every Unix
 
 # Fixed 256-bit bitrot key (same role as the reference's magic HH key).
 BITROT_KEY = bytes.fromhex(
@@ -244,6 +248,80 @@ class BitrotReader:
         if len(want) != dl or len(chunk) != chunk_len:
             raise se.FileCorrupt(f"short read at chunk {chunk_index}")
         return want, chunk
+
+    def read_records(self, first: int,
+                     count: int) -> list[tuple[bytes, memoryview]]:
+        """The raw records first … first + count − 1, UNVERIFIED like
+        read_record's, from ONE read of their contiguous byte range into
+        one fresh buffer: [(stored digest, chunk)], the chunks as
+        memoryview slices of that buffer (no copy of a chunk; they keep it
+        alive). The erasure GET reads a shard's ten records of a batch
+        this way: one system call where read_record makes three a record.
+
+        How it reads follows from what `src` is, not from a knob. A plain
+        file as open(path, "rb") gives it (the local drive's) is read
+        positionally through its descriptor: no seek state, the file
+        object's buffer bypassed. Every other source (a remote drive's
+        ranged stream, a fault injector's pacing wrapper, a BytesIO) takes
+        one seek and one read-exact through its own read(), so an
+        injector still sees the read.
+
+        Ownership: the descriptor is looked up on every call and a closed
+        file raises (FaultyDisk), but a close that lands between that
+        look-up and the read could hand the read a descriptor number the
+        process has reused. Callers therefore close a reader only after
+        the task reading it has returned, which is what
+        erasure/objects.py _abandon_shard (close in the future's done
+        callback) and _close_readers (after the producer's join) do."""
+        if count <= 0:
+            return []
+        dl = self.algo.digest_len
+        first_byte = first * self.shard_size
+        last_start = (first + count - 1) * self.shard_size
+        if first < 0 or not last_start < max(self.data_size, 1):
+            raise se.FileCorrupt(
+                f"chunks {first}..{first + count - 1} outside shard")
+        data_len = min(self.data_size,
+                       last_start + self.shard_size) - first_byte
+        buf = self._read_range(first * (dl + self.shard_size),
+                               count * dl + data_len)
+        out = []
+        off = 0
+        for ci in range(count):
+            chunk_len = min(self.shard_size, data_len - ci * self.shard_size)
+            out.append((bytes(buf[off:off + dl]),
+                        buf[off + dl:off + dl + chunk_len]))
+            off += dl + chunk_len
+        return out
+
+    def _read_range(self, off: int, length: int) -> memoryview:
+        """Exactly `length` bytes at `off` of the source, or FileCorrupt."""
+        src = self.src
+        raw = src.raw if isinstance(src, io.BufferedReader) else src
+        if _HAVE_PREAD and isinstance(raw, io.FileIO):
+            try:
+                fd = raw.fileno()
+            except ValueError:
+                raise se.FaultyDisk(
+                    "shard reader closed under a read") from None
+
+            def read(got: int) -> bytes:
+                return os.pread(fd, length - got, off + got)
+        else:
+            src.seek(off)
+
+            def read(got: int) -> bytes:
+                return src.read(length - got)
+        data = read(0) or b""
+        if len(data) < length:  # short of the end: a signal, a raw stream
+            data = bytearray(data)
+            while len(data) < length:
+                more = read(len(data))
+                if not more:
+                    raise se.FileCorrupt(
+                        f"short read: {len(data)} of {length} bytes at {off}")
+                data += more
+        return memoryview(data)
 
     def read_at(self, offset: int, length: int) -> bytes:
         if offset < 0 or length < 0 or offset + length > self.data_size:

@@ -653,7 +653,8 @@ def test_get_vectored_send_pct_reads_the_scrape(ec):
         return {k: v for k, v in samples.items()
                 if not k[0].startswith(prefix)}
 
-    gone = without(after, "minio_tpu_get_")
+    gone = without(without(after, "minio_tpu_get_drain_"),
+                   "minio_tpu_get_vectored_")
     assert len(gone) == len(after) - 3
     assert scrape.delta_ratio(gone, gone, spec, {}) is None
     parent = (without(before, "minio_tpu_get_vectored_"),

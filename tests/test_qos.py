@@ -487,6 +487,15 @@ def test_blob_lane_flush_full_sheds_slowdown_with_metric(
                     ".mtpu.sys", f"config/f{i}.mp", b"x" * 64))
             except se.OperationTimedOut:
                 break
+            if i == 0:
+                # The committer has to be inside the held fsync with the
+                # first record before the other two fill the queue: it
+                # wakes some 50 us after the put, and a second put that
+                # lands sooner rides the same batch (the queue then holds
+                # one record, the flush gets in and times out instead).
+                end = time.monotonic() + 1.0
+                while not d._wal._q.empty() and time.monotonic() < end:
+                    time.sleep(0.002)
         with pytest.raises(se.OperationTimedOut):
             d._wal.flush(timeout=0.3)
         assert _shed_value("metaplane", "wal_flush_full") == before + 1
