@@ -10,6 +10,7 @@ requests' rows in a `BatchPlane` lane, or as its own launch through
 | `decode_blocks` (GET) | plane enabled, `codec.m > 0`, `block_lens` non-empty, `ceil(max len / k)` <= `RECON_GATE` | falls back to direct | `codec.decode_blocks` |
 | `begin_reconstruct` (heal) | the same gate on `block_lens` | falls back | `codec.begin_reconstruct` |
 | `digest_chunks` (GET verify, deep verify) | plane enabled, `cap` <= `ENCODE_GATE` (no `m` test) | falls back | `fused.digest_chunks_host` |
+| `digest_staged` (GET verify of a batch its reads laid into the launch's array) | never: the caller stages only where `verifies_in_place(cap)`, `digest_chunks`' lane test negated, holds | – | `fused.digest_staged_host` |
 
 "Plane enabled" is `dataplane.maybe_plane()`: `MTPU_BATCHED_DATAPLANE=0`
 means always direct, and a front-door worker's router answers there
@@ -91,10 +92,15 @@ def begin_reconstruct(codec, rows, block_lens, targets,
                                    with_digests=with_digests)
 
 
+def _verify_plane(cap: int):
+    plane = maybe_plane()
+    return plane if plane is not None and cap <= ENCODE_GATE else None
+
+
 def digest_chunks(chunks: list, cap: int) -> list[bytes]:
     """mxsum256 digests of a ragged list of chunks, each <= cap."""
-    plane = maybe_plane()
-    if plane is not None and cap <= ENCODE_GATE:
+    plane = _verify_plane(cap)
+    if plane is not None:
         try:
             return plane.digest_chunks(chunks, cap)
         except se.OperationTimedOut:
@@ -102,3 +108,17 @@ def digest_chunks(chunks: list, cap: int) -> list[bytes]:
     from minio_tpu.ops import fused
 
     return fused.digest_chunks_host(chunks, cap)
+
+
+def verifies_in_place(cap: int) -> bool:
+    """Whether digest_chunks(…, cap) would launch directly: the caller
+    may then stage the rows itself and call digest_staged."""
+    return _verify_plane(cap) is None
+
+
+def digest_staged(stage, lens):
+    """Digests [rows, 32] of rows the caller staged (verifies_in_place
+    said so): the direct launch, no copy a chunk."""
+    from minio_tpu.ops import fused
+
+    return fused.digest_staged_host(stage, lens)

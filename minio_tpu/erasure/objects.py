@@ -29,6 +29,8 @@ import uuid
 from concurrent.futures import TimeoutError as _FutTimeout
 from typing import BinaryIO, Iterator
 
+import numpy as np
+
 from minio_tpu import hottier, metaplane, obs
 from minio_tpu.dataplane import route
 from minio_tpu.obs import flight
@@ -80,14 +82,24 @@ _HEDGED_WINS = obs.counter(
     "Hedged shard reads that made quorum before the straggler").labels()
 
 # What _read_shards asked of the shards' readers: one read a shard a
-# batch (BitrotReader.read_records), so reads / GET is the shards read
-# times the batches, and records / reads the batch's blocks.
+# batch (BitrotReader.read_records_into or read_records), so reads / GET
+# is the shards read times the batches, and records / reads the batch's
+# blocks.
 _SHARD_READS = obs.counter(
     "minio_tpu_get_shard_reads_total",
     "Reads GET's shard tasks issued to a shard's reader").labels()
 _SHARD_RECORDS = obs.counter(
     "minio_tpu_get_shard_records_total",
     "Records ([digest][chunk]) those reads returned").labels()
+# Rows GET's batched verify digested: read straight into the launch's
+# staging array (_VerifyStage), or copied there (a lane, a hedged spare
+# with no slot left, ids that are not consecutive).
+_VERIFY_ROWS = obs.counter(
+    "minio_tpu_get_verify_rows_total",
+    "Rows GET's batched verify digested, by how they reached the "
+    "launch's staging array", ("staged",))
+_VERIFY_READ = _VERIFY_ROWS.labels(staged="read")
+_VERIFY_COPIED = _VERIFY_ROWS.labels(staged="copied")
 
 # Shared with cache/disk.py (the registry dedupes by family name):
 # latest-only caches — the disk cache and the HBM hot tier — bypass
@@ -1279,10 +1291,15 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         them; marks dead drives and raises StorageError to trigger
         re-selection."""
         batched_verify = algo == "mxsum256"
+        cap = codec.shard_size()
+        # A fresh staging array each attempt at a batch (see _read_shards).
+        stage = (_VerifyStage(len(chosen), len(batch_ids), cap)
+                 if batched_verify and _consecutive(batch_ids)
+                 and route.verifies_in_place(cap) else None)
         with flight.span("shard_read", "erasure"):
             results = self._read_shards(
                 readers, chosen, batch_ids, block_lens, codec, n, dead,
-                batched_verify, pool, corrupt, open_reader, benched)
+                batched_verify, pool, corrupt, open_reader, benched, stage)
         rows: list[list[bytes | None]] = []
         records: list[tuple[int, bytes, bytes]] = []  # (drive, want, chunk)
         for j, _b in enumerate(batch_ids):
@@ -1296,24 +1313,43 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         if records:
             # Staging, lane submit or launch, and the D2H of the digests.
             with flight.span("verify_wait", "dataplane"):
-                self._verify_records(records, codec, readers, dead, corrupt)
+                if stage is not None and all(i in stage.slot_of
+                                             for i in results):
+                    self._verify_staged(stage, results, readers, dead,
+                                        corrupt)
+                else:
+                    self._verify_records(records, codec, readers, dead,
+                                         corrupt)
         return rows
 
     def _read_shards(self, readers, chosen, batch_ids, block_lens, codec,
                      n, dead, batched_verify, pool, corrupt, open_reader,
-                     benched) -> dict[int, list]:
+                     benched, stage=None) -> dict[int, list]:
         """shard index -> its [(stored digest | None, chunk)] per block of
         the batch, for the first k shards that answer.
 
         Shards read in PARALLEL (one worker per shard, each reading its
-        batch's contiguous records with one read, BitrotReader.read_records
-        — per-drive sequential I/O, cross-drive concurrency, the
-        reference's parallelReader goroutine layout,
-        cmd/erasure-decode.go:120-188); host hashing and preads release
-        the GIL in native code. mxsum256 shard files verify in ONE device
-        launch per batch (fused.verify_digests) instead of per-chunk host
-        hashing — the TPU-native form of the reference's
-        verify-every-ReadAt (cmd/bitrot-streaming.go:115-158).
+        batch's contiguous records with one read — per-drive sequential
+        I/O, cross-drive concurrency, the reference's parallelReader
+        goroutine layout, cmd/erasure-decode.go:120-188); host hashing
+        and preads release the GIL in native code. mxsum256 shard files
+        verify in ONE device launch per batch (fused.verify_digests)
+        instead of per-chunk host hashing — the TPU-native form of the
+        reference's verify-every-ReadAt (cmd/bitrot-streaming.go:115-158).
+
+        With a `stage` (_VerifyStage: mxsum256, consecutive ids, a direct
+        verify launch) each submitted shard takes the next SLOT, rows
+        slot·B … slot·B + B − 1 of the launch's [bucket_rows(k·B), cap]
+        array (B blocks a batch), in submission order, and its reader
+        lands its records there (BitrotReader.read_records_into: one
+        preadv of a local file); the chunks it hands on are views of those
+        rows. A spare whose slot would fall past the array's rows reads
+        into a buffer of its own (read_records) and the batch verifies by
+        copy. The array is never pooled: an abandoned straggler may still
+        write into its slot after the verify, and the chunks handed on
+        keep it alive until the last slice is sent, so a fresh np.empty a
+        batch is what keeps a late write out of another request's bytes
+        (and no memset is needed: rows whose length is 0 are not read).
 
         First-k-wins with hedging: after the hedge delay (rolling-latency
         derived) spare readers launch on unused parity shards, and the
@@ -1325,11 +1361,12 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         chunk_lens = [-(-bl // codec.k) for bl in block_lens]
         # _stream_one_part cuts batches as range(bi, ...); a caller that
         # ever passes other ids gets a read a block.
-        consecutive = list(batch_ids) == list(
-            range(batch_ids[0], batch_ids[0] + len(batch_ids)))
+        consecutive = _consecutive(batch_ids)
+        # Slots go in submission order, on this thread.
+        slot_for = stage.take if stage is not None else (lambda _i: None)
 
-        def read_shard(
-                i: int) -> list[tuple[bytes | None, bytes | memoryview]]:
+        def read_shard(i: int, slot: int | None = None
+                       ) -> list[tuple[bytes | None, bytes | memoryview]]:
             r = readers[i]
             if r is None:
                 if open_reader is None:
@@ -1348,6 +1385,13 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
             if not batched_verify:
                 out = [(None, r.read_at(b * shard_size, chunk_lens[j]))
                        for j, b in enumerate(batch_ids)]
+            elif slot is not None:
+                # One read a shard a batch, straight into its slot's rows.
+                want, chunk_rows = stage.rows(slot)
+                out = [(memoryview(w), c) for w, c in zip(
+                    want, r.read_records_into(batch_ids[0], len(batch_ids),
+                                              want, chunk_rows))]
+                reads = 1
             elif consecutive:
                 # One read a shard a batch: the records are contiguous,
                 # and the chunks come back as views of that one buffer.
@@ -1394,7 +1438,7 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
                 try:
                     # ctx_wrap: shard reads run in pool workers but their
                     # storage/RPC trace records belong to this request.
-                    f = pool.submit(obs.ctx_wrap(read_shard), i)
+                    f = pool.submit(obs.ctx_wrap(read_shard), i, slot_for(i))
                 except RuntimeError:
                     return False
                 futures[i] = f
@@ -1492,7 +1536,7 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
         else:
             for i in chosen:
                 try:
-                    results[i] = read_shard(i)
+                    results[i] = read_shard(i, slot_for(i))
                 except _SHARD_ERRS as e:
                     record_failure(i, e)
             if first_err is not None:
@@ -1502,17 +1546,45 @@ class ErasureObjects(HealingMixin, MultipartMixin, SysConfigStore):
 
     def _verify_records(self, records, codec, readers, dead,
                         corrupt=None) -> None:
-        """One batched mxsum256 launch over every chunk just read; a digest
-        mismatch marks the drive dead and retriggers shard selection."""
+        """One batched mxsum256 launch over every chunk just read, each
+        copied into the launch's rows; a digest mismatch marks the drive
+        dead and retriggers shard selection."""
         got = route.digest_chunks([c for _i, _w, c in records],
                                   codec.shard_size())
+        _VERIFY_COPIED.inc(len(records))
         for ri, (i, want, _chunk) in enumerate(records):
             if got[ri] != want:
-                dead.add(i)
-                if corrupt is not None:
-                    corrupt.add(i)
-                readers[i] = None
-                raise se.FileCorrupt(f"shard {i}: bitrot digest mismatch")
+                self._verify_failed(i, readers, dead, corrupt)
+
+    def _verify_staged(self, stage, results, readers, dead,
+                       corrupt=None) -> None:
+        """_verify_records for a batch whose harvested shards all read
+        into their slots of `stage`: the launch takes the array as it
+        lies, and the stored digests compare in one array operation. Only
+        the harvested rows get a length; the rest are not read."""
+        b = stage.blocks
+        lens = [len(c) for _w, c in next(iter(results.values()))]
+        for i in results:
+            s = stage.slot_of[i] * b
+            stage.lens[s:s + b] = lens
+        got = route.digest_staged(stage.stage, stage.lens)
+        _VERIFY_READ.inc(b * len(results))
+        bad = np.flatnonzero((got != stage.want).any(axis=1)
+                             & (stage.lens > 0))
+        if bad.size:
+            # The first mismatch in block-then-shard order, as
+            # _verify_records marks it.
+            shard = {s: i for i, s in stage.slot_of.items()}
+            _j, i = min((r % b, shard[r // b]) for r in bad.tolist())
+            self._verify_failed(i, readers, dead, corrupt)
+
+    @staticmethod
+    def _verify_failed(i: int, readers, dead, corrupt) -> None:
+        dead.add(i)
+        if corrupt is not None:
+            corrupt.add(i)
+        readers[i] = None
+        raise se.FileCorrupt(f"shard {i}: bitrot digest mismatch")
 
     # ------------------------------------------------------------------
     # delete (cmd/erasure-object.go:894-1031)
@@ -2249,6 +2321,44 @@ def _shard_paths_mixed(drives: list[StorageAPI], vol: str, rel: str
         paths.append("")
         remotes.append(d)
     return paths, remotes
+
+
+def _consecutive(ids) -> bool:
+    return list(ids) == list(range(ids[0], ids[0] + len(ids)))
+
+
+class _VerifyStage:
+    """One attempt at a batch's verify launch, staged by its reads
+    (_read_shards): `stage` [bucket_rows(shards·blocks), cap] u8 and the
+    stored digests `want` [rows, 32], both np.empty; `lens` [rows] int32,
+    set for the harvested shards' rows only. Slots are handed out in
+    submission order (take, on the submitting thread)."""
+
+    __slots__ = ("stage", "want", "lens", "blocks", "slots", "slot_of")
+
+    def __init__(self, shards: int, blocks: int, cap: int):
+        from minio_tpu.ops import fused, mxsum
+
+        rows = fused.bucket_rows(shards * blocks)
+        self.stage = np.empty((rows, cap), dtype=np.uint8)
+        self.want = np.empty((rows, mxsum.DIGEST_LEN), dtype=np.uint8)
+        self.lens = np.zeros(rows, dtype=np.int32)
+        self.blocks = blocks
+        self.slots = rows // blocks
+        self.slot_of: dict[int, int] = {}  # shard index -> slot
+
+    def take(self, shard: int) -> int | None:
+        """The next slot for `shard`, or None past the array's rows."""
+        slot = len(self.slot_of)
+        if slot >= self.slots:
+            return None
+        self.slot_of[shard] = slot
+        return slot
+
+    def rows(self, slot: int):
+        """(digest rows, chunk rows) of a slot."""
+        s = slice(slot * self.blocks, (slot + 1) * self.blocks)
+        return self.want[s], self.stage[s]
 
 
 def _yield_block_range(chunks, lo: int, hi: int):

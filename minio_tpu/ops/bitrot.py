@@ -38,6 +38,31 @@ except ImportError:  # pragma: no cover - baked into this image
     _HAVE_XXHASH = False
 
 _HAVE_PREAD = hasattr(os, "pread")  # every Unix
+_HAVE_PREADV = hasattr(os, "preadv")
+# iovecs one preadv takes; Linux's 1024 where the system states none
+_IOV_MAX = (os.sysconf("SC_IOV_MAX")
+            if "SC_IOV_MAX" in getattr(os, "sysconf_names", {}) else -1)
+_IOV_MAX = _IOV_MAX if _IOV_MAX > 0 else 1024
+
+
+def _preadv_exact(fd: int, bufs: list[memoryview], off: int,
+                  length: int) -> None:
+    """Fill `bufs` (`length` bytes in all) from `fd` at `off`: one
+    os.preadv, and more only where it answers short (a signal, or more
+    iovecs than the system takes at once). FileCorrupt at end of file."""
+    done = 0
+    while True:
+        got = os.preadv(fd, bufs[:_IOV_MAX], off + done)
+        if got <= 0:
+            raise se.FileCorrupt(
+                f"short read: {done} of {length} bytes at {off}")
+        done += got
+        if done >= length:
+            return
+        while got >= len(bufs[0]):  # drop the buffers it filled
+            got -= len(bufs[0])
+            bufs = bufs[1:]
+        bufs = [bufs[0][got:]] + bufs[1:]
 
 # Fixed 256-bit bitrot key (same role as the reference's magic HH key).
 BITROT_KEY = bytes.fromhex(
@@ -255,8 +280,9 @@ class BitrotReader:
         read_record's, from ONE read of their contiguous byte range into
         one fresh buffer: [(stored digest, chunk)], the chunks as
         memoryview slices of that buffer (no copy of a chunk; they keep it
-        alive). The erasure GET reads a shard's ten records of a batch
-        this way: one system call where read_record makes three a record.
+        alive). The erasure GET reads this way where its verify copies the
+        chunks (a lane's width, a hedged spare with no slot left in the
+        staging array); its usual path reads in place (read_records_into).
 
         How it reads follows from what `src` is, not from a knob. A plain
         file as open(path, "rb") gives it (the local drive's) is read
@@ -276,6 +302,68 @@ class BitrotReader:
         if count <= 0:
             return []
         dl = self.algo.digest_len
+        lens = self._chunk_lens(first, count)
+        buf = self._read_range(first * (dl + self.shard_size),
+                               count * dl + sum(lens))
+        out = []
+        off = 0
+        for chunk_len in lens:
+            out.append((bytes(buf[off:off + dl]),
+                        buf[off + dl:off + dl + chunk_len]))
+            off += dl + chunk_len
+        return out
+
+    def read_records_into(self, first: int, count: int, want_rows,
+                          chunk_rows) -> list[memoryview]:
+        """read_records, landing in the caller's arrays: record j's
+        stored digest in want_rows[j] ([count, digest_len] uint8) and its
+        chunk in chunk_rows[j, :len] ([count, width] uint8), the rest of
+        a short chunk's row zeroed (an mxsum256 digest over the row needs
+        zeros past its length). -> the chunks, as memoryviews of their
+        rows. The erasure GET hands it its shard's rows of the verify
+        launch's staging array, so no chunk is copied on the way to the
+        device (erasure/objects.py _read_shards).
+
+        A plain file is read with ONE os.preadv, a [digest, chunk] iovec
+        pair a record, read on where it answers short. Any other source
+        takes read_records' one seek + read-exact into a buffer, placed
+        with one strided copy for the chunks and one for the digests, so
+        a fault injector still sees the read. Ownership as read_records."""
+        if count <= 0:
+            return []
+        dl = self.algo.digest_len
+        lens = self._chunk_lens(first, count)
+        off = first * (dl + self.shard_size)
+        length = count * dl + sum(lens)
+        fd = self._plain_fd() if _HAVE_PREADV else None
+        if fd is not None:
+            bufs = []
+            for j, n in enumerate(lens):
+                bufs += (memoryview(want_rows[j]),
+                         memoryview(chunk_rows[j, :n]))
+            _preadv_exact(fd, bufs, off, length)
+        else:
+            import numpy as np
+
+            buf = np.frombuffer(self._read_range(off, length), np.uint8)
+            # Every record but a shard's last is full: one [digest][chunk]
+            # stride of dl + shard_size bytes.
+            full = count if lens[-1] == self.shard_size else count - 1
+            if full:
+                recs = buf[:full * (dl + self.shard_size)].reshape(full, -1)
+                want_rows[:full] = recs[:, :dl]
+                chunk_rows[:full, :self.shard_size] = recs[:, dl:]
+            if full < count:
+                tail = buf[full * (dl + self.shard_size):]
+                want_rows[full] = tail[:dl]
+                chunk_rows[full, :lens[-1]] = tail[dl:]
+        if lens[-1] < chunk_rows.shape[1]:
+            chunk_rows[count - 1, lens[-1]:] = 0
+        return [memoryview(chunk_rows[j, :n]) for j, n in enumerate(lens)]
+
+    def _chunk_lens(self, first: int, count: int) -> list[int]:
+        """Chunk byte-lengths of records first … first + count − 1 (all
+        shard_size but a shard's last), or FileCorrupt outside the shard."""
         first_byte = first * self.shard_size
         last_start = (first + count - 1) * self.shard_size
         if first < 0 or not last_start < max(self.data_size, 1):
@@ -283,27 +371,26 @@ class BitrotReader:
                 f"chunks {first}..{first + count - 1} outside shard")
         data_len = min(self.data_size,
                        last_start + self.shard_size) - first_byte
-        buf = self._read_range(first * (dl + self.shard_size),
-                               count * dl + data_len)
-        out = []
-        off = 0
-        for ci in range(count):
-            chunk_len = min(self.shard_size, data_len - ci * self.shard_size)
-            out.append((bytes(buf[off:off + dl]),
-                        buf[off + dl:off + dl + chunk_len]))
-            off += dl + chunk_len
-        return out
+        return [min(self.shard_size, data_len - ci * self.shard_size)
+                for ci in range(count)]
+
+    def _plain_fd(self) -> int | None:
+        """The descriptor of a plain file source, which is read
+        positionally; None for any other source. FaultyDisk if closed."""
+        src = self.src
+        raw = src.raw if isinstance(src, io.BufferedReader) else src
+        if not (_HAVE_PREAD and isinstance(raw, io.FileIO)):
+            return None
+        try:
+            return raw.fileno()
+        except ValueError:
+            raise se.FaultyDisk("shard reader closed under a read") from None
 
     def _read_range(self, off: int, length: int) -> memoryview:
         """Exactly `length` bytes at `off` of the source, or FileCorrupt."""
         src = self.src
-        raw = src.raw if isinstance(src, io.BufferedReader) else src
-        if _HAVE_PREAD and isinstance(raw, io.FileIO):
-            try:
-                fd = raw.fileno()
-            except ValueError:
-                raise se.FaultyDisk(
-                    "shard reader closed under a read") from None
+        fd = self._plain_fd()
+        if fd is not None:
 
             def read(got: int) -> bytes:
                 return os.pread(fd, length - got, off + got)

@@ -256,3 +256,15 @@ def digest_chunks_host(chunks: list[bytes], cap: int) -> list[bytes]:
     got = np.asarray(verify_digests(batch, lens))
     GLOBAL_POOL.put(batch)
     return [got[i].tobytes() for i in range(len(chunks))]
+
+
+def digest_staged_host(stage, lens):
+    """mxsum256 digests of an array the caller has already staged: stage
+    [bucket_rows(n), cap] u8, each row zero past lens[row] ([rows] int32;
+    a row whose length is 0 may hold anything, its digest is not for
+    reading) -> digests [rows, 32] u8 as numpy. The same launch and
+    shapes as digest_chunks_host, without its copy a chunk: the erasure
+    GET reads its records straight into `stage`'s rows."""
+    import numpy as np
+
+    return np.asarray(verify_digests(stage, lens))

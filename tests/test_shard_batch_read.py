@@ -283,13 +283,17 @@ def test_get_reads_one_read_a_shard_a_batch(layer, monkeypatch, what, offset,
     preads, real = [], os.pread
     monkeypatch.setattr(os, "pread", lambda fd, n, off: (
         preads.append(off), real(fd, n, off))[1])
+    preadvs, real_v = [], os.preadv
+    monkeypatch.setattr(os, "preadv", lambda fd, bufs, off: (
+        preadvs.append(off), real_v(fd, bufs, off))[1])
     before = _counts()
     assert _get(es, "whole", offset=offset,
                 length=length) == body[offset:offset + length]
     reads, records = (a - b for a, b in zip(_counts(), before))
     assert (reads, records) == (k * batches, k * blocks)
-    # A local drive's file is read positionally: one system call a read.
-    assert len(preads) == reads
+    # A local drive's file is read positionally: one system call a read,
+    # a scatter read into the verify launch's rows.
+    assert (len(preadvs), len(preads)) == (reads, 0)
 
 
 def test_get_falls_back_to_a_read_a_block(layer, monkeypatch):
